@@ -29,13 +29,6 @@ paths (e.g. ``apex/amp/frontend.py``); see SURVEY.md for the layer map.
 
 __version__ = "0.1.0"
 
-# backfill jax.shard_map / lax.axis_size on jax builds that predate the
-# public spellings (no-op on current jax) — must run before any module
-# that references them at call time
-from apex_tpu.utils import jax_compat as _jax_compat
-
-_jax_compat.install()
-
 from apex_tpu.core.precision import PrecisionPolicy
 from apex_tpu.core.loss_scale import (
     LossScaleState,

@@ -20,6 +20,7 @@ Env override ``APEX_TPU_OPS_IMPL`` sets the default for "auto".
 
 from __future__ import annotations
 
+import logging
 import os
 from typing import Optional
 
@@ -29,10 +30,15 @@ __all__ = ["resolve_impl", "pick_block_rows"]
 
 _VALID = ("auto", "pallas", "pallas_interpret", "xla")
 
+_logger = logging.getLogger(__name__)
+#: ops whose "auto" already said it left the kernel for XLA on a TPU
+_said_xla = set()
+
 
 def resolve_impl(implementation: Optional[str], *,
                  pallas_ok: bool = True,
-                 auto_default: str = "pallas") -> str:
+                 auto_default: str = "pallas",
+                 op: Optional[str] = None) -> str:
     """Resolve an ``implementation`` argument to a concrete choice.
 
     ``pallas_ok=False`` signals the caller's shapes are outside the
@@ -42,16 +48,33 @@ def resolve_impl(implementation: Optional[str], *,
     FASTER than their kernel (group_norm, BASELINE.md round 4) pass
     ``"xla"`` so the measured winner is the default while explicit
     ``implementation=``/env overrides still reach the kernel.
+
+    Ops that pass their name as ``op`` get the strict contract: the
+    result is exactly what runs.  Asking for the kernel outside its
+    envelope raises (the reference never answers under the kernel's
+    name), and on a TPU "auto" says once per op, through this module's
+    logger, that it chose XLA.
     """
     impl = implementation or os.environ.get("APEX_TPU_OPS_IMPL", "auto")
     if impl not in _VALID:
         raise ValueError(
             f"implementation={impl!r} not in {_VALID}")
     if impl == "auto":
-        if (auto_default == "pallas" and pallas_ok
-                and jax.default_backend() == "tpu"):
+        on_tpu = jax.default_backend() == "tpu"
+        if auto_default == "pallas" and pallas_ok and on_tpu:
             return "pallas"
+        if op is not None and on_tpu and op not in _said_xla:
+            _said_xla.add(op)
+            _logger.warning(
+                "%s: implementation='auto' runs the XLA reference on "
+                "this TPU (the call is outside the Pallas kernel's "
+                "envelope)", op)
         return "xla"
+    if op is not None and impl != "xla" and not pallas_ok:
+        raise ValueError(
+            f"{op}: implementation={impl!r} asks for the Pallas kernel "
+            f"but the call is outside its envelope (shapes, dtypes or "
+            f"fast memory) — pass 'auto' or 'xla' for the reference")
     return impl
 
 

@@ -243,10 +243,7 @@ def _pick_rows(r_total: int, c: int) -> Optional[int]:
     return best
 
 
-# jax 0.4.x spells this TPUCompilerParams; newer releases CompilerParams
-_SEQ = getattr(pltpu, "CompilerParams",
-               getattr(pltpu, "TPUCompilerParams", None))(
-    dimension_semantics=("arbitrary",))
+_SEQ = pltpu.CompilerParams(dimension_semantics=("arbitrary",))
 
 
 def _row_spec(br, c):

@@ -97,25 +97,30 @@ _TINY = np.float32(np.finfo(np.float32).tiny)
 _ROTATIONS = ((13, 15, 26, 6), (17, 29, 16, 24))
 #: rows per kernel grid step (fp32 sublane height)
 _BLOCK_ROWS = 8
-#: VMEM budget gate for the kernel's row block + f32 scratch
-_VMEM_BUDGET = 10 * 1024 * 1024
+#: the TPU compiler's scoped fast-memory limit for one kernel (the
+#: v5e default; the kernel asks for no more)
+_VMEM_LIMIT = 16 * 1024 * 1024
 
 
 def pallas_envelope_ok(rows: int, vocab: int, dtype,
                        block_v: int) -> bool:
-    """Whether the kernel's support envelope admits this geometry:
-    even 128-aligned vocab (lane alignment + the even threefry draw —
-    odd sizes pad inside jax's threefry, a layout the kernel does not
-    replay), a tile that divides it, and the row block + two fp32
-    scratch rows inside the VMEM budget.  THE gate behind ``"auto"``
-    dispatch, and the check :func:`~apex_tpu.ops.autotune.
+    """Whether the kernel's support envelope admits this geometry: a
+    128-aligned vocab (lane alignment), a tile that divides it, and
+    the kernel's fast memory inside the compiler's scoped limit.  Per
+    row block that is the double-buffered logits block, the two fp32
+    scratch rows, the two whole-row uint32 order images the compiler
+    hoists out of the radix descents, and about three tile-wide fp32
+    temporaries — a bound fitted to what the v5e compiler accepted and
+    refused (bf16 fits to V=65536; V=131072 does not at any tile;
+    ``tests/test_chip_compile.py`` pins the edge).  THE gate behind
+    ``"auto"`` dispatch, and the check :func:`~apex_tpu.ops.autotune.
     tune_fused_sampling` applies per candidate so an out-of-envelope
     sweep errors out instead of silently timing the XLA reference."""
     br = min(_BLOCK_ROWS, int(rows))
+    need = (br * vocab * (2 * jnp.dtype(dtype).itemsize + 16)
+            + 3 * br * block_v * 4)
     return (vocab % 128 == 0 and block_v >= 128
-            and vocab % block_v == 0
-            and br * vocab * (jnp.dtype(dtype).itemsize + 8)
-            <= _VMEM_BUDGET)
+            and vocab % block_v == 0 and need <= _VMEM_LIMIT)
 
 
 def sampling_cost_bytes(rows: int, vocab: int, dtype) -> int:
@@ -222,6 +227,19 @@ def _threefry2x32(k0, k1, c0, c1):
     return x0, x1
 
 
+def _gumbel(k0, k1, pos):
+    """``jax.random.gumbel(key, (V,))[pos]`` for the raw key pair
+    ``(k0, k1)``, bit for bit.  Counter layout: jax's partitionable
+    threefry (``jax_threefry_partitionable``, the installed default) —
+    position ``j`` is the 64-bit counter ``(hi=0, lo=j)`` and its 32
+    random bits are the XOR of the cipher's two output lanes."""
+    r0, r1 = _threefry2x32(k0, k1, jnp.zeros_like(pos), pos)
+    fb = ((r0 ^ r1) >> jnp.uint32(9)) | jnp.uint32(0x3F800000)
+    floats = jax.lax.bitcast_convert_type(fb, jnp.float32) - 1.0
+    u = jnp.maximum(_TINY, floats * (jnp.float32(1.0) - _TINY) + _TINY)
+    return -jnp.log(-jnp.log(u))
+
+
 def _mono_u32(x):
     """Order-preserving uint32 image of fp32: flip the sign bit of
     non-negatives, invert negatives — ``a < b  ⇔  mono(a) < mono(b)``.
@@ -273,7 +291,6 @@ def _sampling_kernel(x_ref, keys_ref, temp_ref, topk_ref, topp_ref,
     k = jnp.where(topk_ref[:, 0] > 0, topk_ref[:, 0], vocab)
     topp = topp_ref[:, 0].astype(jnp.float32)
     p_on = (topp > 0.0) & (topp < 1.0)
-    half = vocab // 2
 
     # ---- pass 1: scale into scratch; online max + first-argmax.
     # The greedy argmax runs on the RAW fp32 logits, like the
@@ -356,25 +373,14 @@ def _sampling_kernel(x_ref, keys_ref, temp_ref, topk_ref, topp_ref,
     p_bits = jax.lax.fori_loop(0, 32, _p_body,
                                jnp.zeros((br,), jnp.uint32))
 
-    # ---- pass 5: Gumbel-max categorical.  Counter layout replays
-    # jax's threefry_2x32 split-half pairing for an even-size draw:
-    # position j < V/2 is lane 0 of counters (j, j+V/2), position
-    # j >= V/2 is lane 1 of counters (j-V/2, j).
+    # ---- pass 5: Gumbel-max categorical over vocab positions
     k0, k1 = keys_ref[:, 0:1], keys_ref[:, 1:2]
     s_run = jnp.full((br, 1), -jnp.inf, jnp.float32)
     si_run = jnp.full((br, 1), vocab, jnp.int32)
     for off, width in _chunks(vocab, block_v):
         pos = jax.lax.broadcasted_iota(
             jnp.uint32, (br, width), 1) + jnp.uint32(off)
-        lo = pos < jnp.uint32(half)
-        c0 = jnp.where(lo, pos, pos - jnp.uint32(half))
-        r0, r1 = _threefry2x32(k0, k1, c0, c0 + jnp.uint32(half))
-        bits = jnp.where(lo, r0, r1)
-        fb = (bits >> jnp.uint32(9)) | jnp.uint32(0x3F800000)
-        floats = jax.lax.bitcast_convert_type(fb, jnp.float32) - 1.0
-        u = jnp.maximum(_TINY,
-                        floats * (jnp.float32(1.0) - _TINY) + _TINY)
-        gum = -jnp.log(-jnp.log(u))
+        gum = _gumbel(k0, k1, pos)
         xs = scaled_ref[:, off:off + width]
         masked = jnp.where(xs < kth, _NEG_INF, xs)
         mu = _mono_u32(masked)
@@ -408,41 +414,38 @@ def _run_fused(logits, keys, temperature, top_k, top_p, vocab: int,
         top_p = jnp.pad(top_p, (0, pad))
     kernel = functools.partial(_sampling_kernel, vocab=vocab,
                                block_v=block_v)
-    kwargs = {}
-    cost_cls = getattr(pl, "CostEstimate", None)
-    if cost_cls is not None:
-        # declare the kernel's TRUE traffic: the one-shot logits read
-        # + params + tokens (sampling_cost_bytes, the number the
-        # decode_epilogue bench models) — without it XLA scores the
-        # custom call as free and the executable's cost analysis
-        # undercounts
-        kwargs["cost_estimate"] = cost_cls(
-            flops=98 * nrb * br * vocab,           # threefry dominates
-            bytes_accessed=sampling_cost_bytes(nrb * br, vocab,
-                                               logits.dtype),
-            transcendentals=3 * nrb * br * vocab)  # exp + 2 logs
-    out = pl.pallas_call(
-        kernel,
-        grid=(nrb,),
-        in_specs=[
-            pl.BlockSpec((br, vocab), lambda i: (i, 0)),
-            pl.BlockSpec((br, 2), lambda i: (i, 0)),
-            pl.BlockSpec((br, 1), lambda i: (i, 0)),
-            pl.BlockSpec((br, 1), lambda i: (i, 0)),
-            pl.BlockSpec((br, 1), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((br, 1), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((nrb * br, 1), jnp.int32),
-        scratch_shapes=[
-            # fp32 scaled row + softmax terms, re-swept by the radix
-            # descents at VMEM speed (the HBM read happened once)
-            pltpu.VMEM((br, vocab), jnp.float32),
-            pltpu.VMEM((br, vocab), jnp.float32),
-        ],
-        interpret=interpret,
-        **kwargs,
-    )(logits, keys.astype(jnp.uint32), temperature[:, None],
-      top_k[:, None], top_p[:, None])
+    # declare the kernel's TRUE traffic: the one-shot logits read +
+    # params + tokens (sampling_cost_bytes, the number the
+    # decode_epilogue bench models) — without it XLA scores the custom
+    # call as free and the executable's cost analysis undercounts
+    cost = pl.CostEstimate(
+        flops=98 * nrb * br * vocab,               # threefry dominates
+        bytes_accessed=sampling_cost_bytes(nrb * br, vocab,
+                                           logits.dtype),
+        transcendentals=3 * nrb * br * vocab)      # exp + 2 logs
+    with jax.named_scope("fused_sample"):
+        out = pl.pallas_call(
+            kernel,
+            grid=(nrb,),
+            in_specs=[
+                pl.BlockSpec((br, vocab), lambda i: (i, 0)),
+                pl.BlockSpec((br, 2), lambda i: (i, 0)),
+                pl.BlockSpec((br, 1), lambda i: (i, 0)),
+                pl.BlockSpec((br, 1), lambda i: (i, 0)),
+                pl.BlockSpec((br, 1), lambda i: (i, 0)),
+            ],
+            out_specs=pl.BlockSpec((br, 1), lambda i: (i, 0)),
+            out_shape=jax.ShapeDtypeStruct((nrb * br, 1), jnp.int32),
+            scratch_shapes=[
+                # fp32 scaled row + softmax terms, re-swept by the radix
+                # descents at VMEM speed (the HBM read happened once)
+                pltpu.VMEM((br, vocab), jnp.float32),
+                pltpu.VMEM((br, vocab), jnp.float32),
+            ],
+            interpret=interpret,
+            cost_estimate=cost,
+        )(logits, keys.astype(jnp.uint32), temperature[:, None],
+          top_k[:, None], top_p[:, None])
     return out[:rows, 0]
 
 
@@ -466,11 +469,11 @@ def fused_sample(logits, keys, temperature, top_k, top_p, *,
 
     ``implementation`` follows :mod:`apex_tpu.ops._dispatch`:
     ``"auto"`` takes the Pallas kernel on TPU when the geometry fits
-    its envelope (even 128-aligned vocab, ``block_v`` dividing it, row
-    block + scratch within the VMEM budget) and the XLA reference
-    elsewhere.  ``block_v`` is the vocab tile (0 = the autotuned
-    winner for ``(vocab, width)`` when one is cached, else the whole
-    row).  Returns ``(rows,)`` — or ``(rows, width)`` — int32 tokens,
+    its envelope (:func:`pallas_envelope_ok`) and the XLA reference
+    elsewhere; asking for the kernel outside the envelope raises.
+    ``block_v`` is the vocab tile (0 = the autotuned winner for
+    ``(vocab, width)`` when one is cached, else the whole row).
+    Returns ``(rows,)`` — or ``(rows, width)`` — int32 tokens,
     token-identical to the reference per the module parity contract.
     """
     width = None
@@ -507,10 +510,13 @@ def fused_sample(logits, keys, temperature, top_k, top_p, *,
         from apex_tpu.ops import autotune
         block_v = autotune.cached_sampling_tile(
             vocab, width or 1) or vocab
-    pallas_ok = pallas_envelope_ok(logits.shape[0], vocab,
-                                   logits.dtype, block_v)
-    impl = resolve_impl(implementation, pallas_ok=pallas_ok)
-    if impl == "xla" or not pallas_ok:
+    # the kernel replays the partitionable threefry layout only
+    pallas_ok = (pallas_envelope_ok(logits.shape[0], vocab,
+                                    logits.dtype, block_v)
+                 and jax.config.jax_threefry_partitionable)
+    impl = resolve_impl(implementation, pallas_ok=pallas_ok,
+                        op="fused_sample")
+    if impl == "xla":
         out = fused_sample_reference(logits, keys, temperature, top_k,
                                      top_p, vocab)
     else:

@@ -373,6 +373,33 @@ def paged_attention_reference(q, k_pages, v_pages, block_tables,
 # --------------------------------------------------------------------- #
 # Pallas TPU kernel
 # --------------------------------------------------------------------- #
+def _row_page_scales(scales, tables):
+    """``(kv_heads, num_blocks)`` page scales -> ``(b, kv_heads, 1,
+    pages_per_seq)`` fp32 in each row's LOGICAL page order, gathered
+    through the block table in XLA (``b·kv_heads·pages_per_seq``
+    floats) and carried whole per (row, head) grid step.  A ``(1, 1)``
+    block of the pool-wide array is below the TPU's (8, 128) tile —
+    the chip's compiler refuses it — and a scalar per page is too
+    small to be worth a DMA of its own anyway."""
+    g = scales.astype(jnp.float32)[:, tables]            # (hk, b, mb)
+    return g.transpose(1, 0, 2)[:, :, None, :]
+
+
+def _row_scale_spec(mb):
+    return pl.BlockSpec((1, 1, 1, mb),
+                        lambda row, head, j, *_: (row, head, 0, 0))
+
+
+def _page_scale(ref, j):
+    """Logical page ``j``'s scale out of a :func:`_row_page_scales`
+    block, as a (1, 1) tile: a one-hot lane select (the sum adds exact
+    zeros, so the value is bitwise the stored scale)."""
+    row = ref[0, 0]                                      # (1, mb)
+    lane = jax.lax.broadcasted_iota(jnp.int32, row.shape, 1)
+    return jnp.sum(jnp.where(lane == j, row, 0.0), axis=1,
+                   keepdims=True)
+
+
 def _paged_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, *refs,
                   bs, s, rep, scale, nb, qmax=None):
     """One (row, kv-head, page) step of the online-softmax sweep.
@@ -385,9 +412,9 @@ def _paged_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, *refs,
 
     ONE body serves both pool widths (the masking/softmax algebra must
     never fork).  With ``qmax`` set, ``k_ref``/``v_ref`` hold int8/fp8
-    codes and two extra refs — ``ks_ref``/``vs_ref``, the pages' fp32
-    amax scales, DMA-ed through the same block-table index map as
-    their pages (one ``(1, 1)`` scalar per step) — precede the output.
+    codes and two extra refs — ``ks_ref``/``vs_ref``, the row's pages'
+    fp32 amax scales in LOGICAL page order (:func:`_row_page_scales`;
+    lane ``j`` is page ``j``'s) — precede the output.
     The per-page dequant multiplier ``scale/qmax`` is CONSTANT over
     the ``(bs, d)`` tile, so it factors out of both contractions:
     codes are cast up (exact — |int8| ≤ 127 and e4m3 fit any float)
@@ -421,7 +448,7 @@ def _paged_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, *refs,
             preferred_element_type=jnp.float32)      # (bs, rep*s)
         if qmax is not None:
             # in-register dequant: one f32 multiply per score tile
-            sc = sc * (ks_ref[0, 0] * jnp.float32(1.0 / qmax))
+            sc = sc * (_page_scale(ks_ref, j) * jnp.float32(1.0 / qmax))
         k_pos = j * bs + jax.lax.broadcasted_iota(
             jnp.int32, (bs, rep * s), 0)
         q_off = jax.lax.broadcasted_iota(
@@ -440,7 +467,7 @@ def _paged_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, *refs,
             vq, pv = v_ref[0, 0], p.astype(v_ref.dtype)
         else:
             vq = (v_ref[0, 0].astype(jnp.float32)
-                  * (vs_ref[0, 0] * jnp.float32(1.0 / qmax)))
+                  * (_page_scale(vs_ref, j) * jnp.float32(1.0 / qmax)))
             pv = p
         acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
             vq, pv, (((0,), (0,)), ((), ())),
@@ -478,11 +505,6 @@ def _run_paged(q4, k_pages, v_pages, tables, lengths, scale, interpret,
         live = jnp.maximum(lens_ref[row] + s - 1, 0) // bs
         return head, tables_ref[row, jnp.minimum(j, live)], 0, 0
 
-    def _scale_map(row, head, j, tables_ref, lens_ref):
-        # the page's scale rides the same logical→physical resolution
-        live = jnp.maximum(lens_ref[row] + s - 1, 0) // bs
-        return head, tables_ref[row, jnp.minimum(j, live)]
-
     quantized = k_scales is not None
     in_specs = [
         pl.BlockSpec((1, 1, rep * s, d),
@@ -492,10 +514,9 @@ def _run_paged(q4, k_pages, v_pages, tables, lengths, scale, interpret,
     ]
     args = [tables, lengths, q3, k_pages, v_pages]
     if quantized:
-        in_specs += [pl.BlockSpec((1, 1), _scale_map),
-                     pl.BlockSpec((1, 1), _scale_map)]
-        args += [k_scales.astype(jnp.float32),
-                 v_scales.astype(jnp.float32)]
+        in_specs += [_row_scale_spec(mb), _row_scale_spec(mb)]
+        args += [_row_page_scales(k_scales, tables),
+                 _row_page_scales(v_scales, tables)]
         kernel = functools.partial(
             _paged_kernel, bs=bs, s=s, rep=rep, scale=scale,
             nb=mb, qmax=_qmax_for_pool(k_pages.dtype))
@@ -515,12 +536,15 @@ def _run_paged(q4, k_pages, v_pages, tables, lengths, scale, interpret,
             pltpu.VMEM((d, rep * s), jnp.float32),   # transposed acc
         ],
     )
-    o3 = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hk, rep * s, d), q4.dtype),
-        interpret=interpret,
-    )(*args)
+    # the scope names the kernel in HLO metadata and profiler traces
+    with jax.named_scope("paged_attention"):
+        o3 = pl.pallas_call(
+            kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((b, hk, rep * s, d),
+                                           q4.dtype),
+            interpret=interpret,
+        )(*args)
     return (o3.reshape(b, hk, rep, s, d)
             .transpose(0, 3, 1, 2, 4).reshape(b, s, h, d))
 
@@ -637,7 +661,7 @@ def paged_decode_fused_reference(q, k_new, v_new, k_pages, v_pages,
 
 
 def _paged_fused_kernel(tables_ref, lens_ref, wphys_ref, woff_ref,
-                        base_ref, real_ref, q_ref, k_ref, v_ref,
+                        real_ref, q_ref, k_ref, v_ref,
                         wk_ref, wv_ref, nk_ref, nv_ref, *refs,
                         bs, rep, scale, nb, S, half, qmax=None):
     """The decode sweep of :func:`_paged_kernel` (s = 1) with the
@@ -652,30 +676,27 @@ def _paged_fused_kernel(tables_ref, lens_ref, wphys_ref, woff_ref,
     its own query (write-then-attend) without the pool round-trip.
 
     Extra scalar prefetch vs the plain kernel: ``wphys``/``woff`` (the
-    write page and offset, null-routed on the host side of the trace),
-    ``base`` (the previous page — the scale chain's seed) and ``real``
-    (the pad-lane routing bit).  ``half`` is the RoPE half-rotation
-    width (0 = non-rotary model).  Outputs gain the write-page views
-    of the pool (and scales), each aliased to its input so untouched
-    pages persist.
+    write page and offset, null-routed on the host side of the trace)
+    and ``real`` (the pad-lane routing bit).  ``half`` is the RoPE
+    half-rotation width (0 = non-rotary model).  Outputs gain the
+    write-page views of the pool, each aliased to its input so
+    untouched pages persist.  A coded pool adds ``ks_ref``/``vs_ref``
+    (the row's page scales, :func:`_row_page_scales`), ``side_ref``
+    (the write page's current and the previous page's K/V scales — the
+    running-amax chain's inputs, lanes ``wk, wv, bk, bv``) and
+    ``ns_out`` (the write page's new K/V scales, lanes ``k, v``; the
+    caller scatters them into the pool-wide arrays — ``kv_heads``
+    floats per row).
     """
+    rest = list(refs)
+    cos_ref = sin_ref = ks_ref = vs_ref = side_ref = ns_out = None
+    if half:
+        cos_ref, sin_ref = rest[:2]
+        rest = rest[2:]
     if qmax is None:
-        cos_ref = sin_ref = ks_ref = vs_ref = None
-        wks_ref = wvs_ref = bks_ref = bvs_ref = None
-        rest = list(refs)
-        if half:
-            cos_ref, sin_ref = rest[:2]
-            rest = rest[2:]
         (o_ref, kp_out, vp_out, m_ref, l_ref, acc_ref) = rest
-        ks_out = vs_out = None
     else:
-        rest = list(refs)
-        cos_ref = sin_ref = None
-        if half:
-            cos_ref, sin_ref = rest[:2]
-            rest = rest[2:]
-        (ks_ref, vs_ref, wks_ref, wvs_ref, bks_ref, bvs_ref,
-         o_ref, kp_out, vp_out, ks_out, vs_out,
+        (ks_ref, vs_ref, side_ref, o_ref, kp_out, vp_out, ns_out,
          m_ref, l_ref, acc_ref) = rest
     row = pl.program_id(0)
     j = pl.program_id(2)
@@ -687,24 +708,32 @@ def _paged_fused_kernel(tables_ref, lens_ref, wphys_ref, woff_ref,
     wlog = length // bs                 # the write page IS the last
     # live page of the sweep (s = 1)
 
-    def _rot_row(x_row, x1_cos, x1_sin):
+    def _rot_row(x_row):
         # half-rotation RoPE of (rows, d) at this row's position —
-        # bitwise rope_rows (f32 math, cast back)
-        x1 = x_row[:, :half].astype(jnp.float32)
-        x2 = x_row[:, half:2 * half].astype(jnp.float32)
-        o1 = (x1 * x1_cos - x2 * x1_sin).astype(x_row.dtype)
-        o2 = (x2 * x1_cos + x1 * x1_sin).astype(x_row.dtype)
-        return jnp.concatenate([o1, o2, x_row[:, 2 * half:]], axis=-1)
+        # bitwise rope_rows (f32 math in the reference's own
+        # expression forms, cast back).  Lane-aligned: each lane's
+        # rotation partner arrives by an XLU roll against full-width
+        # tables ``[cos, cos]`` / ``[sin, sin]`` — a slice at lane
+        # ``half`` (64 at d=128) is not a shape the chip's compiler
+        # takes.
+        xf = x_row.astype(jnp.float32)
+        d = xf.shape[-1]
+        lo = pltpu.roll(xf, half, 1)                 # x[lane - half]
+        hi = lo if 2 * half == d else pltpu.roll(xf, d - half, 1)
+        c, sn = cos_ref[0], sin_ref[0]
+        lane = jax.lax.broadcasted_iota(jnp.int32, xf.shape, 1)
+        out = jnp.where(lane < half, xf * c - hi * sn,
+                        xf * c + lo * sn).astype(x_row.dtype)
+        return out if 2 * half == d else jnp.where(
+            lane < 2 * half, out, x_row)
 
     if half:
-        cos_row = cos_ref[:].astype(jnp.float32)     # (1, half)
-        sin_row = sin_ref[:].astype(jnp.float32)
-        qt = _rot_row(q_ref[0, 0], cos_row, sin_row)
-        k_row = _rot_row(nk_ref[0], cos_row, sin_row)
+        qt = _rot_row(q_ref[0, 0])
+        k_row = _rot_row(nk_ref[0, 0])
     else:
         qt = q_ref[0, 0]
-        k_row = nk_ref[0]
-    v_row = nv_ref[0]                                # (1, d)
+        k_row = nk_ref[0, 0]
+    v_row = nv_ref[0, 0]                             # (1, d)
 
     # the updated write tile (+ scales): computed at the first visit,
     # persisted in the aliased out blocks (same index all sweep long)
@@ -713,11 +742,13 @@ def _paged_fused_kernel(tables_ref, lens_ref, wphys_ref, woff_ref,
         m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
+        # the row lands at sublane ``woff`` of the write tile by a
+        # masked select (the compiler has no dynamic in-register slice)
+        here = write_ok & (jax.lax.broadcasted_iota(
+            jnp.int32, wk_ref.shape[2:], 0) == woff)
         if qmax is None:
-            kw = jnp.where(write_ok, k_row, wk_ref[0, 0][woff][None])
-            vw = jnp.where(write_ok, v_row, wv_ref[0, 0][woff][None])
-            kp_out[0, 0] = wk_ref[0, 0].at[woff].set(kw[0])
-            vp_out[0, 0] = wv_ref[0, 0].at[woff].set(vw[0])
+            kp_out[0, 0] = jnp.where(here, k_row, wk_ref[0, 0])
+            vp_out[0, 0] = jnp.where(here, v_row, wv_ref[0, 0])
         else:
             # monotone running-amax scale chain, width-1 form: the
             # write page's new scale = max(row amax, previous scale)
@@ -728,10 +759,12 @@ def _paged_fused_kernel(tables_ref, lens_ref, wphys_ref, woff_ref,
             va = jnp.max(jnp.abs(v_row.astype(jnp.float32)))
             ka = jnp.where(real, ka, 0.0)
             va = jnp.where(real, va, 0.0)
-            bk = jnp.where(length > 0, bks_ref[0, 0], 0.0)
-            bv = jnp.where(length > 0, bvs_ref[0, 0], 0.0)
-            cur_k = jnp.where(woff == 0, 0.0, wks_ref[0, 0])
-            cur_v = jnp.where(woff == 0, 0.0, wvs_ref[0, 0])
+            side = side_ref[0, 0]                    # (1, 4)
+            wks, wvs = side[:, 0:1], side[:, 1:2]
+            bk = jnp.where(length > 0, side[:, 2:3], 0.0)
+            bv = jnp.where(length > 0, side[:, 3:4], 0.0)
+            cur_k = jnp.where(woff == 0, 0.0, wks)
+            cur_v = jnp.where(woff == 0, 0.0, wvs)
             nks = jnp.maximum(cur_k, jnp.maximum(ka, bk))
             nvs = jnp.maximum(cur_v, jnp.maximum(va, bv))
 
@@ -746,14 +779,13 @@ def _paged_fused_kernel(tables_ref, lens_ref, wphys_ref, woff_ref,
                     y = jnp.round(y)
                 return y.astype(k_ref.dtype)
 
-            kw = jnp.where(write_ok, _code(k_row, nks),
-                           wk_ref[0, 0][woff][None])
-            vw = jnp.where(write_ok, _code(v_row, nvs),
-                           wv_ref[0, 0][woff][None])
-            kp_out[0, 0] = wk_ref[0, 0].at[woff].set(kw[0])
-            vp_out[0, 0] = wv_ref[0, 0].at[woff].set(vw[0])
-            ks_out[0, 0] = jnp.where(write_ok, nks, wks_ref[0, 0])
-            vs_out[0, 0] = jnp.where(write_ok, nvs, wvs_ref[0, 0])
+            kp_out[0, 0] = jnp.where(here, _code(k_row, nks),
+                                     wk_ref[0, 0])
+            vp_out[0, 0] = jnp.where(here, _code(v_row, nvs),
+                                     wv_ref[0, 0])
+            ns_out[0, 0] = jnp.concatenate(
+                [jnp.where(write_ok, nks, wks),
+                 jnp.where(write_ok, nvs, wvs)], axis=1)
 
     last_q = length                     # s == 1
 
@@ -766,7 +798,8 @@ def _paged_fused_kernel(tables_ref, lens_ref, wphys_ref, woff_ref,
             kq, qs, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)          # (bs, rep)
         if qmax is not None:
-            ksc = jnp.where(use_new, ks_out[0, 0], ks_ref[0, 0])
+            ksc = jnp.where(use_new, ns_out[0, 0][:, 0:1],
+                            _page_scale(ks_ref, j))
             sc = sc * (ksc * jnp.float32(1.0 / qmax))
         k_pos = j * bs + jax.lax.broadcasted_iota(
             jnp.int32, (bs, rep), 0)
@@ -780,7 +813,8 @@ def _paged_fused_kernel(tables_ref, lens_ref, wphys_ref, woff_ref,
         if qmax is None:
             vq, pv = vt, p.astype(vt.dtype)
         else:
-            vsc = jnp.where(use_new, vs_out[0, 0], vs_ref[0, 0])
+            vsc = jnp.where(use_new, ns_out[0, 0][:, 1:2],
+                            _page_scale(vs_ref, j))
             vq = vt.astype(jnp.float32) * (vsc * jnp.float32(1.0 / qmax))
             pv = p
         acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
@@ -809,8 +843,11 @@ def _run_decode_fused(q4, k_new, v_new, k_pages, v_pages, tables,
     half = 0 if cos_b is None else int(cos_b.shape[-1])
     q3 = (q4.reshape(b, 1, hk, rep, d)
           .transpose(0, 2, 3, 1, 4).reshape(b, hk, rep, d))
-    nk = k_new.reshape(b, hk, d)
-    nv = v_new.reshape(b, hk, d)
+    # the new row rides (b, hk, 1, d) and the RoPE tables (b, 1, half):
+    # a block's last two dims must equal the array's (or tile by
+    # (8, 128)), so the per-(row, head) row keeps a unit axis before d
+    nk = k_new.reshape(b, hk, 1, d)
+    nv = v_new.reshape(b, hk, 1, d)
     # the write target, resolved once in-trace (the kernel's scalar
     # prefetch): position -> clamped logical page -> physical, with
     # past-the-cache and pad-lane writes routed to the null page
@@ -825,9 +862,6 @@ def _run_decode_fused(q4, k_new, v_new, k_pages, v_pages, tables,
             if chunk_lens is None
             else (chunk_lens > 0).astype(jnp.int32))
     wphys = jnp.where(real != 0, wphys, 0)
-    base_logical = jnp.clip((lengths - 1) // bs, 0, mb - 1)
-    base_phys = jnp.take_along_axis(tables, base_logical[:, None],
-                                    axis=1)[:, 0]
 
     def _kv_map(row, head, j, *pref):
         tables_ref, lens_ref = pref[0], pref[1]
@@ -837,55 +871,34 @@ def _run_decode_fused(q4, k_new, v_new, k_pages, v_pages, tables,
     def _w_map(row, head, j, *pref):
         return head, pref[2][row], 0, 0
 
-    def _scale_map(row, head, j, *pref):
-        tables_ref, lens_ref = pref[0], pref[1]
-        live = jnp.maximum(lens_ref[row], 0) // bs
-        return head, tables_ref[row, jnp.minimum(j, live)]
-
-    def _wscale_map(row, head, j, *pref):
-        return head, pref[2][row]
-
-    def _bscale_map(row, head, j, *pref):
-        return head, pref[4][row]
+    def _row_map(row, head, j, *_):
+        return row, head, 0, 0
 
     in_specs = [
-        pl.BlockSpec((1, 1, rep, d),
-                     lambda row, head, j, *_: (row, head, 0, 0)),
+        pl.BlockSpec((1, 1, rep, d), _row_map),
         pl.BlockSpec((1, 1, bs, d), _kv_map),
         pl.BlockSpec((1, 1, bs, d), _kv_map),
         pl.BlockSpec((1, 1, bs, d), _w_map),
         pl.BlockSpec((1, 1, bs, d), _w_map),
-        pl.BlockSpec((1, 1, d),
-                     lambda row, head, j, *_: (row, head, 0)),
-        pl.BlockSpec((1, 1, d),
-                     lambda row, head, j, *_: (row, head, 0)),
+        pl.BlockSpec((1, 1, 1, d), _row_map),
+        pl.BlockSpec((1, 1, 1, d), _row_map),
     ]
-    args = [tables, lengths, wphys, woff, base_phys, real,
+    args = [tables, lengths, wphys, woff, real,
             q3, k_pages, v_pages, k_pages, v_pages, nk, nv]
     if half:
-        in_specs += [
-            pl.BlockSpec((1, half),
-                         lambda row, head, j, *_: (row, 0)),
-            pl.BlockSpec((1, half),
-                         lambda row, head, j, *_: (row, 0)),
-        ]
-        args += [cos_b.reshape(b, half).astype(jnp.float32),
-                 sin_b.reshape(b, half).astype(jnp.float32)]
-    if quantized:
-        in_specs += [
-            pl.BlockSpec((1, 1), _scale_map),
-            pl.BlockSpec((1, 1), _scale_map),
-            pl.BlockSpec((1, 1), _wscale_map),
-            pl.BlockSpec((1, 1), _wscale_map),
-            pl.BlockSpec((1, 1), _bscale_map),
-            pl.BlockSpec((1, 1), _bscale_map),
-        ]
-        ksf = k_scales.astype(jnp.float32)
-        vsf = v_scales.astype(jnp.float32)
-        args += [ksf, vsf, ksf, vsf, ksf, vsf]
+        # full-width tables for the kernel's lane-aligned rotation:
+        # [cos, cos, 0] and [sin, sin, 0] over head_dim
+        def _full(t):
+            t = t.reshape(b, 1, half).astype(jnp.float32)
+            return jnp.concatenate(
+                [t, t, jnp.zeros((b, 1, d - 2 * half))], axis=-1)
+
+        rope_spec = pl.BlockSpec((1, 1, d),
+                                 lambda row, head, j, *_: (row, 0, 0))
+        in_specs += [rope_spec, rope_spec]
+        args += [_full(cos_b), _full(sin_b)]
     out_specs = [
-        pl.BlockSpec((1, 1, rep, d),
-                     lambda row, head, j, *_: (row, head, 0, 0)),
+        pl.BlockSpec((1, 1, rep, d), _row_map),
         pl.BlockSpec((1, 1, bs, d), _w_map),
         pl.BlockSpec((1, 1, bs, d), _w_map),
     ]
@@ -894,25 +907,30 @@ def _run_decode_fused(q4, k_new, v_new, k_pages, v_pages, tables,
         jax.ShapeDtypeStruct(k_pages.shape, k_pages.dtype),
         jax.ShapeDtypeStruct(v_pages.shape, v_pages.dtype),
     ]
-    # inputs count scalar prefetch first: 6 scalars, then q3 (6),
-    # k_pages read view (7), v_pages (8) — aliased to pool outputs so
-    # unvisited pages persist
-    aliases = {7: 1, 8: 2}
     if quantized:
-        out_specs += [pl.BlockSpec((1, 1), _wscale_map),
-                      pl.BlockSpec((1, 1), _wscale_map)]
-        out_shapes += [jax.ShapeDtypeStruct((hk, _nb_pool), jnp.float32),
-                       jax.ShapeDtypeStruct((hk, _nb_pool), jnp.float32)]
-        # scale read views sit after q3/pools/write-views/nk/nv (+rope)
-        ks_idx = 13 + (2 if half else 0)
-        aliases[ks_idx] = 3
-        aliases[ks_idx + 1] = 4
+        ksf = k_scales.astype(jnp.float32)
+        vsf = v_scales.astype(jnp.float32)
+        base_logical = jnp.clip((lengths - 1) // bs, 0, mb - 1)
+        base_phys = jnp.take_along_axis(tables, base_logical[:, None],
+                                        axis=1)[:, 0]
+        # the scale chain's inputs per (row, head): the write page's
+        # current and the previous page's scales, (b, hk, 1, 4)
+        side = jnp.stack([ksf[:, wphys], vsf[:, wphys],
+                          ksf[:, base_phys], vsf[:, base_phys]],
+                         axis=-1).transpose(1, 0, 2)[:, :, None, :]
+        in_specs += [_row_scale_spec(mb), _row_scale_spec(mb),
+                     pl.BlockSpec((1, 1, 1, 4), _row_map)]
+        args += [_row_page_scales(ksf, tables),
+                 _row_page_scales(vsf, tables), side]
+        out_specs.append(pl.BlockSpec((1, 1, 1, 2), _row_map))
+        out_shapes.append(
+            jax.ShapeDtypeStruct((b, hk, 1, 2), jnp.float32))
     kernel = functools.partial(
         _paged_fused_kernel, bs=bs, rep=rep, scale=scale, nb=mb,
         S=S, half=half,
         qmax=_qmax_for_pool(k_pages.dtype) if quantized else None)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=6,
+        num_scalar_prefetch=5,
         grid=(b, hk, mb),
         in_specs=in_specs,
         out_specs=out_specs,
@@ -922,18 +940,28 @@ def _run_decode_fused(q4, k_new, v_new, k_pages, v_pages, tables,
             pltpu.VMEM((d, rep), jnp.float32),       # transposed acc
         ],
     )
-    outs = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=out_shapes,
-        input_output_aliases=aliases,
-        interpret=interpret,
-    )(*args)
+    with jax.named_scope("paged_decode_fused"):
+        outs = pl.pallas_call(
+            kernel,
+            grid_spec=grid_spec,
+            out_shape=out_shapes,
+            # inputs count scalar prefetch first: 5 scalars, then q3
+            # (5), k_pages read view (6), v_pages (7) — aliased to the
+            # pool outputs so unvisited pages persist
+            input_output_aliases={6: 1, 7: 2},
+            interpret=interpret,
+        )(*args)
     o3 = outs[0].reshape(b, hk, rep, 1, d) \
         .transpose(0, 3, 1, 2, 4).reshape(b, 1, h, d)
-    if quantized:
-        return (o3, outs[1], outs[2], outs[3], outs[4])
-    return o3, outs[1], outs[2]
+    if not quantized:
+        return o3, outs[1], outs[2]
+    # the write pages' new scales land in the pool-wide arrays here
+    # (kv_heads floats per row; rows that did not write carry their
+    # page's old scale back, so the scatter is a no-op for them)
+    ns = outs[3][:, :, 0, :].transpose(1, 0, 2)          # (hk, b, 2)
+    return (o3, outs[1], outs[2],
+            ksf.at[:, wphys].set(ns[..., 0]),
+            vsf.at[:, wphys].set(ns[..., 1]))
 
 
 def _run_decode_fused_sharded(q, k_new, v_new, k_pages, v_pages,
@@ -1063,8 +1091,9 @@ def paged_decode_fused(q, k_new, v_new, k_pages, v_pages, block_tables,
                  and (half == 0 or half % 8 == 0)
                  and (quantized
                       or q.dtype == k_pages.dtype == v_pages.dtype))
-    impl = resolve_impl(implementation, pallas_ok=pallas_ok)
-    if impl == "xla" or not pallas_ok:
+    impl = resolve_impl(implementation, pallas_ok=pallas_ok,
+                        op="paged_decode_fused")
+    if impl == "xla":
         return paged_decode_fused_reference(
             q, k_new, v_new, k_pages, v_pages, block_tables, lengths,
             max_seq_len=int(max_seq_len), cos_b=cos_b, sin_b=sin_b,
@@ -1161,8 +1190,9 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
     pallas_ok = (bs % 8 == 0 and d % 8 == 0
                  and (quantized
                       or q.dtype == k_pages.dtype == v_pages.dtype))
-    impl = resolve_impl(implementation, pallas_ok=pallas_ok)
-    if impl == "xla" or not pallas_ok:
+    impl = resolve_impl(implementation, pallas_ok=pallas_ok,
+                        op="paged_attention")
+    if impl == "xla":
         return paged_attention_reference(
             q, k_pages, v_pages, block_tables, lengths, scale=scale,
             k_scales=k_scales, v_scales=v_scales)

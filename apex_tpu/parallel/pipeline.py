@@ -473,9 +473,7 @@ def wrap_pipeline_step(
     # ones at 1) count as manual too — nothing is sharded over them,
     # and folding them in lets the common dp × pipe(×1×1) case take
     # the full-manual spelling below.  When the manual set covers the
-    # whole mesh, omit the partial-manual axis_names subset entirely —
-    # full-manual shard_map is the portable spelling (the jax_compat
-    # fallback supports it).
+    # whole mesh, omit the partial-manual axis_names subset entirely.
     manual = frozenset(
         a for a in mesh.axis_names
         if a in (data_axis, axis) or mesh.shape[a] == 1)

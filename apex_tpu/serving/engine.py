@@ -1390,6 +1390,22 @@ class PagedEngine:
         finally:
             self._drafter = drafter
 
+    def compiled_step_text(self, *, prefill: bool = False) -> str:
+        """Compiled text of the steady decode step (width 1) — with
+        ``prefill``, of the mixed prefill step (chunk width) — at the
+        engine's current arguments: what a caller reads to see which
+        kernels are really in the program (``tpu_custom_call`` and the
+        kernels' scope names).  Compiles a second copy of the
+        executable; the retrace budgets are untouched."""
+        runner = self._prefill if prefill else self._decode
+        feed = np.zeros((self.max_slots, self._chunk if prefill else 1),
+                        np.int32)
+        ones = np.ones((self.max_slots,), np.int32)
+        off = np.zeros((self.max_slots,), bool)
+        return runner.lower(
+            self._variables, self.cache, self.state, self._tables,
+            self._cursors, feed, ones, off, off).compile().as_text()
+
     # ------------------------------------------------------------ gauges
     @property
     def chips_per_replica(self) -> int:

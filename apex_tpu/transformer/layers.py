@@ -47,7 +47,21 @@ __all__ = [
     "row_parallel_linear",
     "vocab_parallel_embedding",
     "maybe_constrain",
+    "sharded_param",
 ]
+
+
+def _constrain_to_auto_axes(x, abstract, spec):
+    """Constrain ``x`` to ``spec`` against the ambient abstract mesh,
+    keeping only its Auto (GSPMD-managed) axes: names the mesh lacks or
+    holds Manual (shard_map'ed) drop out; nothing left = ``x`` as is."""
+    auto = {n for n, t in zip(abstract.axis_names, abstract.axis_types)
+            if t == jax.sharding.AxisType.Auto}
+    spec = tuple(s if s in auto else None for s in spec)
+    if all(s is None for s in spec):
+        return x
+    return lax.with_sharding_constraint(
+        x, jax.sharding.PartitionSpec(*spec))
 
 
 def maybe_constrain(x, *spec):
@@ -58,36 +72,21 @@ def maybe_constrain(x, *spec):
     e.g. ``pipe`` inside the pipeline schedule) — are dropped from the
     spec, so TP/SP constraints compose with any surrounding topology.
     """
-    # the ambient-mesh accessors arrived in newer jax; on versions
-    # without them (no jax.set_mesh either) the library-global mesh
-    # below is the only ambient-mesh channel, so falling through IS the
-    # whole old-jax semantics, not a degraded mode
-    get_abstract_mesh = getattr(jax.sharding, "get_abstract_mesh", None)
-    abstract = None if get_abstract_mesh is None else get_abstract_mesh()
+    abstract = jax.sharding.get_abstract_mesh()
     # the abstract-mesh form of the constraint is only legal under a
     # trace; eagerly (e.g. model.init under jax.set_mesh) fall through
     # to the concrete-mesh NamedSharding path below
-    if (abstract is not None and not abstract.empty
-            and isinstance(x, jax.core.Tracer)):
-        # inside jax.set_mesh / shard_map: resolve against the ambient
-        # abstract mesh, keeping only its Auto (GSPMD-managed) axes
-        auto = {n for n, t in zip(abstract.axis_names,
-                                  abstract.axis_types)
-                if t == jax.sharding.AxisType.Auto}
-        spec = tuple(s if s in auto else None for s in spec)
-        if all(s is None for s in spec):
-            return x
-        return lax.with_sharding_constraint(
-            x, jax.sharding.PartitionSpec(*spec))
+    if not abstract.empty and isinstance(x, jax.core.Tracer):
+        # inside jax.set_mesh / shard_map
+        return _constrain_to_auto_axes(x, abstract, spec)
     # eager: prefer the ambient jax.set_mesh mesh (concrete form), then
     # the library-global one.  Under a trace with no ambient abstract
     # mesh (plain jit), jax.sharding.get_mesh() raises — skip straight
     # to the library-global mesh, whose concrete NamedSharding is legal
     # inside jit.
     try:
-        get_ambient_mesh = getattr(jax.sharding, "get_mesh", None)
-        mesh = None if get_ambient_mesh is None else get_ambient_mesh()
-        if mesh is not None and mesh.empty:
+        mesh = jax.sharding.get_mesh()
+        if mesh.empty:
             mesh = None
     except ValueError:
         mesh = None
@@ -107,6 +106,29 @@ def maybe_constrain(x, *spec):
     sharding = jax.sharding.NamedSharding(
         mesh, jax.sharding.PartitionSpec(*spec))
     return lax.with_sharding_constraint(x, sharding)
+
+
+def sharded_param(module, name, init, names, shape, dtype):
+    """``module.param`` for a weight partitioned as ``names``.
+
+    The stored leaf keeps flax's ``nn.with_partitioning`` box (so
+    ``nn.get_partition_spec`` reads the layout off a state), but the
+    in-trace sharding constraint is applied HERE, like
+    :func:`maybe_constrain`: mesh axes the ambient mesh does not have,
+    or holds Manual, drop out of the spec.  flax's own unboxing
+    constrains to the full spec whenever any ambient mesh exists, which
+    the installed jax refuses inside a fully-manual ``shard_map`` — the
+    data-parallel and ZeRO train steps — for every model in the zoo.
+    """
+    boxed = module.param(name, nn.with_partitioning(init, names), shape,
+                         dtype, unbox=False)
+    if not isinstance(boxed, nn.Partitioned):
+        return boxed                  # the caller passed plain leaves
+    value = boxed.unbox(apply_constraint=False)
+    abstract = jax.sharding.get_abstract_mesh()
+    if abstract.empty:                # flax's own rule: no ambient mesh
+        return value
+    return _constrain_to_auto_axes(value, abstract, boxed.names)
 
 
 # --------------------------------------------------------------------- #
@@ -135,9 +157,8 @@ class ColumnParallelLinear(nn.Module):
     @nn.compact
     def __call__(self, x):
         dtype = self.dtype or x.dtype
-        kernel = self.param(
-            "kernel",
-            nn.with_partitioning(self.kernel_init, (None, self.axis)),
+        kernel = sharded_param(
+            self, "kernel", self.kernel_init, (None, self.axis),
             (x.shape[-1], self.features), self.param_dtype)
         if self.sequence_parallel:
             # input arrives sequence-sharded over the tensor axis;
@@ -148,8 +169,8 @@ class ColumnParallelLinear(nn.Module):
             (((x.ndim - 1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         if self.use_bias:
-            bias = self.param(
-                "bias", nn.with_partitioning(self.bias_init, (self.axis,)),
+            bias = sharded_param(
+                self, "bias", self.bias_init, (self.axis,),
                 (self.features,), self.param_dtype)
             y = y + bias.astype(jnp.float32)
         y = y.astype(dtype)
@@ -182,9 +203,8 @@ class RowParallelLinear(nn.Module):
     @nn.compact
     def __call__(self, x):
         dtype = self.dtype or x.dtype
-        kernel = self.param(
-            "kernel",
-            nn.with_partitioning(self.kernel_init, (self.axis, None)),
+        kernel = sharded_param(
+            self, "kernel", self.kernel_init, (self.axis, None),
             (x.shape[-1], self.features), self.param_dtype)
         if self.input_is_parallel:
             x = maybe_constrain(x, "data", *([None] * (x.ndim - 2)),
@@ -223,9 +243,8 @@ class VocabParallelEmbedding(nn.Module):
     embedding_init: Callable = nn.initializers.normal(stddev=0.02)
 
     def setup(self):
-        self.embedding = self.param(
-            "embedding",
-            nn.with_partitioning(self.embedding_init, (self.axis, None)),
+        self.embedding = sharded_param(
+            self, "embedding", self.embedding_init, (self.axis, None),
             (self.num_embeddings, self.features), self.param_dtype)
 
     def __call__(self, ids):

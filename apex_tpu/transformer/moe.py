@@ -29,6 +29,7 @@ import flax.linen as nn
 
 from apex_tpu.core.mesh import TENSOR_AXIS
 from apex_tpu.ops.mlp import resolve_activation
+from apex_tpu.transformer.layers import sharded_param
 
 __all__ = ["MoEConfig", "top_k_gating", "MoEMLP"]
 
@@ -147,25 +148,22 @@ class MoEMLP(nn.Module):
             lambda lg: top_k_gating(lg, cfg.top_k, capacity))(logits)
         aux = jnp.mean(aux)
 
-        part = nn.with_partitioning if cfg.expert_axis else (
-            lambda init, spec: init)
-        w1 = self.param(
-            "w1", part(nn.initializers.he_normal(),
-                       (cfg.expert_axis, None, None)),
-            (e, h, cfg.ffn_size), cfg.param_dtype)
-        w2 = self.param(
-            "w2", part(nn.initializers.he_normal(),
-                       (cfg.expert_axis, None, None)),
-            (e, cfg.ffn_size, h), cfg.param_dtype)
+        def expert_param(name, init, shape):
+            if not cfg.expert_axis:
+                return self.param(name, init, shape, cfg.param_dtype)
+            names = (cfg.expert_axis,) + (None,) * (len(shape) - 1)
+            return sharded_param(self, name, init, names, shape,
+                                 cfg.param_dtype)
+
+        w1 = expert_param("w1", nn.initializers.he_normal(),
+                          (e, h, cfg.ffn_size))
+        w2 = expert_param("w2", nn.initializers.he_normal(),
+                          (e, cfg.ffn_size, h))
         if cfg.use_bias:
-            b1 = self.param(
-                "b1", part(nn.initializers.zeros_init(),
-                           (cfg.expert_axis, None)),
-                (e, cfg.ffn_size), cfg.param_dtype)
-            b2 = self.param(
-                "b2", part(nn.initializers.zeros_init(),
-                           (cfg.expert_axis, None)),
-                (e, h), cfg.param_dtype)
+            b1 = expert_param("b1", nn.initializers.zeros_init(),
+                              (e, cfg.ffn_size))
+            b2 = expert_param("b2", nn.initializers.zeros_init(),
+                              (e, h))
 
         # dispatch: (G,S,E,C) x (G,S,H) -> (G,E,C,H); GSPMD turns the
         # E-sharded contraction into the token all-to-all
@@ -182,10 +180,8 @@ class MoEMLP(nn.Module):
             # SwiGLU-style experts (Mixtral): elementwise gate from a
             # third expert matrix, sharded identically over the
             # expert axis (no bias, as the Llama-family recipe)
-            wg = self.param(
-                "wg", part(nn.initializers.he_normal(),
-                           (cfg.expert_axis, None, None)),
-                (e, h, cfg.ffn_size), cfg.param_dtype)
+            wg = expert_param("wg", nn.initializers.he_normal(),
+                              (e, h, cfg.ffn_size))
             hmid = hmid * jnp.einsum(
                 "gech,ehf->gecf", xin, wg.astype(cfg.dtype),
                 preferred_element_type=jnp.float32)

@@ -24,6 +24,7 @@ from apex_tpu.utils.tracecheck import (
     RetraceError, retrace_guard, trace_event_count,
     reset_trace_event_count,
 )
+from apex_tpu.utils.compile_cache import enable_compile_cache
 from apex_tpu.utils import lockcheck
 from apex_tpu.utils import numcheck
 from apex_tpu.utils import shardcheck
@@ -45,6 +46,7 @@ __all__ = [
     "MetricsWriter", "log_metrics", "namespaced_sink",
     "RetraceError", "retrace_guard", "trace_event_count",
     "reset_trace_event_count",
+    "enable_compile_cache",
     "lockcheck",
     "numcheck",
     "shardcheck",

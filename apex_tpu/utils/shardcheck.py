@@ -241,13 +241,9 @@ def _remove_listener() -> None:
     global _listening
     if not _listening:
         return
-    try:
-        from jax._src import monitoring as _m
-        _m._unregister_event_listener_by_callback(_on_monitoring_event)
-        _m._unregister_event_duration_listener_by_callback(
-            _on_monitoring_duration)
-    except Exception:                      # pragma: no cover - jax drift
-        pass
+    jax.monitoring.unregister_event_listener(_on_monitoring_event)
+    jax.monitoring.unregister_event_duration_listener(
+        _on_monitoring_duration)
     _listening = False
 
 
@@ -283,15 +279,13 @@ def _check_leaves(site: str, declared: Any, actual: Any,
     """Compare ``actual``'s leaves against the structurally-matching
     ``declared`` tree of shardings/specs; record mismatches."""
     try:
-        # tree_leaves_with_path: jax.tree.leaves_with_path only exists
-        # on current jax, the tree_util spelling on 0.4.37 too
         pairs = list(zip(
             jax.tree.leaves(
                 declared,
                 is_leaf=lambda e: isinstance(
                     e, (jax.sharding.Sharding,
                         jax.sharding.PartitionSpec))),
-            jax.tree_util.tree_leaves_with_path(actual)))
+            jax.tree.leaves_with_path(actual)))
     except Exception:                      # pragma: no cover - shape drift
         return
     checked = mismatched = 0

@@ -188,6 +188,11 @@ class _GuardedFunction:
     def __call__(self, *args, **kwargs):
         return self._wrapped(*args, **kwargs)
 
+    def lower(self, *args, **kwargs):
+        """``jax.jit(fn).lower`` of the guarded function (AOT
+        inspection; a signature already traced does not trace again)."""
+        return self._wrapped.lower(*args, **kwargs)
+
     def reset(self) -> None:
         """Zero the count and drop the compiled cache."""
         self.trace_count = 0

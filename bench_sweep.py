@@ -2,7 +2,8 @@
 
 Usage: BENCH_BATCH=32 BENCH_REMAT_POLICY=dots_with_no_batch_dims_saveable
        python bench_sweep.py
-Fresh process per config (HBM is not reclaimed promptly across builds).
+Fresh process per config (each starts from an empty HBM).  Every row
+names the device it ran on.
 """
 
 import json
@@ -14,6 +15,9 @@ import bench
 def main():
     import jax.numpy as jnp
 
+    from apex_tpu.utils import enable_compile_cache
+
+    enable_compile_cache()
     cfg_kw = {
         "remat": os.environ.get("BENCH_REMAT", "1") == "1",
         "remat_policy": os.environ.get("BENCH_REMAT_POLICY",
@@ -38,6 +42,7 @@ def main():
         "window_ms": [round(d * 1e3, 2) for d in dts],
         "samples_per_sec": round(b / dt, 2),
         "finite": finite,
+        **bench.device_fields(),
     }))
 
 

@@ -79,7 +79,7 @@ PAGES = {
                    "apex_tpu.resilience.trainer"],
     "utils": ["apex_tpu.utils.checkpoint", "apex_tpu.utils.profiler",
               "apex_tpu.utils.debug", "apex_tpu.utils.metrics",
-              "apex_tpu.utils.tree", "apex_tpu.utils.jax_compat",
+              "apex_tpu.utils.tree", "apex_tpu.utils.compile_cache",
               "apex_tpu.utils.lockcheck", "apex_tpu.utils.numcheck",
               "apex_tpu.utils.shardcheck"],
     "fp16_utils": ["apex_tpu.fp16_utils"],

@@ -67,6 +67,9 @@ def main():  # graftlint: hot-step
                         "uint8 or float) as the real distribution; "
                         "default: synthetic noise images")
     args = p.parse_args()
+    from apex_tpu.utils import enable_compile_cache
+
+    enable_compile_cache()
 
     gen, disc = Generator(), Discriminator()
     key = jax.random.PRNGKey(0)

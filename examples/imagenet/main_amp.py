@@ -75,6 +75,9 @@ def parse_args():
 
 def main():  # graftlint: hot-step
     args = parse_args()
+    from apex_tpu.utils import enable_compile_cache
+
+    enable_compile_cache()
     mesh = initialize_mesh(data_parallel_size=-1)  # all devices → DP
 
     if args.data:

@@ -35,6 +35,9 @@ def main():
                     help="prefill the prompt in chunks of this many "
                          "tokens (None = auto: single call below 8k)")
     args = ap.parse_args()
+    from apex_tpu.utils import enable_compile_cache
+
+    enable_compile_cache()
 
     import torch
     from transformers import LlamaConfig as HFLlamaConfig

@@ -85,6 +85,9 @@ def main():
                          "planner may spend (0 = all attached "
                          "devices)")
     args = ap.parse_args()
+    from apex_tpu.utils import enable_compile_cache
+
+    enable_compile_cache()
 
     import jax
     import jax.numpy as jnp

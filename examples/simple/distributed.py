@@ -217,6 +217,9 @@ def main():
                     help="1F1B microbatches per step for planned "
                          "pipeline layouts")
     args = ap.parse_args()
+    from apex_tpu.utils import enable_compile_cache
+
+    enable_compile_cache()
     if args.zero_int8 and not args.zero:
         ap.error("--zero-int8 needs --zero 1 or 2 (the int8 wire is "
                  "the ZeRO grad sync's dtype)")

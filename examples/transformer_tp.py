@@ -135,6 +135,9 @@ def main():  # graftlint: hot-step
     p.add_argument("--seq-len", type=int, default=128)
     p.add_argument("--opt-level", default="O2")
     args = p.parse_args()
+    from apex_tpu.utils import enable_compile_cache
+
+    enable_compile_cache()
 
     if args.pp > 1:
         run_pipelined(args)

@@ -1,0 +1,210 @@
+"""Main-path kernels compiled for a described TPU v5e chip.
+
+No chip is attached here: ``get_topology_desc`` describes one and the
+TPU compiler that ships with jax compiles for it, raising what the
+chip's compiler would raise (tiling, fast-memory and partitioning
+refusals that interpret mode cannot see).  A compile that passes is
+not a chip run — ``chip_smoke.py`` is the run.
+
+Rules this file keeps (on-chip-measurement guide §2): the topology is
+described inside a module-scoped, non-autouse fixture of THIS file —
+only one process may load the TPU library, and under xdist every
+worker imports every test file — so nothing topology-related happens
+at import time, in ``skipif`` or in ``parametrize`` arguments; compiles
+run in the test's own process, with the persistent compile cache off
+(an entry compiled for a described chip cannot be read back here).
+All chip compiles live in this one file so one worker owns the library.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from apex_tpu.ops import (fused_attention, fused_layer_norm,
+                          fused_rms_norm)
+from apex_tpu.ops import fused_sampling as fs
+from apex_tpu.ops.paged_attention import (paged_attention,
+                                          paged_decode_fused)
+
+bf16 = jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:            # no TPU compiler in this install
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def compile_for_chip(topo):
+    """``compile_for_chip(fn, *shapes)`` -> compiled text, for chip 0."""
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def run(fn, *shapes):
+        args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+                for s, d in shapes]
+        return jax.jit(fn).lower(*args).compile().as_text()
+
+    return run
+
+
+# ----------------------------------------------------------- norms
+@pytest.mark.parametrize("op,rows,width", [
+    ("layer_norm", 8192, 1024),       # BERT-Large b16 x s512 rows
+    ("rms_norm", 2048, 4096),         # Mistral-7B hidden
+])
+def test_norm_fwd_bwd(compile_for_chip, op, rows, width):
+    if op == "layer_norm":
+        def loss(x, w, b):
+            return jnp.sum(fused_layer_norm(
+                x, w, b, implementation="pallas").astype(jnp.float32))
+        shapes = [((rows, width), bf16), ((width,), jnp.float32),
+                  ((width,), jnp.float32)]
+        argnums = (0, 1, 2)
+    else:
+        def loss(x, w):
+            return jnp.sum(fused_rms_norm(
+                x, w, implementation="pallas").astype(jnp.float32))
+        shapes = [((rows, width), bf16), ((width,), jnp.float32)]
+        argnums = (0, 1)
+    text = compile_for_chip(jax.value_and_grad(loss, argnums), *shapes)
+    assert "tpu_custom_call" in text
+
+
+# ------------------------------------------------------- flash attn
+@pytest.mark.parametrize("b,s,h,hk,d,window", [
+    (16, 512, 16, 16, 64, None),      # BERT-Large
+    (1, 8192, 32, 8, 128, 4096),      # Mistral-7B, banded causal
+])
+def test_flash_attention_fwd_bwd(compile_for_chip, b, s, h, hk, d,
+                                 window):
+    causal = window is not None
+
+    def loss(q, k, v):
+        return jnp.sum(fused_attention(
+            q, k, v, causal=causal, window=window,
+            implementation="pallas").astype(jnp.float32))
+
+    text = compile_for_chip(
+        jax.value_and_grad(loss, (0, 1, 2)),
+        ((b, s, h, d), bf16), ((b, s, hk, d), bf16),
+        ((b, s, hk, d), bf16))
+    assert "tpu_custom_call" in text
+
+
+# ------------------------------------------- paged pool (Mistral-7B)
+B, H, HK, D = 16, 32, 8, 128          # slots, q heads, kv heads, head dim
+MAX_SEQ = 4096
+POOL_TOKENS = 32768
+
+
+def _pool(bs, dtype):
+    nb = POOL_TOKENS // bs + 1
+    return (((HK, nb, bs, D), dtype), ((HK, nb, bs, D), dtype),
+            ((B, MAX_SEQ // bs), jnp.int32), ((B,), jnp.int32)), nb
+
+
+@pytest.mark.parametrize("s", [1, 32])        # decode, prefill chunk
+@pytest.mark.parametrize("bs", [16, 128])     # engine default, MXU-wide
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+def test_paged_attention(compile_for_chip, s, bs, kv):
+    quant = kv == "int8"
+    pool, nb = _pool(bs, jnp.int8 if quant else bf16)
+    shapes = [((B, s, H, D), bf16), *pool]
+    if quant:
+        shapes += [((HK, nb), jnp.float32)] * 2
+
+    def fn(q, kp, vp, bt, ln, *scales):
+        ks, vs = scales if scales else (None, None)
+        return paged_attention(q, kp, vp, bt, ln, k_scales=ks,
+                               v_scales=vs, implementation="pallas")
+
+    assert "tpu_custom_call" in compile_for_chip(fn, *shapes)
+
+
+@pytest.mark.parametrize("rope", [False, True])
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+def test_paged_decode_fused(compile_for_chip, rope, kv):
+    bs = 16
+    quant = kv == "int8"
+    pool, nb = _pool(bs, jnp.int8 if quant else bf16)
+    shapes = [((B, 1, H, D), bf16), ((B, 1, HK, D), bf16),
+              ((B, 1, HK, D), bf16), *pool]
+    n_rope = 2 if rope else 0
+    shapes += [((B, 1, 1, D // 2), jnp.float32)] * n_rope
+    if quant:
+        shapes += [((HK, nb), jnp.float32)] * 2 + [((B,), jnp.int32)]
+
+    def fn(q, nk, nv, kp, vp, bt, ln, *rest):
+        kw = {}
+        if rope:
+            kw.update(cos_b=rest[0], sin_b=rest[1])
+        if quant:
+            ks, vs, cl = rest[n_rope:]
+            kw.update(k_scales=ks, v_scales=vs, chunk_lens=cl)
+        return paged_decode_fused(q, nk, nv, kp, vp, bt, ln,
+                                  max_seq_len=MAX_SEQ,
+                                  implementation="pallas", **kw)
+
+    assert "tpu_custom_call" in compile_for_chip(fn, *shapes)
+
+
+# --------------------------------------------------- fused sampling
+def _sample_with_the_kernel(logits, keys, t, k, p):
+    return fs.fused_sample(logits, keys, t, k, p,
+                           implementation="pallas")
+
+
+def _largest_admitted_vocab(rows, dtype):
+    vocab = 128
+    while fs.pallas_envelope_ok(rows, vocab * 2, dtype, vocab * 2):
+        vocab *= 2
+    return vocab
+
+
+@pytest.mark.parametrize("vocab,width", [
+    (32000, 1), (32000, 4),           # Mistral-7B decode / spec verify
+    (None, 1),                        # the envelope's own upper edge
+])
+def test_fused_sample(compile_for_chip, vocab, width):
+    rows = B
+    if vocab is None:
+        vocab = _largest_admitted_vocab(rows, bf16)
+        assert vocab >= 32768
+    assert fs.pallas_envelope_ok(rows * width, vocab, bf16, vocab)
+    lead = (rows,) if width == 1 else (rows, width)
+    text = compile_for_chip(
+        _sample_with_the_kernel, (lead + (vocab,), bf16),
+        (lead + (2,), jnp.uint32),
+        ((rows,), jnp.float32), ((rows,), jnp.int32),
+        ((rows,), jnp.float32))
+    assert "tpu_custom_call" in text
+
+
+def test_explicit_pallas_outside_envelope_raises(compile_for_chip):
+    """What the envelope refuses, an explicit ``"pallas"`` must refuse
+    too — never the reference under the kernel's name."""
+    rows, vocab = B, 2 * _largest_admitted_vocab(B, bf16)
+    assert not fs.pallas_envelope_ok(rows, vocab, bf16, vocab)
+    with pytest.raises(ValueError, match="envelope"):
+        compile_for_chip(
+            _sample_with_the_kernel, ((rows, vocab), bf16),
+            ((rows, 2), jnp.uint32),
+            ((rows,), jnp.float32), ((rows,), jnp.int32),
+            ((rows,), jnp.float32))

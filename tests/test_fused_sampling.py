@@ -227,20 +227,11 @@ class TestThreefryReplay:
     the serving chain-identity contract depends on it."""
 
     def test_gumbel_bits_match(self):
-        from apex_tpu.ops.fused_sampling import (
-            _threefry2x32, _TINY)
+        from apex_tpu.ops.fused_sampling import _gumbel
         keys = jax.vmap(jax.random.PRNGKey)(
             jnp.arange(5, dtype=jnp.uint32) * 13 + 1)
-        half = V // 2
-        c0 = jnp.arange(half, dtype=jnp.uint32)[None, :]
-        r0, r1 = _threefry2x32(keys[:, 0:1], keys[:, 1:2], c0,
-                               c0 + jnp.uint32(half))
-        bits = jnp.concatenate([r0, r1], axis=1)
-        fb = (bits >> jnp.uint32(9)) | jnp.uint32(0x3F800000)
-        floats = jax.lax.bitcast_convert_type(fb, jnp.float32) - 1.0
-        u = jnp.maximum(_TINY,
-                        floats * (jnp.float32(1.0) - _TINY) + _TINY)
-        mine = -jnp.log(-jnp.log(u))
+        pos = jnp.arange(V, dtype=jnp.uint32)[None, :]
+        mine = _gumbel(keys[:, 0:1], keys[:, 1:2], pos)
         ref = jax.vmap(
             lambda k: jax.random.gumbel(k, (V,), jnp.float32))(keys)
         assert jnp.array_equal(mine, ref), (

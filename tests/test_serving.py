@@ -487,7 +487,7 @@ class TestInferenceServer:
 
 
 class TestHandleErrorContract:
-    """RequestHandle.stream/result error taxonomy (docs/resilience.md):
+    """RequestHandle.stream/result error classes (docs/resilience.md):
     TimeoutError = retryable "no token yet"; ServerClosed /
     RequestFailed = terminal.  A shutdown race must never surface as a
     bare timeout."""

@@ -178,26 +178,22 @@ class TestTransferAccounting:
 
 
 # --------------------------------------------------------------------- #
-# the jax_compat check_vma -> check_rep shim (ISSUE-16 satellite): the
-# runtime twin of graftlint's unreplicated-out-spec rule must surface
-# the same-shaped trace-time error on jax 0.4.37 (where the kwarg is
-# check_rep) as on current jax (check_vma) — every call site in the
-# repo writes the current spelling through the shim
+# jax.shard_map's check_vma (ISSUE-16 satellite): the runtime twin of
+# graftlint's unreplicated-out-spec rule — the trace-time error must
+# keep the shape the rule's message quotes
 # --------------------------------------------------------------------- #
 class TestCheckVmaShim:
     def test_divergent_return_with_replicated_out_spec_raises(
             self, mesh8):
-        from apex_tpu.utils import jax_compat
-
         def body(x):
             return x * 2.0        # shard-divergent, no reduction
 
-        sm = jax_compat.shard_map(
+        sm = jax.shard_map(
             body, mesh=mesh8, in_specs=(P("data"),), out_specs=P(),
             check_vma=True)
         with pytest.raises(ValueError) as exc:
             jax.jit(sm)(jnp.arange(8.0))
-        # the rule-3 shape, pinned across jax versions: the error
+        # the rule-3 shape: the error
         # names out_specs and the replication contract it violates
         msg = str(exc.value)
         assert "out_specs" in msg
@@ -205,12 +201,10 @@ class TestCheckVmaShim:
 
     def test_reduction_on_the_return_path_passes_the_check(
             self, mesh8):
-        from apex_tpu.utils import jax_compat
-
         def body(x):
             return jax.lax.psum(x, "data")
 
-        sm = jax_compat.shard_map(
+        sm = jax.shard_map(
             body, mesh=mesh8, in_specs=(P("data"),), out_specs=P(),
             check_vma=True)
         out = jax.jit(sm)(jnp.arange(8.0))
@@ -218,14 +212,11 @@ class TestCheckVmaShim:
         np.testing.assert_allclose(np.asarray(out), [28.0])
 
     def test_check_vma_false_disables_the_check(self, mesh8):
-        # the chaos-soak spelling: check_vma=False must map onto the
-        # old check_rep=False rather than raise on 0.4.37
-        from apex_tpu.utils import jax_compat
-
+        # the chaos-soak spelling
         def body(x):
             return x * 2.0
 
-        sm = jax_compat.shard_map(
+        sm = jax.shard_map(
             body, mesh=mesh8, in_specs=(P("data"),), out_specs=P("data"),
             check_vma=False)
         out = jax.jit(sm)(jnp.arange(8.0))

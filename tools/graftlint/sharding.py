@@ -723,7 +723,7 @@ class _Analysis:
                     f"derived from sharded inputs with no "
                     f"psum/all_gather on the return path — each shard "
                     f"returns a DIFFERENT value; jax's "
-                    f"check_vma/check_rep rejects this at trace time "
+                    f"check_vma rejects this at trace time "
                     f"(see docs/graftlint.md)")
 
         for ret in returns:
@@ -1131,8 +1131,8 @@ class UnreplicatedOutSpec(_ShardingRule):
 
     ``out_specs=P()`` asserts every shard returns the SAME value; a
     return derived from sharded inputs with no psum/all_gather on the
-    path violates that — the shape ``check_vma`` (``check_rep`` on
-    older jax, via ``jax_compat``) rejects at trace time.
+    path violates that — the shape ``check_vma`` rejects at trace
+    time.
     """
 
     name = "unreplicated-out-spec"
