@@ -546,28 +546,30 @@ def _run_fa_fwd(q3, k3, v3, kvb, seed, scale, causal, window, bias_mode,
     if rate > 0.0:
         in_specs.append(_SEED_SPEC)
         args.append(seed)
-    o, lse = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, bq, d), lambda b, *g: (b, im(*g), 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1, bq), lambda b, *g: (b, 0, im(*g)),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, sq, d), q3.dtype),
-            # (bh, 1, sq): middle singleton keeps blocks TPU-tileable
-            jax.ShapeDtypeStruct((bh, 1, sq), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((d, bq), jnp.float32),      # transposed acc
-            pltpu.VMEM((1, bq), jnp.float32),      # m (lane row)
-            pltpu.VMEM((1, bq), jnp.float32),      # l (lane row)
-        ],
-        interpret=interpret,
-    )(*args)
+    # the scope names the kernel in HLO metadata and profiler traces
+    with jax.named_scope("attention.fwd"):
+        o, lse = pl.pallas_call(
+            kernel,
+            grid=grid,
+            in_specs=in_specs,
+            out_specs=[
+                pl.BlockSpec((1, bq, d), lambda b, *g: (b, im(*g), 0),
+                             memory_space=pltpu.VMEM),
+                pl.BlockSpec((1, 1, bq), lambda b, *g: (b, 0, im(*g)),
+                             memory_space=pltpu.VMEM),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((bh, sq, d), q3.dtype),
+                # (bh, 1, sq): middle singleton keeps blocks TPU-tileable
+                jax.ShapeDtypeStruct((bh, 1, sq), jnp.float32),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((d, bq), jnp.float32),      # transposed acc
+                pltpu.VMEM((1, bq), jnp.float32),      # m (lane row)
+                pltpu.VMEM((1, bq), jnp.float32),      # l (lane row)
+            ],
+            interpret=interpret,
+        )(*args)
     return o, lse
 
 
@@ -765,16 +767,18 @@ def _run_fa_bwd(q3, k3, v3, kvb, seed, o3, lse, do3, scale, causal,
         pl.BlockSpec((1, 1, bq), lambda b, *g: (b, 0, im(*g)),
                      memory_space=pltpu.VMEM),
     ]
-    dq = pl.pallas_call(
-        dq_kernel,
-        grid=(bh, n_tiles) if tri else (bh, nb, sk // bk),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, bq, d), lambda b, *g: (b, im(*g), 0),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((bh, sq, d), q3.dtype),
-        scratch_shapes=[pltpu.VMEM((d, bq), jnp.float32)],
-        interpret=interpret,
-    )(*args, do3, lse, delta)
+    # the scope names the kernel in HLO metadata and profiler traces
+    with jax.named_scope("attention.bwd_dq"):
+        dq = pl.pallas_call(
+            dq_kernel,
+            grid=(bh, n_tiles) if tri else (bh, nb, sk // bk),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((1, bq, d), lambda b, *g: (b, im(*g), 0),
+                                   memory_space=pltpu.VMEM),
+            out_shape=jax.ShapeDtypeStruct((bh, sq, d), q3.dtype),
+            scratch_shapes=[pltpu.VMEM((d, bq), jnp.float32)],
+            interpret=interpret,
+        )(*args, do3, lse, delta)
 
     dkv_kernel = functools.partial(
         _fa_bwd_dkv_kernel, scale=scale, causal=causal,
@@ -811,30 +815,32 @@ def _run_fa_bwd(q3, k3, v3, kvb, seed, o3, lse, do3, scale, causal,
         pl.BlockSpec((1, 1, bq), lambda b, *g: (b, 0, im2(*g)),
                      memory_space=pltpu.VMEM),
     ]
-    dk, dv = pl.pallas_call(
-        dkv_kernel,
-        grid=(bh, n_tiles) if tri else (bh, sk // bk, nb),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, bk, d), lambda b, *g: (b, jm2(*g), 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bk, d), lambda b, *g: (b, jm2(*g), 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_shape=[
-            # fp32 only when a cross-head group sum follows (rep > 1);
-            # otherwise write the kv dtype directly (half the HBM bytes)
-            jax.ShapeDtypeStruct(
-                (bh, sk, d), jnp.float32 if rep > 1 else k3.dtype),
-            jax.ShapeDtypeStruct(
-                (bh, sk, d), jnp.float32 if rep > 1 else v3.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((d, bk), jnp.float32),      # transposed dk acc
-            pltpu.VMEM((d, bk), jnp.float32),      # transposed dv acc
-        ],
-        interpret=interpret,
-    )(*args, do3, lse, delta)
+    # the scope names the kernel in HLO metadata and profiler traces
+    with jax.named_scope("attention.bwd_dkv"):
+        dk, dv = pl.pallas_call(
+            dkv_kernel,
+            grid=(bh, n_tiles) if tri else (bh, sk // bk, nb),
+            in_specs=in_specs,
+            out_specs=[
+                pl.BlockSpec((1, bk, d), lambda b, *g: (b, jm2(*g), 0),
+                             memory_space=pltpu.VMEM),
+                pl.BlockSpec((1, bk, d), lambda b, *g: (b, jm2(*g), 0),
+                             memory_space=pltpu.VMEM),
+            ],
+            out_shape=[
+                # fp32 only when a cross-head group sum follows (rep > 1);
+                # otherwise write the kv dtype directly (half the HBM bytes)
+                jax.ShapeDtypeStruct(
+                    (bh, sk, d), jnp.float32 if rep > 1 else k3.dtype),
+                jax.ShapeDtypeStruct(
+                    (bh, sk, d), jnp.float32 if rep > 1 else v3.dtype),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((d, bk), jnp.float32),      # transposed dk acc
+                pltpu.VMEM((d, bk), jnp.float32),      # transposed dv acc
+            ],
+            interpret=interpret,
+        )(*args, do3, lse, delta)
     if rep > 1:
         dk = dk.reshape(bh // rep, rep, sk, d).sum(axis=1)
         dv = dv.reshape(bh // rep, rep, sk, d).sum(axis=1)

@@ -127,25 +127,27 @@ def _run_ln_fwd(x2d, w2d, b2d, eps, rms, interpret, block_rows=None):
         in_specs.append(
             pl.BlockSpec((1, h), lambda i: (0, 0), memory_space=pltpu.VMEM))
         args.append(b2d)
-    y, mu, rstd = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((br, h), lambda i: (i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((br, 1), lambda i: (i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((br, 1), lambda i: (i, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n, h), x2d.dtype),
-            jax.ShapeDtypeStruct((n, 1), jnp.float32),
-            jax.ShapeDtypeStruct((n, 1), jnp.float32),
-        ],
-        interpret=interpret,
-    )(*args)
+    # the scope names the kernel in HLO metadata and profiler traces
+    with jax.named_scope("layer_norm_fwd"):
+        y, mu, rstd = pl.pallas_call(
+            kernel,
+            grid=grid,
+            in_specs=in_specs,
+            out_specs=[
+                pl.BlockSpec((br, h), lambda i: (i, 0),
+                             memory_space=pltpu.VMEM),
+                pl.BlockSpec((br, 1), lambda i: (i, 0),
+                             memory_space=pltpu.VMEM),
+                pl.BlockSpec((br, 1), lambda i: (i, 0),
+                             memory_space=pltpu.VMEM),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((n, h), x2d.dtype),
+                jax.ShapeDtypeStruct((n, 1), jnp.float32),
+                jax.ShapeDtypeStruct((n, 1), jnp.float32),
+            ],
+            interpret=interpret,
+        )(*args)
     return y, mu, rstd
 
 
@@ -160,26 +162,28 @@ def _run_ln_bwd_dx(dy2d, x2d, w2d, mu, rstd, rms, interpret):
     br = pick_block_rows(n, h, op="layer_norm", dtype=x2d.dtype)
     grid = (pl.cdiv(n, br),)
     kernel = functools.partial(_ln_bwd_dx_kernel, rms=rms)
-    dx = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((br, h), lambda i: (i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((br, h), lambda i: (i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, h), lambda i: (0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((br, 1), lambda i: (i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((br, 1), lambda i: (i, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((br, h), lambda i: (i, 0),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((n, h), x2d.dtype),
-        interpret=interpret,
-    )(dy2d, x2d, w2d, mu, rstd)
+    # the scope names the kernel in HLO metadata and profiler traces
+    with jax.named_scope("layer_norm_bwd"):
+        dx = pl.pallas_call(
+            kernel,
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((br, h), lambda i: (i, 0),
+                             memory_space=pltpu.VMEM),
+                pl.BlockSpec((br, h), lambda i: (i, 0),
+                             memory_space=pltpu.VMEM),
+                pl.BlockSpec((1, h), lambda i: (0, 0),
+                             memory_space=pltpu.VMEM),
+                pl.BlockSpec((br, 1), lambda i: (i, 0),
+                             memory_space=pltpu.VMEM),
+                pl.BlockSpec((br, 1), lambda i: (i, 0),
+                             memory_space=pltpu.VMEM),
+            ],
+            out_specs=pl.BlockSpec((br, h), lambda i: (i, 0),
+                                   memory_space=pltpu.VMEM),
+            out_shape=jax.ShapeDtypeStruct((n, h), x2d.dtype),
+            interpret=interpret,
+        )(dy2d, x2d, w2d, mu, rstd)
     return dx
 
 

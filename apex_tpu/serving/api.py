@@ -37,11 +37,20 @@ from apex_tpu.utils.metrics import (
     counters,
     percentile_summary,
 )
+from apex_tpu.utils.profiler import SpanTotals, span
 
 __all__ = ["InferenceServer", "RequestHandle", "ServerClosed",
            "RequestFailed", "ReplicaDraining"]
 
 _SENTINEL = object()
+
+#: the server's spans: ``step`` is one whole iteration of the worker
+#: loop with work (deadline expiry, the scheduler's step, delivery;
+#: id ``step``) — an idle server is the absence of it; ``deliver``
+#: hands the step's tokens to handles and taps (client callbacks run
+#: here)
+SERVE_STEP = "apex/serve/step"
+DELIVER = "apex/serve/deliver"
 
 #: server-side observer a fleet router attaches to a handle:
 #: ``tap(token, finished, error)`` — token events carry ``(tok, fin,
@@ -297,7 +306,13 @@ class InferenceServer:
         # pre-existing race graftlint's concurrency pass flagged)
         self._lat_lock = threading.Lock()
         self._ttft: deque = deque(maxlen=2048)  # graftlint: guarded-by(_lat_lock)
+        self._queue_wait: deque = deque(maxlen=2048)  # graftlint: guarded-by(_lat_lock)
         self._step_times: deque = deque(maxlen=4096)  # graftlint: guarded-by(_lat_lock)
+        # requests that got their first token, and the seconds from
+        # their admission to it (prefill, as the request felt it)
+        self._first_tokens = 0
+        self._prefill_s = 0.0
+        self.spans = SpanTotals((SERVE_STEP, DELIVER))
         #: the exception that killed the worker loop, if any — clients
         #: see ServerClosed; the root cause lives here for post-mortems.
         #: Published under _wakeup together with the _stop flip, so a
@@ -459,59 +474,9 @@ class InferenceServer:
                 if self._draining:
                     self._drain_out()
                     continue            # idle until shutdown()
-                self._expire_deadlines()
-                if not self.scheduler.has_work():
-                    continue                # everything just expired
-                try:
-                    # injected against the ATTEMPT counter, not
-                    # self._steps: a faulted attempt doesn't advance
-                    # the step count, and a step-pinned fault keyed on
-                    # it would re-fire forever and starve recovery
-                    attempt = self._step_attempts
-                    self._step_attempts += 1
-                    faults.inject("serving.step", step=attempt)
-                    t_step0 = time.monotonic()
-                    events = self.scheduler.run_step()
-                    with self._lat_lock:
-                        self._step_times.append(
-                            time.monotonic() - t_step0)
-                except faults.TransientError as exc:
-                    # a retryable step fault: the raiser guarantees
-                    # engine state is intact (host-side failure, raised
-                    # before dispatch), so recovery is slot-local —
-                    # evict the poisoned tenants, requeue each once
-                    self._recover_step(exc)
-                    with self._wakeup:
-                        self._wakeup.notify_all()
-                    continue
-                for req, exc in self.scheduler.take_admit_failures():
-                    failure = RequestFailed(
-                        f"admission failed twice for request "
-                        f"{req.uid}: {exc}")
-                    failure.__cause__ = exc
-                    self._fail_request(req, failure)
-                self._steps += 1
-                now = time.monotonic()
-                if self._window_t0 is None:
-                    self._window_t0 = now
-                for ev in events:
-                    self._tokens_emitted += 1
-                    self._window_tokens += 1
-                    if len(ev.request.tokens) == 1:
-                        # first token of this request (requeued
-                        # continuations keep their prefix, so this
-                        # fires exactly once per request)
-                        with self._lat_lock:
-                            self._ttft.append(
-                                now - ev.request.accepted_at)
-                    handle = self._handles.get(id(ev.request))
-                    if handle is not None:
-                        handle._deliver(ev.token, ev.finished)
-                        if ev.finished:
-                            self._handles.pop(id(ev.request), None)
-                with self._wakeup:
-                    self._wakeup.notify_all()   # queue space freed
-                if self.metrics is not None \
+                with span(self.spans, SERVE_STEP, step=self._steps):
+                    now = self._serve_step()
+                if now is not None and self.metrics is not None \
                         and self._steps % self.metrics_interval == 0:
                     self._emit_metrics(now)
         except BaseException as exc:    # noqa: BLE001 — any engine
@@ -546,6 +511,70 @@ class InferenceServer:
             if self.metrics is not None \
                     and self._steps != self._last_emit_step:
                 self._emit_metrics(time.monotonic())
+
+    def _serve_step(self) -> Optional[float]:
+        """One iteration with work (worker thread): expire deadlines,
+        run the scheduler's step, deliver its tokens.  Returns the
+        step's time, or ``None`` where no step completed (everything
+        expired, or a transient fault was recovered from)."""
+        self._expire_deadlines()
+        if not self.scheduler.has_work():
+            return None                 # everything just expired
+        try:
+            # injected against the ATTEMPT counter, not self._steps: a
+            # faulted attempt doesn't advance the step count, and a
+            # step-pinned fault keyed on it would re-fire forever and
+            # starve recovery
+            attempt = self._step_attempts
+            self._step_attempts += 1
+            faults.inject("serving.step", step=attempt)
+            t_step0 = time.monotonic()
+            events = self.scheduler.run_step()
+            with self._lat_lock:
+                self._step_times.append(time.monotonic() - t_step0)
+        except faults.TransientError as exc:
+            # a retryable step fault: the raiser guarantees engine
+            # state is intact (host-side failure, raised before
+            # dispatch), so recovery is slot-local — evict the
+            # poisoned tenants, requeue each once
+            self._recover_step(exc)
+            with self._wakeup:
+                self._wakeup.notify_all()
+            return None
+        for req, exc in self.scheduler.take_admit_failures():
+            failure = RequestFailed(
+                f"admission failed twice for request "
+                f"{req.uid}: {exc}")
+            failure.__cause__ = exc
+            self._fail_request(req, failure)
+        self._steps += 1
+        now = time.monotonic()
+        if self._window_t0 is None:
+            self._window_t0 = now
+        with span(self.spans, DELIVER):
+            for ev in events:
+                self._tokens_emitted += 1
+                self._window_tokens += 1
+                req = ev.request
+                if len(req.tokens) == 1:
+                    # first token of this request (requeued
+                    # continuations keep their prefix, so this fires
+                    # exactly once per request)
+                    req.first_token_at = now
+                    self._first_tokens += 1
+                    self._prefill_s += now - req.admitted_at
+                    with self._lat_lock:
+                        self._ttft.append(now - req.accepted_at)
+                        self._queue_wait.append(
+                            req.admitted_at - req.accepted_at)
+                handle = self._handles.get(id(req))
+                if handle is not None:
+                    handle._deliver(ev.token, ev.finished)
+                    if ev.finished:
+                        self._handles.pop(id(req), None)
+        with self._wakeup:
+            self._wakeup.notify_all()   # queue space freed
+        return now
 
     def _drain_out(self) -> None:
         """Evict everything for :meth:`begin_drain` (worker thread):
@@ -632,9 +661,10 @@ class InferenceServer:
                     f"expired after {len(req.tokens)} tokens"))
 
     def latency_summary(self) -> Dict[str, float]:
-        """p50/p99 of time-to-first-token and per-step decode latency
-        over the bounded reservoirs (seconds / milliseconds) — the
-        soak-summary numbers; also folded into every metrics
+        """p50/p99 of time-to-first-token, of the wait in the queue
+        before a request's first admission, and of per-step decode
+        latency over the bounded reservoirs (seconds / milliseconds)
+        — the soak-summary numbers; also folded into every metrics
         emission."""
         # snapshot under _lat_lock: the worker thread appends
         # concurrently, and iterating a deque during an append raises
@@ -642,10 +672,13 @@ class InferenceServer:
         # itself must exclude the appender, not just downstream use
         with self._lat_lock:
             ttft = list(self._ttft)
+            queue_wait = list(self._queue_wait)
             step_times = list(self._step_times)
         out: Dict[str, float] = {}
         out.update(percentile_summary(
             ttft, "ttft_p50_s", "ttft_p99_s"))
+        out.update(percentile_summary(
+            queue_wait, "queue_wait_p50_s", "queue_wait_p99_s"))
         out.update(percentile_summary(
             step_times, "step_ms_p50", "step_ms_p99", scale=1e3))
         return out
@@ -737,6 +770,17 @@ class InferenceServer:
             "deadline_expired": self._deadline_expired,
             "drain_evicted": self._drain_evicted,
             "preempts": self.scheduler.preempts,
+            # the program's own measurement (docs/serving.md): span
+            # totals of the worker's three layers, and where requests
+            # changed state
+            "spans": {**self.spans.snapshot(),
+                      **self.scheduler.spans.snapshot(),
+                      **self.engine.spans.snapshot()},
+            "admitted": self.scheduler.admitted,
+            "queue_wait_s": self.scheduler.queue_wait_s,
+            "first_tokens": self._first_tokens,
+            "prefill_s": self._prefill_s,
+            "compiles": sum(self.engine.trace_counts.values()),
             "error": None if error is None else repr(error),
             # chips this ONE replica spans (tensor-parallel paged
             # engine; 1 everywhere else) — the fleet's capacity math

@@ -47,6 +47,7 @@ fully rebuilt at the next admission.
 from __future__ import annotations
 
 import dataclasses
+import time
 from typing import Any, List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
@@ -66,9 +67,24 @@ from apex_tpu.ops.paged_attention import tp_head_shards
 from apex_tpu.serving import cache as slot_cache
 from apex_tpu.utils import tracecheck
 from apex_tpu.utils.metrics import counters
+from apex_tpu.utils.profiler import SpanTotals, span
 
 __all__ = ["Engine", "PagedEngine", "StepOutput", "sample_dynamic",
            "prompt_lookup_draft", "DEFAULT_BUCKETS", "tp_mesh"]
+
+#: the engines' spans (``apex_tpu.utils.profiler.span``): one engine
+#: step, named by the program it runs, and its four parts — ``plan``
+#: (drafts, feed, page allocation), ``dispatch`` (the jitted call:
+#: enqueue only), ``fetch`` (the host's wait for the device and the
+#: copy back) and ``commit`` (host mirrors of the step).  The dense
+#: engine has ``step_decode`` and ``fetch`` only.
+STEP_PREFILL = "apex/engine/step_prefill"
+STEP_DECODE = "apex/engine/step_decode"
+STEP_SPEC = "apex/engine/step_spec"
+PLAN = "apex/engine/plan"
+DISPATCH = "apex/engine/dispatch"
+FETCH = "apex/engine/fetch"
+COMMIT = "apex/engine/commit"
 
 
 def tp_mesh(tp: int, devices=None):
@@ -320,6 +336,7 @@ class Engine:
         slot_cache.validate_cache_tree(self._shapes)
         self.cache = slot_cache.stacked_zeros(self._shapes, max_slots)
         self.state = slot_cache.init_slot_state(max_slots)
+        self.spans = SpanTotals((STEP_DECODE, FETCH))
         self._build()
 
     # ------------------------------------------------------------- jits
@@ -473,10 +490,12 @@ class Engine:
         device; the caller should :meth:`release` it to zero the row).
         The single per-step host sync lives here.
         """
-        self.cache, self.state, toks, finished = self._step(
-            self._variables, self.cache, self.state)
-        # graftlint: unsharded(the engine's single per-step host sync — the scheduler needs the sampled tokens to route)
-        return np.asarray(toks), np.asarray(finished)
+        with span(self.spans, STEP_DECODE):
+            self.cache, self.state, toks, finished = self._step(
+                self._variables, self.cache, self.state)
+            with span(self.spans, FETCH):
+                # graftlint: unsharded(the engine's single per-step host sync — the scheduler needs the sampled tokens to route)
+                return np.asarray(toks), np.asarray(finished)
 
     def release(self, slot: int) -> None:
         """Zero and free ``slot``."""
@@ -838,6 +857,8 @@ class PagedEngine:
         self._cursors = np.zeros((self.max_slots,), np.int32)
         self._tenants: List[Optional[_Tenant]] = [None] * self.max_slots
         self._admit_seq = 0
+        self.spans = SpanTotals((STEP_PREFILL, STEP_DECODE, STEP_SPEC,
+                                 PLAN, DISPATCH, FETCH, COMMIT))
         self._build()
 
     # ------------------------------------------------------------- jits
@@ -1256,6 +1277,7 @@ class PagedEngine:
         one bonus token.  Inactive slots compute garbage into the null
         page.  The single per-step host sync lives here.
         """
+        t_top = time.perf_counter()
         any_prefill = any(rec is not None
                           and rec.fed < rec.prompt.size
                           for rec in self._tenants)
@@ -1263,84 +1285,97 @@ class PagedEngine:
         if not any_prefill and self.spec_tokens > 0:
             drafts = self._plan_drafts()
         any_spec = any(d is not None for d in drafts)
-        w = (self._chunk if any_prefill
-             else 1 + self.spec_tokens if any_spec else 1)
-        feed = np.zeros((self.max_slots, w), np.int32)
-        n_tokens = np.ones((self.max_slots,), np.int32)
-        is_prefill = np.zeros((self.max_slots,), bool)
-        emit = np.zeros((self.max_slots,), bool)
-        preempted: List[int] = []
-        for slot in range(self.max_slots):
-            rec = self._tenants[slot]
-            if rec is None:
-                continue
-            if rec.fed < rec.prompt.size:
-                n = min(w, rec.prompt.size - rec.fed)
-                feed[slot, :n] = rec.prompt[rec.fed:rec.fed + n]
-                n_tokens[slot] = n
-                is_prefill[slot] = True
-                emit[slot] = rec.fed + n >= rec.prompt.size
-            else:
-                emit[slot] = True
-                if drafts[slot] is not None:
-                    d = drafts[slot]
-                    feed[slot, 1:1 + d.size] = d
-                    n_tokens[slot] = 1 + d.size
-            self._extend(slot, int(n_tokens[slot]), preempted)
-        for slot in preempted:
-            feed[slot] = 0
-            n_tokens[slot] = 1
-            is_prefill[slot] = False
-            emit[slot] = False
-            drafts[slot] = None
-        if any_spec:
-            self.cache, self.state, sampled, n_emit, finished = \
-                self._spec(self._variables, self.cache, self.state,
-                           self._tables, self._cursors, feed,
-                           n_tokens, emit)
-            # graftlint: unsharded(the paged engine's single per-step host sync — verified drafts steer host-side cursors)
-            tokens = np.asarray(sampled)
-            # graftlint: unsharded(same fetch — accepted-prefix lengths roll the cursors back over rejected tails)
-            counts = np.asarray(n_emit)
-        else:
-            runner = self._prefill if any_prefill else self._decode
-            self.cache, self.state, toks, finished = runner(
-                self._variables, self.cache, self.state, self._tables,
-                self._cursors, feed, n_tokens, is_prefill, emit)
-            # graftlint: unsharded(the paged engine's single per-step host sync — emitted tokens feed the host tenant table)
-            tokens = np.asarray(toks)[:, None]
-            counts = emit.astype(np.int32)
-        for slot in range(self.max_slots):
-            rec = self._tenants[slot]
-            if rec is None:
-                continue
-            if any_spec:
-                # keep only the verified prefix: the cursor rolls back
-                # over rejected draft tails, whose pool writes are
-                # position-masked garbage the next step overwrites
-                kept = int(counts[slot])
-                rec.cursor += kept
-                proposed = int(n_tokens[slot]) - 1
-                if proposed > 0:
-                    self.spec_proposed += proposed
-                    self.spec_accepted += max(kept - 1, 0)
-            else:
-                n = int(n_tokens[slot])
-                if is_prefill[slot]:
-                    rec.fed += n
-                    if self.share_prefixes:
-                        self._register_blocks(rec)
-                rec.cursor += n
-            # host mirrors of the emission (the drafter's context and
-            # budget cap)
-            kept = int(counts[slot])
-            if kept:
-                rec.emitted += kept
-                rec.gen.extend(int(t) for t in tokens[slot, :kept])
-            self._cursors[slot] = rec.cursor
-        # graftlint: unsharded(finished flags ride the same per-step fetch; the caller releases finished slots)
-        return StepOutput(tokens, np.asarray(finished),
-                          counts > 0, tuple(preempted), counts)
+        # the step's span is named by the program it runs, which the
+        # drafts decide: its seconds (and plan's) count from the top
+        kind = (STEP_SPEC if any_spec
+                else STEP_PREFILL if any_prefill else STEP_DECODE)
+        with span(self.spans, kind, since=t_top):
+            with span(self.spans, PLAN, since=t_top):
+                w = (self._chunk if any_prefill
+                     else 1 + self.spec_tokens if any_spec else 1)
+                feed = np.zeros((self.max_slots, w), np.int32)
+                n_tokens = np.ones((self.max_slots,), np.int32)
+                is_prefill = np.zeros((self.max_slots,), bool)
+                emit = np.zeros((self.max_slots,), bool)
+                preempted: List[int] = []
+                for slot in range(self.max_slots):
+                    rec = self._tenants[slot]
+                    if rec is None:
+                        continue
+                    if rec.fed < rec.prompt.size:
+                        n = min(w, rec.prompt.size - rec.fed)
+                        feed[slot, :n] = rec.prompt[rec.fed:rec.fed + n]
+                        n_tokens[slot] = n
+                        is_prefill[slot] = True
+                        emit[slot] = rec.fed + n >= rec.prompt.size
+                    else:
+                        emit[slot] = True
+                        if drafts[slot] is not None:
+                            d = drafts[slot]
+                            feed[slot, 1:1 + d.size] = d
+                            n_tokens[slot] = 1 + d.size
+                    self._extend(slot, int(n_tokens[slot]), preempted)
+                for slot in preempted:
+                    feed[slot] = 0
+                    n_tokens[slot] = 1
+                    is_prefill[slot] = False
+                    emit[slot] = False
+                    drafts[slot] = None
+            with span(self.spans, DISPATCH):
+                if any_spec:
+                    self.cache, self.state, toks, n_emit, finished = \
+                        self._spec(self._variables, self.cache,
+                                   self.state, self._tables,
+                                   self._cursors, feed, n_tokens, emit)
+                else:
+                    runner = self._prefill if any_prefill else self._decode
+                    self.cache, self.state, toks, finished = runner(
+                        self._variables, self.cache, self.state,
+                        self._tables, self._cursors, feed, n_tokens,
+                        is_prefill, emit)
+            with span(self.spans, FETCH):
+                # graftlint: unsharded(the paged engine's single per-step host sync — emitted tokens feed the host tenant table, verified drafts steer host-side cursors)
+                tokens = np.asarray(toks)
+                # graftlint: unsharded(same fetch — finished flags; the caller releases finished slots)
+                finished = np.asarray(finished)
+                if any_spec:
+                    # graftlint: unsharded(same fetch — accepted-prefix lengths roll the cursors back over rejected tails)
+                    counts = np.asarray(n_emit)
+                else:
+                    tokens = tokens[:, None]
+                    counts = emit.astype(np.int32)
+            with span(self.spans, COMMIT):
+                for slot in range(self.max_slots):
+                    rec = self._tenants[slot]
+                    if rec is None:
+                        continue
+                    if any_spec:
+                        # keep only the verified prefix: the cursor
+                        # rolls back over rejected draft tails, whose
+                        # pool writes are position-masked garbage the
+                        # next step overwrites
+                        kept = int(counts[slot])
+                        rec.cursor += kept
+                        proposed = int(n_tokens[slot]) - 1
+                        if proposed > 0:
+                            self.spec_proposed += proposed
+                            self.spec_accepted += max(kept - 1, 0)
+                    else:
+                        n = int(n_tokens[slot])
+                        if is_prefill[slot]:
+                            rec.fed += n
+                            if self.share_prefixes:
+                                self._register_blocks(rec)
+                        rec.cursor += n
+                    # host mirrors of the emission (the drafter's
+                    # context and budget cap)
+                    kept = int(counts[slot])
+                    if kept:
+                        rec.emitted += kept
+                        rec.gen.extend(int(t) for t in tokens[slot, :kept])
+                    self._cursors[slot] = rec.cursor
+            return StepOutput(tokens, finished, counts > 0,
+                              tuple(preempted), counts)
 
     def release(self, slot: int) -> None:
         """Free ``slot``: pages back to the pool (refcount-decremented

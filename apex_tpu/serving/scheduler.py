@@ -30,8 +30,16 @@ import numpy as np
 from apex_tpu.resilience import faults
 from apex_tpu.serving.engine import StepOutput
 from apex_tpu.utils.metrics import counters
+from apex_tpu.utils.profiler import SpanTotals, span
 
 __all__ = ["Request", "Scheduler", "QueueFull", "StepEvent"]
+
+#: the scheduler's spans: ``admit`` covers one request's admission
+#: (from leaving the queue to owning its slot, ``engine.admit``
+#: included; ids ``uid``, ``prompt_len``), ``route`` what follows
+#: ``engine.step()`` (preempt requeues, token routing, releases)
+ADMIT = "apex/sched/admit"
+ROUTE = "apex/sched/route"
 
 
 class QueueFull(RuntimeError):
@@ -54,6 +62,13 @@ class Request:
     ``retries`` / ``accepted_at`` are serving-loop bookkeeping: how
     many times this request has been requeued after a transient step
     fault, and when it entered the queue (the deadline epoch).
+
+    The other stamps (``time.monotonic()``, ``-1.0`` until set) say
+    when the request changed state, for the client that holds it:
+    ``enqueued_at`` — when it last entered the queue (submission, or
+    a requeue after a preempt or a fault); ``admitted_at`` — when it
+    was first given a slot; ``first_token_at`` — when the serving
+    loop handed over its first token.
     """
 
     prompt: np.ndarray
@@ -68,6 +83,9 @@ class Request:
     tokens: List[int] = dataclasses.field(default_factory=list)
     retries: int = 0
     accepted_at: float = -1.0
+    enqueued_at: float = -1.0
+    admitted_at: float = -1.0
+    first_token_at: float = -1.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -102,6 +120,11 @@ class Scheduler:
         self._admit_failures: List[Tuple[Request, BaseException]] = []
         #: block-exhaustion preemptions requeued so far (paged engine)
         self.preempts = 0
+        #: admissions so far (re-admissions after a preempt or a fault
+        #: included) and the seconds they had waited in the queue
+        self.admitted = 0
+        self.queue_wait_s = 0.0
+        self.spans = SpanTotals((ADMIT, ROUTE))
 
     # ------------------------------------------------------------ intake
     def submit(self, request: Request) -> Request:
@@ -125,7 +148,7 @@ class Scheduler:
                     f"request queue at capacity "
                     f"({self.queue_capacity}); retry after a drain")
             request.uid = next(self._uid)
-            request.accepted_at = time.monotonic()
+            request.accepted_at = request.enqueued_at = time.monotonic()
             self._queue.append(request)
         return request
 
@@ -153,6 +176,7 @@ class Scheduler:
         request.prompt = prompt
         request.max_new_tokens = budget
         with self._lock:
+            request.enqueued_at = time.monotonic()
             self._queue.appendleft(request)
 
     def expire_queued(self, now: Optional[float] = None) -> List[Request]:
@@ -229,28 +253,35 @@ class Scheduler:
                     counters.inc("serving.admit_blocked")
                     break
                 req = self._queue.popleft()
-            try:
-                faults.inject("serving.admit")
-                self.engine.admit(
-                    slot, req.prompt,
-                    max_new_tokens=req.max_new_tokens,
-                    temperature=req.temperature,
-                    top_k=req.top_k or 0,
-                    top_p=req.top_p,
-                    eos_id=req.eos_id,
-                    seed=req.seed)
-            except faults.TransientError as exc:
-                counters.inc("serving.admit_fault")
-                if req.retries < 1:
-                    req.retries += 1
-                    with self._lock:
-                        self._queue.appendleft(req)
-                else:
-                    self._admit_failures.append((req, exc))
-                # don't spin on the same request within one boundary —
-                # the retry happens at the next step
-                break
-            self._slots[slot] = req
+            with span(self.spans, ADMIT, uid=req.uid,
+                      prompt_len=int(req.prompt.shape[0])):
+                try:
+                    faults.inject("serving.admit")
+                    self.engine.admit(
+                        slot, req.prompt,
+                        max_new_tokens=req.max_new_tokens,
+                        temperature=req.temperature,
+                        top_k=req.top_k or 0,
+                        top_p=req.top_p,
+                        eos_id=req.eos_id,
+                        seed=req.seed)
+                except faults.TransientError as exc:
+                    counters.inc("serving.admit_fault")
+                    if req.retries < 1:
+                        req.retries += 1
+                        with self._lock:
+                            self._queue.appendleft(req)
+                    else:
+                        self._admit_failures.append((req, exc))
+                    # don't spin on the same request within one
+                    # boundary — the retry happens at the next step
+                    break
+                now = time.monotonic()
+                if req.admitted_at < 0:
+                    req.admitted_at = now
+                self.admitted += 1
+                self.queue_wait_s += now - req.enqueued_at
+                self._slots[slot] = req
             admitted += 1
         return admitted
 
@@ -308,6 +339,12 @@ class Scheduler:
         if self.active_count == 0:
             return []
         out = self.engine.step()
+        with span(self.spans, ROUTE):
+            return self._route(out)
+
+    def _route(self, out) -> List[StepEvent]:
+        """Requeue the step's preempted tenants, route its tokens to
+        their requests, release the slots that finished."""
         if isinstance(out, StepOutput):
             tokens, finished, _emitted, preempted, counts = out
         else:

@@ -179,6 +179,11 @@ class _GuardedFunction:
                 self.signatures.pop()
                 raise
 
+        # the guard's name is the program's: the compiled module reads
+        # ``jit_<name>`` in a profile and the dispatch
+        # ``PjitFunction(<name>)``, not ``counted`` for every guard
+        counted.__name__ = counted.__qualname__ = \
+            self._name.replace(".", "_")
         if self._wrap_jit:
             import jax
             self._wrapped = jax.jit(counted, **self._jit_kwargs)

@@ -618,6 +618,7 @@ class TestLatencySummarySnapshotRace:
         srv = InferenceServer.__new__(InferenceServer)
         srv._lat_lock = threading.Lock()
         srv._ttft = deque(maxlen=2048)
+        srv._queue_wait = deque(maxlen=2048)
         srv._step_times = deque(maxlen=4096)
         for i in range(512):                    # pre-fill: long iteration
             with srv._lat_lock:
@@ -645,6 +646,7 @@ class TestLatencySummarySnapshotRace:
                 out = srv.latency_summary()
                 assert set(out) == {"ttft_p50_s", "ttft_p99_s",
                                     "step_ms_p50", "step_ms_p99"}
+                # (an empty queue-wait reservoir adds no keys)
         finally:
             stop.set()
             t.join()

@@ -1,0 +1,354 @@
+"""The program's own measurement (ISSUE 29): the span primitive, the
+spans at the serving path's layer boundaries, the stamps and counters
+where requests change state, and the names on programs and kernels.
+
+Contracts under test:
+- ``utils.profiler.span`` adds its block's seconds and 1 to a fixed
+  ``SpanTotals`` record with no profiler session, and is a
+  ``TraceAnnotation`` on the profiler's clock with one;
+- ``InferenceServer.health()["spans"]`` holds every span of the three
+  layers, their counts add up to the steps taken and a child's seconds
+  never exceed its parent's;
+- ``admitted`` / ``queue_wait_s`` / ``first_tokens`` / ``prefill_s`` /
+  ``compiles`` count what they say, and every request's stamps are in
+  order;
+- ``health()`` stays safe from another thread while the worker steps;
+- a guarded program is named by its guard, a kernel by its scope;
+- ``docs/serving.md`` and ``PERF.md`` spell every name as the code does.
+"""
+
+import glob
+import pathlib
+import re
+import threading
+import time
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from apex_tpu import utils
+from apex_tpu.models import GPTConfig, GPTModel
+from apex_tpu.ops import fused_attention, fused_layer_norm
+from apex_tpu.serving import (
+    InferenceServer,
+    PagedEngine,
+    Request,
+    Scheduler,
+)
+from apex_tpu.serving import api, engine as engine_mod, scheduler
+from apex_tpu.utils import tracecheck
+from apex_tpu.utils.profiler import SpanTotals, span
+
+ROOT = pathlib.Path(__file__).resolve().parents[1]
+
+ENGINE_STEPS = (engine_mod.STEP_PREFILL, engine_mod.STEP_DECODE,
+                engine_mod.STEP_SPEC)
+ENGINE_PARTS = (engine_mod.PLAN, engine_mod.DISPATCH, engine_mod.FETCH,
+                engine_mod.COMMIT)
+SPANS = ((api.SERVE_STEP, api.DELIVER, scheduler.ADMIT, scheduler.ROUTE)
+         + ENGINE_STEPS + ENGINE_PARTS)
+FIELDS = ("spans", "admitted", "queue_wait_s", "first_tokens",
+          "prefill_s", "compiles")
+
+
+@pytest.fixture(scope="module")
+def gpt():
+    cfg = GPTConfig.tiny(position_embedding="learned", scan_layers=True)
+    model = GPTModel(cfg)
+    params = model.init(jax.random.PRNGKey(0),
+                        jnp.zeros((1, 4), jnp.int32))
+    return model, {"params": params["params"]}
+
+
+def _prompts(model, sizes, seed=11):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(0, model.cfg.vocab_size, size=(n,)).astype(
+        np.int32) for n in sizes]
+
+
+def _moved(after, before, name, field):
+    return after["spans"][name][field] - before["spans"][name][field]
+
+
+# ------------------------------------------------------------ primitive
+class TestSpanPrimitive:
+    def test_totals_count_without_a_profiler_session(self):
+        totals = SpanTotals(("a", "b"))
+        assert totals.snapshot() == {"a": {"n": 0, "s": 0.0},
+                                     "b": {"n": 0, "s": 0.0}}
+        for _ in range(3):
+            with span(totals, "a", uid=7):
+                time.sleep(0.002)
+        snap = totals.snapshot()
+        assert snap["a"]["n"] == 3 and snap["a"]["s"] >= 0.006
+        assert snap["b"] == {"n": 0, "s": 0.0}
+
+    def test_a_span_that_raises_is_still_counted(self):
+        totals = SpanTotals(("a",))
+        with pytest.raises(ZeroDivisionError):
+            with span(totals, "a"):
+                1 / 0
+        assert totals.snapshot()["a"]["n"] == 1
+
+    def test_names_are_fixed_at_construction(self):
+        totals = SpanTotals(("a",))
+        with pytest.raises(KeyError):
+            span(totals, "never_declared")
+        assert list(totals.snapshot()) == ["a"]
+
+    def test_since_counts_from_an_earlier_reading(self):
+        totals = SpanTotals(("a",))
+        t0 = time.perf_counter()
+        time.sleep(0.005)
+        with span(totals, "a", since=t0):
+            pass
+        assert totals.snapshot()["a"]["s"] >= 0.005
+
+
+# ------------------------------------------------- the serving path
+class TestServerSpans:
+    def _serve(self, server, prompts, budget=5):
+        handles = [server.submit(p, max_new_tokens=budget)
+                   for p in prompts]
+        for h in handles:
+            assert len(h.result(timeout=300)) == budget
+        return [h._request for h in handles]
+
+    def test_health_spans_add_up_after_a_drained_run(self, gpt):
+        model, params = gpt
+        server = InferenceServer(
+            model, params, max_slots=2, kv_cache="paged", block_size=8,
+            prefill_chunk=4, pool_tokens=256)
+        prompts = _prompts(model, (3, 9, 14, 6, 11))
+        with server:
+            before = server.health()
+            requests = self._serve(server, prompts)
+            after = server.health()
+            # a second identical batch replays compiled programs
+            self._serve(server, prompts)
+            again = server.health()
+            summary = server.latency_summary()
+            counts = dict(server.engine.trace_counts)
+        assert set(after["spans"]) == set(SPANS)
+        steps = after["steps"] - before["steps"]
+        assert steps > 0
+        moved = {n: _moved(after, before, n, "n") for n in SPANS}
+        secs = {n: _moved(after, before, n, "s") for n in SPANS}
+        assert moved[api.SERVE_STEP] == steps
+        assert moved[api.DELIVER] == steps
+        assert moved[scheduler.ROUTE] == steps
+        assert sum(moved[n] for n in ENGINE_STEPS) == steps
+        assert moved[engine_mod.STEP_PREFILL] > 0
+        assert moved[engine_mod.STEP_DECODE] > 0
+        assert moved[engine_mod.STEP_SPEC] == 0       # drafting is off
+        for part in ENGINE_PARTS:
+            assert moved[part] == steps
+        assert moved[scheduler.ADMIT] == len(prompts)
+        # a child's seconds never exceed its parent's
+        engine_s = sum(secs[n] for n in ENGINE_STEPS)
+        assert 0 < sum(secs[p] for p in ENGINE_PARTS) <= engine_s
+        inside = engine_s + secs[scheduler.ADMIT] + secs[scheduler.ROUTE] \
+            + secs[api.DELIVER]
+        assert inside <= secs[api.SERVE_STEP]
+        # requests changing state
+        assert after["admitted"] - before["admitted"] == len(prompts)
+        assert after["first_tokens"] - before["first_tokens"] \
+            == len(prompts)
+        assert after["queue_wait_s"] >= before["queue_wait_s"] >= 0
+        assert after["prefill_s"] > before["prefill_s"] >= 0
+        for r in requests:
+            assert 0 < r.accepted_at <= r.enqueued_at <= r.admitted_at \
+                <= r.first_token_at
+        # compilations: what the guards counted, none in steady state
+        assert after["compiles"] == sum(counts.values()) == 4
+        assert again["compiles"] == after["compiles"]
+        assert {"queue_wait_p50_s", "queue_wait_p99_s"} <= set(summary)
+        assert 0 <= summary["queue_wait_p50_s"] \
+            <= summary["queue_wait_p99_s"] <= summary["ttft_p99_s"]
+
+    def test_preempt_readmissions_are_counted(self, gpt):
+        model, params = gpt
+        server = InferenceServer(
+            model, params, max_slots=2, kv_cache="paged", block_size=8,
+            prefill_chunk=4, pool_tokens=64, admit_headroom=0)
+        p1, p2 = _prompts(model, (20, 22), seed=7)
+        with server:
+            h1 = server.submit(p1, max_new_tokens=30)
+            h2 = server.submit(p2, max_new_tokens=28)
+            assert len(h1.result(timeout=300)) == 30
+            assert len(h2.result(timeout=300)) == 28
+            health = server.health()
+        assert health["preempts"] >= 1
+        assert health["admitted"] == 2 + health["preempts"]
+        assert health["first_tokens"] == 2
+        assert health["spans"][scheduler.ADMIT]["n"] == health["admitted"]
+        for r in (h1._request, h2._request):
+            # a requeue moves enqueued_at, never the first admission
+            assert r.accepted_at <= r.admitted_at <= r.first_token_at
+            assert r.enqueued_at >= r.accepted_at
+
+    def test_a_drafted_step_is_named_by_its_program(self, gpt):
+        model, params = gpt
+        engine = PagedEngine(model, params, max_slots=2, block_size=8,
+                             prefill_chunk=4, pool_tokens=256,
+                             spec_tokens=2, spec_ngram=2)
+        # every drafting step finds a draft, whatever the context
+        engine._drafter = lambda context, cap, ngram: \
+            np.zeros((cap,), np.int32)
+        sched = Scheduler(engine)
+        engine.warmup()
+        before = engine.spans.snapshot()
+        sched.submit(Request(prompt=np.arange(6, dtype=np.int32),
+                             max_new_tokens=8))
+        sched.drain()
+        after = engine.spans.snapshot()
+        moved = {n: after[n]["n"] - before[n]["n"] for n in after}
+        assert moved[engine_mod.STEP_SPEC] > 0
+        assert moved[engine_mod.STEP_PREFILL] == 2       # 6 tokens by 4
+        assert sum(moved[n] for n in ENGINE_STEPS) \
+            == moved[engine_mod.PLAN] == moved[engine_mod.COMMIT]
+        assert sched.admitted == 1 and sched.queue_wait_s >= 0
+
+    def test_dense_engine_has_step_decode_and_fetch_only(self, gpt):
+        model, params = gpt
+        server = InferenceServer(model, params, max_slots=2,
+                                 prompt_buckets=(8,))
+        with server:
+            before = server.health()
+            self._serve(server, _prompts(model, (3, 5)), budget=4)
+            after = server.health()
+        assert set(after["spans"]) == {
+            api.SERVE_STEP, api.DELIVER, scheduler.ADMIT,
+            scheduler.ROUTE, engine_mod.STEP_DECODE, engine_mod.FETCH}
+        steps = after["steps"] - before["steps"]
+        assert _moved(after, before, engine_mod.STEP_DECODE, "n") == steps
+        assert _moved(after, before, engine_mod.FETCH, "n") == steps
+        assert after["compiles"] == sum(
+            server.engine.trace_counts.values())
+        assert after["first_tokens"] - before["first_tokens"] == 2
+
+    def test_health_from_another_thread_never_raises(self, gpt):
+        model, params = gpt
+        server = InferenceServer(
+            model, params, max_slots=2, kv_cache="paged", block_size=8,
+            prefill_chunk=4, pool_tokens=256)
+        stop = threading.Event()
+        errors, reads = [], [0]
+
+        def monitor():
+            try:
+                while not stop.is_set():
+                    health = server.health()
+                    for rec in health["spans"].values():
+                        assert rec["n"] >= 0 and rec["s"] >= 0.0
+                    server.latency_summary()
+                    reads[0] += 1
+            except BaseException as exc:        # pragma: no cover
+                errors.append(exc)
+
+        with server:
+            t = threading.Thread(target=monitor)
+            t.start()
+            try:
+                self._serve(server, _prompts(model, (5, 12, 7, 9)),
+                            budget=12)
+            finally:
+                stop.set()
+                t.join()
+        assert errors == [] and reads[0] > 0
+
+
+# --------------------------------------------- on the profiler's clock
+def test_trace_holds_the_engine_spans_nested_on_one_line(gpt, tmp_path):
+    from jax.profiler import ProfileData
+
+    model, params = gpt
+    engine = PagedEngine(model, params, max_slots=2, block_size=8,
+                         prefill_chunk=4, pool_tokens=128)
+    sched = Scheduler(engine)
+    engine.warmup()
+    with utils.profiler.trace(str(tmp_path)):
+        req = sched.submit(Request(
+            prompt=np.arange(9, dtype=np.int32), max_new_tokens=4))
+        sched.drain()
+    path = glob.glob(str(
+        tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb"))[-1]
+    lines = []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            evs = [(e.start_ns, e.start_ns + e.duration_ns, e.name,
+                    dict(e.stats))
+                   for e in line.events if e.name.startswith("apex/")]
+            if evs:
+                lines.append(evs)
+    assert len(lines) == 1, "the spans of one thread lie on one line"
+    evs = lines[0]
+    names = {e[2] for e in evs}
+    assert names >= set(ENGINE_PARTS) | {
+        engine_mod.STEP_PREFILL, engine_mod.STEP_DECODE,
+        scheduler.ADMIT, scheduler.ROUTE}
+    steps = [e for e in evs if e[2] in ENGINE_STEPS]
+    parts = [e for e in evs if e[2] in ENGINE_PARTS]
+    assert len(parts) == 4 * len(steps)
+    for s, t, name, _ in parts:
+        assert sum(a <= s and t <= b for a, b, _, _ in steps) == 1, name
+    for a, b, _, _ in steps:
+        mine = [e[2] for e in sorted(parts) if a <= e[0] and e[1] <= b]
+        assert mine == list(ENGINE_PARTS)        # in order, once each
+    admit = [e for e in evs if e[2] == scheduler.ADMIT]
+    assert len(admit) == 1
+    ids = {k: str(v) for k, v in admit[0][3].items()}
+    assert ids.get("uid") == str(req.uid)
+    assert ids.get("prompt_len") == "9"
+
+
+# -------------------------------------------------------------- names
+def test_retrace_guard_names_the_module_after_the_guard():
+    guarded = tracecheck.retrace_guard(lambda x: x + 1, name="a.b")
+    assert "module @jit_a_b " in guarded.lower(jnp.ones(3)).as_text()
+    plain = tracecheck.retrace_guard(lambda x: x + 1, name="decode_step")
+    assert "module @jit_decode_step " in plain.lower(
+        jnp.ones(3)).as_text()
+
+
+@pytest.mark.parametrize("guard,module", [
+    ("_decode", "jit_serving_decode_step"),
+    ("_prefill", "jit_serving_prefill_step"),
+    ("_admit", "jit_serving_admit"),
+    ("_release", "jit_serving_release"),
+])
+def test_paged_engine_programs_are_told_apart(gpt, guard, module):
+    model, params = gpt
+    engine = PagedEngine(model, params, max_slots=2, block_size=8,
+                         prefill_chunk=4, pool_tokens=64)
+    assert getattr(engine, guard)._wrapped.__name__ == module[4:]
+
+
+@pytest.mark.parametrize("scope", [
+    "attention.fwd", "attention.bwd_dq", "attention.bwd_dkv",
+    "layer_norm_fwd", "layer_norm_bwd"])
+def test_kernel_scopes_reach_the_lowered_text(scope):
+    def loss(q, k, v, w, b):
+        o = fused_attention(q, k, v, implementation="pallas_interpret")
+        y = fused_layer_norm(o.reshape(-1, 128), w, b,
+                             implementation="pallas_interpret")
+        return jnp.sum(y.astype(jnp.float32))
+
+    q = jnp.ones((1, 128, 2, 64), jnp.bfloat16)
+    w = jnp.ones((128,), jnp.float32)
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        q, q, q, w, w).as_text(debug_info=True)
+    # ``jit(f)/jvp(attention.fwd)/pallas_call``,
+    # ``.../transpose(jvp(attention.bwd_dq))/pallas_call``
+    assert re.search(rf"[/(]{re.escape(scope)}\)*/pallas_call", text)
+
+
+# --------------------------------------------------------------- docs
+@pytest.mark.parametrize("doc", ["docs/serving.md", "PERF.md"])
+@pytest.mark.parametrize("name", SPANS + FIELDS + (
+    "enqueued_at", "admitted_at", "first_token_at"))
+def test_documents_spell_the_names_as_the_code_does(doc, name):
+    assert f"`{name}`" in (ROOT / doc).read_text()
