@@ -12,11 +12,11 @@ through its block table.
 Why it matters: the dense slab's steady decode reads (or at best
 cond-skips over) a ``max_seq_len`` cache row per slot per step, and its
 HBM *footprint* reserves ``max_slots × max_seq_len`` tokens no matter
-how short the live sequences are.  Here both the footprint and the
-per-step bytes scale with **live tokens**: a slot at position ``L``
-owns ``ceil((L+1)/block_size)`` pages and the kernel touches only
-those (the TPU-serving recipe of "Fine-Tuning and Serving Gemma on
-Cloud TPU", PAPERS.md).
+how short the live sequences are.  Here the footprint, the per-step
+bytes and the kernel's time all scale with **live tokens**: a slot at
+position ``L`` owns ``ceil((L+1)/block_size)`` pages and the kernel
+fetches and visits only those (the TPU-serving recipe of "Fine-Tuning
+and Serving Gemma on Cloud TPU", PAPERS.md).
 
 Layouts::
 
@@ -58,10 +58,11 @@ saturating fp8 cast (``qmax = 448``), with ``scale`` the page region's
 running amax, stored in fp32 ``(kv_heads, num_blocks)`` arrays that
 live beside the block table and travel with the page through sharing /
 CoW / preemption.  Dequant happens **in-register inside the kernel**:
-the per-page scale is a scalar over the ``(block_size, head_dim)``
-tile, so it factors out of the score and value contractions — the
-kernel DMAs 1-byte pages plus one f32 scalar per page per side and
-multiplies after the dot, before the log2-domain online softmax.  The
+the per-page scale is constant over its page's ``block_size`` rows of
+the chunk tile, so it factors out of the score and value contractions
+— the kernel DMAs 1-byte pages, carries the row's page scales as one
+small block, and multiplies by a per-row column after the dot, before
+the log2-domain online softmax.  The
 XLA reference dequantizes the gathered pages explicitly (the parity
 anchor); both paths are exercised by
 ``tests/test_paged_attention.py::TestQuantizedKernel``.  Without
@@ -72,16 +73,26 @@ Two implementations under the :mod:`apex_tpu.ops._dispatch`
 conventions:
 
 - **Pallas TPU kernel** (``implementation="pallas"``): grid
-  ``(batch, kv_heads, pages_per_seq)`` with the page axis sequential;
-  the block table and lengths ride **scalar prefetch**
-  (``pltpu.PrefetchScalarGridSpec``) so the K/V BlockSpec index maps
-  resolve logical→physical pages before each DMA.  Pages past a row's
-  live prefix are *clamped to the last live page* in the index map —
-  consecutive identical block indices skip the DMA — and the body is
-  ``pl.when``-skipped, so per-step bytes scale with the row's live
-  tokens, not ``pages_per_seq``.  Online softmax runs in the log2
-  domain with the transposed (keys-on-sublanes) score tiles of
-  ``ops/attention.py``.
+  ``(batch,)`` — one grid step a row, all kv heads in it — and the
+  sweep over the row's pages is a LOOP INSIDE the kernel.  The pool
+  stays in HBM, unblocked (``memory_space=pl.ANY``); the block table
+  and lengths ride **scalar prefetch**
+  (``pltpu.PrefetchScalarGridSpec``), and the kernel fetches the
+  row's live pages itself, in chunks of ``C = max(1, 128 //
+  block_size)`` pages (about 128 key positions, the MXU's width):
+  one strided ``make_async_copy`` brings a page for all kv heads,
+  K and V double buffered in VMEM, the next chunk's copies started
+  before the current chunk's wait.  The trip count is the row's live
+  chunk count, ``ceil(((length + s - 1) // block_size + 1) / C)``,
+  so a call costs what its live tokens cost.  The sweep used to be a
+  third grid axis over ``pages_per_seq``, dead pages clamped and
+  ``pl.when``-skipped; that saved their bytes and not their time — a
+  grid step on this chip costs 0.2–0.35 µs whether or not it does
+  anything, and 16 × 8 × 256 of them made the decode kernel 10.8 ms
+  against 15 µs of live bytes (PERF.md §6, PR 30).  Online softmax
+  runs in the log2 domain with the transposed (keys-on-sublanes)
+  score tiles of ``ops/attention.py``, one ``(C·block_size,
+  rep·s)`` tile per chunk and kv head.
 - **XLA gather reference** (``implementation="xla"``; golden semantics,
   CPU/GPU fallback): ``k_pages[:, block_tables]`` then a masked fp32
   einsum — bit-comparable to the dense engine's cache attention.
@@ -377,115 +388,222 @@ def _row_page_scales(scales, tables):
     """``(kv_heads, num_blocks)`` page scales -> ``(b, kv_heads, 1,
     pages_per_seq)`` fp32 in each row's LOGICAL page order, gathered
     through the block table in XLA (``b·kv_heads·pages_per_seq``
-    floats) and carried whole per (row, head) grid step.  A ``(1, 1)``
-    block of the pool-wide array is below the TPU's (8, 128) tile —
-    the chip's compiler refuses it — and a scalar per page is too
-    small to be worth a DMA of its own anyway."""
+    floats) and carried whole per row grid step.  A ``(1, 1)`` block
+    of the pool-wide array is below the TPU's (8, 128) tile — the
+    chip's compiler refuses it — and a scalar per page is too small to
+    be worth a DMA of its own anyway."""
     g = scales.astype(jnp.float32)[:, tables]            # (hk, b, mb)
     return g.transpose(1, 0, 2)[:, :, None, :]
 
 
-def _row_scale_spec(mb):
-    return pl.BlockSpec((1, 1, 1, mb),
-                        lambda row, head, j, *_: (row, head, 0, 0))
+def _row_spec(*block):
+    """BlockSpec of one row's ``(1, *block)`` slab of a ``(b, ...)``
+    operand — every blocked operand of both kernels is indexed by the
+    grid's one axis, the row."""
+    zeros = (0,) * len(block)
+    return pl.BlockSpec((1, *block), lambda row, *_: (row, *zeros))
 
 
-def _page_scale(ref, j):
-    """Logical page ``j``'s scale out of a :func:`_row_page_scales`
-    block, as a (1, 1) tile: a one-hot lane select (the sum adds exact
-    zeros, so the value is bitwise the stored scale)."""
-    row = ref[0, 0]                                      # (1, mb)
+def _page_scale(row, j):
+    """Logical page ``j``'s scale out of one (row, head)'s ``(1,
+    pages_per_seq)`` lane row of :func:`_row_page_scales`, as a (1, 1)
+    tile: a one-hot lane select (the sum adds exact zeros, so the
+    value is bitwise the stored scale; a ``j`` past the table reads
+    0)."""
     lane = jax.lax.broadcasted_iota(jnp.int32, row.shape, 1)
     return jnp.sum(jnp.where(lane == j, row, 0.0), axis=1,
                    keepdims=True)
 
 
-def _paged_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, *refs,
-                  bs, s, rep, scale, nb, qmax=None):
-    """One (row, kv-head, page) step of the online-softmax sweep.
+def _chunk_pages(bs):
+    """Pages a sweep chunk fetches: about 128 key positions, the MXU's
+    width on this chip — derived from the block size, never set."""
+    return max(1, 128 // bs)
 
-    Score tiles are TRANSPOSED — (bs, rep·s): key slots on sublanes,
-    (q-head, chunk-offset) lanes — so the softmax statistics are native
-    lane rows and the value accumulation contracts over the page at
-    full MXU shape (the ops/attention.py layout, measured there).
-    Lane ``l`` holds q head ``l // s`` at chunk offset ``l % s``.
 
-    ONE body serves both pool widths (the masking/softmax algebra must
-    never fork).  With ``qmax`` set, ``k_ref``/``v_ref`` hold int8/fp8
-    codes and two extra refs — ``ks_ref``/``vs_ref``, the row's pages'
-    fp32 amax scales in LOGICAL page order (:func:`_row_page_scales`;
-    lane ``j`` is page ``j``'s) — precede the output.
-    The per-page dequant multiplier ``scale/qmax`` is CONSTANT over
-    the ``(bs, d)`` tile, so it factors out of both contractions:
-    codes are cast up (exact — |int8| ≤ 127 and e4m3 fit any float)
-    for the MXU dot, and the product is rescaled in-register before
-    the log2-domain softmax statistics (scores) / the output
-    accumulation (values).
+def _live_pages(length, s, bs, mb):
+    """Pages holding a key some query of the row can see: the prefix
+    up to the newest query's position, clamped to the table (a cursor
+    at or past the table's end sweeps all of it)."""
+    return jnp.minimum(jnp.maximum(length + s - 1, 0) // bs + 1, mb)
+
+
+def _sweep_scratch(hk, bs, d, lanes, pool_dtype):
+    """Scratch of :func:`_sweep_row`: the double-buffered K and V
+    chunk tiles for all kv heads, their DMA semaphores (side × slot),
+    and the per-head online-softmax state."""
+    t = _chunk_pages(bs) * bs
+    return [
+        pltpu.VMEM((2, hk, t, d), pool_dtype),           # K chunks
+        pltpu.VMEM((2, hk, t, d), pool_dtype),           # V chunks
+        pltpu.SemaphoreType.DMA((2, 2)),
+        pltpu.VMEM((hk, 1, lanes), jnp.float32),         # m (lane rows)
+        pltpu.VMEM((hk, 1, lanes), jnp.float32),         # l (lane rows)
+        pltpu.VMEM((hk, d, lanes), jnp.float32),         # transposed acc
+    ]
+
+
+def _sweep_row(tables_ref, row, length, k_hbm, v_hbm, scratch, o_ref, *,
+               s, bs, rep, mb, q_tile, qmax=None, page_scales=None,
+               on_last_chunk=None):
+    """One row's online-softmax sweep over its LIVE pages — the one
+    body behind both kernels (the masking/softmax algebra must never
+    fork).
+
+    The pool stays in HBM (``k_hbm``/``v_hbm``, unblocked); the row's
+    live pages arrive in CHUNKS of :func:`_chunk_pages` pages by the
+    kernel's own DMA — one strided copy brings a page for ALL kv heads
+    — into a double-buffered VMEM tile, the next chunk's copies
+    starting before the current chunk's wait.  The trip count is the
+    row's live chunk count, so a call costs what its live tokens cost,
+    whatever the table's width.  Page slots of the last chunk past the
+    row's last live page re-fetch that page (every buffer row holds
+    real pool data); the position mask makes them unreachable.
+
+    Score tiles are TRANSPOSED — ``(C·bs, rep·s)``: key slots on
+    sublanes, (q-head, chunk-offset) lanes — so the softmax statistics
+    are native lane rows and the value accumulation contracts over the
+    chunk at full MXU shape (the ops/attention.py layout, measured
+    there).  Lane ``l`` holds q head ``l // s`` at chunk offset
+    ``l % s``.  ``q_tile(head)`` returns the head's scaled ``(rep·s,
+    d)`` queries (log2 domain).
+
+    With ``qmax`` set the buffers hold int8/fp8 codes and
+    ``page_scales(head, page)`` returns the page's fp32 K and V amax
+    scales as (1, 1) tiles.  The per-page dequant multiplier
+    ``scale/qmax`` is constant over its page's ``bs`` rows of the
+    chunk tile, so it factors out of both contractions as a
+    ``(C·bs, 1)`` column: codes are cast up (exact — |int8| ≤ 127 and
+    e4m3 fit any float) for the MXU dot, and the product is rescaled
+    in-register before the log2-domain softmax statistics (scores) /
+    the output accumulation (values).
+
+    ``on_last_chunk(slot)`` runs once, after the last chunk has landed
+    in ``slot`` and before it is attended — the fused kernel's
+    prologue hook.
     """
+    kbuf, vbuf, sems, m_ref, l_ref, acc_ref = scratch
+    _slots, hk, t, _d = kbuf.shape
+    c_pages = t // bs
+    lanes = rep * s
+    live = _live_pages(length, s, bs, mb)
+    n_chunks = (live + c_pages - 1) // c_pages
+
+    def copies(c, slot):
+        out = []
+        for i in range(c_pages):
+            phys = tables_ref[row, jnp.minimum(c * c_pages + i, live - 1)]
+            rows = pl.ds(i * bs, bs)
+            out.append(pltpu.make_async_copy(
+                k_hbm.at[:, phys], kbuf.at[slot, :, rows],
+                sems.at[0, slot]))
+            out.append(pltpu.make_async_copy(
+                v_hbm.at[:, phys], vbuf.at[slot, :, rows],
+                sems.at[1, slot]))
+        return out
+
+    for cp in copies(0, 0):
+        cp.start()
+    m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def chunk(c, carry):
+        slot = c % 2
+
+        @pl.when(c + 1 < n_chunks)
+        def _prefetch():
+            for cp in copies(c + 1, 1 - slot):
+                cp.start()
+
+        for cp in copies(c, slot):
+            cp.wait()
+        if on_last_chunk is not None:
+            pl.when(c == n_chunks - 1)(lambda: on_last_chunk(slot))
+
+        k_pos = c * t + jax.lax.broadcasted_iota(
+            jnp.int32, (t, lanes), 0)
+        q_off = jax.lax.broadcasted_iota(
+            jnp.int32, (t, lanes), 1) % s
+        # visible up to the query's own position, and never past the
+        # table (the last chunk's spare slots re-fetch the last live
+        # page: a cursor at or past the table's end must not see them)
+        dead = k_pos > jnp.minimum(length + q_off, mb * bs - 1)
+        for head in range(hk):
+            qs = q_tile(head)
+            kt = kbuf[slot, head]
+            kq = kt if qmax is None else kt.astype(qs.dtype)  # exact
+            sc = jax.lax.dot_general(
+                kq, qs, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)      # (t, lanes)
+            if qmax is not None:
+                per_page = [page_scales(head, c * c_pages + i)
+                            for i in range(c_pages)]
+                kcol, vcol = (
+                    jnp.concatenate(
+                        [jnp.broadcast_to(p[side], (bs, 1))
+                         for p in per_page], axis=0)
+                    * jnp.float32(1.0 / qmax) for side in (0, 1))
+                # in-register dequant: one f32 multiply per score tile
+                sc = sc * kcol
+            sc = jnp.where(dead, _NEG_INF, sc)
+            m_prev = m_ref[head]                         # (1, lanes)
+            m_new = jnp.maximum(m_prev,
+                                jnp.max(sc, axis=0, keepdims=True))
+            # every lane sees >= 1 live key in chunk 0 (position 0 is
+            # always visible), so m is finite from the first chunk on
+            # and exp2(-1e30 - m) underflows to exactly 0 at dead
+            # positions — no explicit dead-row zeroing needed (see
+            # ops/attention.py)
+            p = jnp.exp2(sc - m_new)
+            alpha = jnp.exp2(m_prev - m_new)
+            l_ref[head] = l_ref[head] * alpha + jnp.sum(
+                p, axis=0, keepdims=True)
+            vt = vbuf[slot, head]
+            if qmax is None:
+                vq, pv = vt, p.astype(vt.dtype)
+            else:
+                vq, pv = vt.astype(jnp.float32) * vcol, p
+            acc_ref[head] = acc_ref[head] * alpha + jax.lax.dot_general(
+                vq, pv, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)      # (d, lanes)
+            m_ref[head] = m_new
+        return carry
+
+    jax.lax.fori_loop(0, n_chunks, chunk, None)
+
+    for head in range(hk):
+        l = l_ref[head]
+        l_safe = jnp.where(l == 0.0, 1.0, l)
+        o_ref[0, head] = jnp.transpose(acc_ref[head] / l_safe).astype(
+            o_ref.dtype)
+
+
+def _paged_kernel(tables_ref, lens_ref, q_ref, k_hbm, v_hbm, *refs,
+                  bs, s, rep, scale, mb, qmax=None):
+    """One row of the chunk / verify / plain-decode attend: the shared
+    sweep (:func:`_sweep_row`) over the row's live pages, all kv heads
+    in one grid step.  With ``qmax`` set two extra refs —
+    ``ks_ref``/``vs_ref``, the row's pages' fp32 amax scales in
+    LOGICAL page order (:func:`_row_page_scales`; lane ``j`` is page
+    ``j``'s) — precede the output."""
     if qmax is None:
         ks_ref = vs_ref = None
-        o_ref, m_ref, l_ref, acc_ref = refs
+        o_ref, *scratch = refs
     else:
-        ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = refs
+        ks_ref, vs_ref, o_ref, *scratch = refs
     row = pl.program_id(0)
-    j = pl.program_id(2)
 
-    @pl.when(j == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+    def q_tile(head):
+        return q_ref[0, head] * jnp.asarray(scale * _LOG2E, q_ref.dtype)
 
-    length = lens_ref[row]
-    last_q = length + s - 1
+    def page_scales(head, j):
+        return (_page_scale(ks_ref[0, head], j),
+                _page_scale(vs_ref[0, head], j))
 
-    def _step():
-        qs = q_ref[0, 0] * jnp.asarray(scale * _LOG2E, q_ref.dtype)
-        kq = (k_ref[0, 0] if qmax is None
-              else k_ref[0, 0].astype(qs.dtype))     # exact upcast
-        sc = jax.lax.dot_general(
-            kq, qs, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)      # (bs, rep*s)
-        if qmax is not None:
-            # in-register dequant: one f32 multiply per score tile
-            sc = sc * (_page_scale(ks_ref, j) * jnp.float32(1.0 / qmax))
-        k_pos = j * bs + jax.lax.broadcasted_iota(
-            jnp.int32, (bs, rep * s), 0)
-        q_off = jax.lax.broadcasted_iota(
-            jnp.int32, (bs, rep * s), 1) % s
-        sc = jnp.where(k_pos > length + q_off, _NEG_INF, sc)
-        m_prev = m_ref[:]                            # (1, rep*s)
-        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=0, keepdims=True))
-        # every lane sees >= 1 live key in page 0 (position 0 is always
-        # visible), so m is finite from the first visited page on and
-        # exp2(-1e30 - m) underflows to exactly 0 at dead positions —
-        # no explicit dead-row zeroing needed (see ops/attention.py)
-        p = jnp.exp2(sc - m_new)
-        alpha = jnp.exp2(m_prev - m_new)
-        l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=0, keepdims=True)
-        if qmax is None:
-            vq, pv = v_ref[0, 0], p.astype(v_ref.dtype)
-        else:
-            vq = (v_ref[0, 0].astype(jnp.float32)
-                  * (_page_scale(vs_ref, j) * jnp.float32(1.0 / qmax)))
-            pv = p
-        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-            vq, pv, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)      # (d, rep*s)
-        m_ref[:] = m_new
-
-    # pages wholly past the row's newest query hold nothing visible —
-    # skip the body (their DMA is also skipped: the index map clamps
-    # dead pages to the last live page, and a repeated block index
-    # fetches nothing new)
-    pl.when(j * bs <= last_q)(_step)
-
-    @pl.when(j == nb - 1)
-    def _final():
-        l = l_ref[:]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = jnp.transpose(acc_ref[:] / l_safe).astype(
-            o_ref.dtype)
+    _sweep_row(tables_ref, row, lens_ref[row], k_hbm, v_hbm, scratch,
+               o_ref, s=s, bs=bs, rep=rep, mb=mb, q_tile=q_tile,
+               qmax=qmax, page_scales=page_scales)
 
 
 def _run_paged(q4, k_pages, v_pages, tables, lengths, scale, interpret,
@@ -497,44 +615,24 @@ def _run_paged(q4, k_pages, v_pages, tables, lengths, scale, interpret,
     # (b, s, h, d) -> (b, hk, rep*s, d): lane l = (head r)*s + offset i
     q3 = (q4.reshape(b, s, hk, rep, d)
           .transpose(0, 2, 3, 1, 4).reshape(b, hk, rep * s, d))
-
-    def _kv_map(row, head, j, tables_ref, lens_ref):
-        # logical page -> physical pool block via the prefetched table;
-        # dead pages (past the live prefix) clamp to the last live page
-        # so their DMA is a no-op revisit
-        live = jnp.maximum(lens_ref[row] + s - 1, 0) // bs
-        return head, tables_ref[row, jnp.minimum(j, live)], 0, 0
-
     quantized = k_scales is not None
-    in_specs = [
-        pl.BlockSpec((1, 1, rep * s, d),
-                     lambda row, head, j, *_: (row, head, 0, 0)),
-        pl.BlockSpec((1, 1, bs, d), _kv_map),
-        pl.BlockSpec((1, 1, bs, d), _kv_map),
-    ]
+    pool_spec = pl.BlockSpec(memory_space=pl.ANY)
+    in_specs = [_row_spec(hk, rep * s, d), pool_spec, pool_spec]
     args = [tables, lengths, q3, k_pages, v_pages]
     if quantized:
-        in_specs += [_row_scale_spec(mb), _row_scale_spec(mb)]
+        in_specs += [_row_spec(hk, 1, mb), _row_spec(hk, 1, mb)]
         args += [_row_page_scales(k_scales, tables),
                  _row_page_scales(v_scales, tables)]
-        kernel = functools.partial(
-            _paged_kernel, bs=bs, s=s, rep=rep, scale=scale,
-            nb=mb, qmax=_qmax_for_pool(k_pages.dtype))
-    else:
-        kernel = functools.partial(_paged_kernel, bs=bs, s=s, rep=rep,
-                                   scale=scale, nb=mb)
+    kernel = functools.partial(
+        _paged_kernel, bs=bs, s=s, rep=rep, scale=scale, mb=mb,
+        qmax=_qmax_for_pool(k_pages.dtype) if quantized else None)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, hk, mb),
+        grid=(b,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (1, 1, rep * s, d),
-            lambda row, head, j, *_: (row, head, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((1, rep * s), jnp.float32),   # m (lane row)
-            pltpu.VMEM((1, rep * s), jnp.float32),   # l (lane row)
-            pltpu.VMEM((d, rep * s), jnp.float32),   # transposed acc
-        ],
+        out_specs=_row_spec(hk, rep * s, d),
+        scratch_shapes=_sweep_scratch(hk, bs, d, rep * s,
+                                      k_pages.dtype),
     )
     # the scope names the kernel in HLO metadata and profiler traces
     with jax.named_scope("paged_attention"):
@@ -661,32 +759,33 @@ def paged_decode_fused_reference(q, k_new, v_new, k_pages, v_pages,
 
 
 def _paged_fused_kernel(tables_ref, lens_ref, wphys_ref, woff_ref,
-                        real_ref, q_ref, k_ref, v_ref,
-                        wk_ref, wv_ref, nk_ref, nv_ref, *refs,
-                        bs, rep, scale, nb, S, half, qmax=None):
+                        real_ref, q_ref, k_hbm, v_hbm, nk_ref, nv_ref,
+                        *refs, bs, rep, scale, mb, S, half, qmax=None):
     """The decode sweep of :func:`_paged_kernel` (s = 1) with the
-    step's PROLOGUE folded in: at its first page visit each (row,
-    head) rotates the row's new K (RoPE at the row's absolute
-    position), quantizes it under the monotone running-amax discipline
-    when the pool is coded, and writes it — with its V — into the
-    row's WRITE PAGE tile, which lands back in the pool through the
-    aliased output instead of a separate XLA scatter pass.  The attend
-    then swaps the updated tile (and its updated scale) in when the
-    page sweep reaches the write page, so the new token is visible to
-    its own query (write-then-attend) without the pool round-trip.
+    step's PROLOGUE folded in.  The row's write page IS the last live
+    page of its sweep, so once the last chunk has landed each head
+    rotates the row's new K (RoPE at the row's absolute position),
+    quantizes it under the monotone running-amax discipline when the
+    pool is coded, and patches it — with its V — into the chunk
+    buffer at the write position.  The attend reads the patched
+    buffer, so the new token is visible to its own query
+    (write-then-attend) without a pool round-trip, and one DMA per
+    side copies the patched page for all heads back into the pool
+    through the aliased output instead of a separate XLA scatter pass.
 
     Extra scalar prefetch vs the plain kernel: ``wphys``/``woff`` (the
     write page and offset, null-routed on the host side of the trace)
     and ``real`` (the pad-lane routing bit).  ``half`` is the RoPE
     half-rotation width (0 = non-rotary model).  Outputs gain the
-    write-page views of the pool, each aliased to its input so
-    untouched pages persist.  A coded pool adds ``ks_ref``/``vs_ref``
-    (the row's page scales, :func:`_row_page_scales`), ``side_ref``
-    (the write page's current and the previous page's K/V scales — the
-    running-amax chain's inputs, lanes ``wk, wv, bk, bv``) and
-    ``ns_out`` (the write page's new K/V scales, lanes ``k, v``; the
-    caller scatters them into the pool-wide arrays — ``kv_heads``
-    floats per row).
+    pool, aliased to its input and as unblocked as it, so untouched
+    pages persist; a row that may not write (a pad lane, a cursor at
+    or past ``S``) starts no copy at all.  A coded pool adds
+    ``ks_ref``/``vs_ref`` (the row's page scales,
+    :func:`_row_page_scales`), ``side_ref`` (the write page's current
+    and the previous page's K/V scales — the running-amax chain's
+    inputs, lanes ``wk, wv, bk, bv``) and ``ns_out`` (the write
+    page's new K/V scales, lanes ``k, v``; the caller scatters them
+    into the pool-wide arrays — ``kv_heads`` floats per row).
     """
     rest = list(refs)
     cos_ref = sin_ref = ks_ref = vs_ref = side_ref = ns_out = None
@@ -694,19 +793,21 @@ def _paged_fused_kernel(tables_ref, lens_ref, wphys_ref, woff_ref,
         cos_ref, sin_ref = rest[:2]
         rest = rest[2:]
     if qmax is None:
-        (o_ref, kp_out, vp_out, m_ref, l_ref, acc_ref) = rest
+        o_ref, kp_out, vp_out, wsem, *scratch = rest
     else:
         (ks_ref, vs_ref, side_ref, o_ref, kp_out, vp_out, ns_out,
-         m_ref, l_ref, acc_ref) = rest
+         wsem, *scratch) = rest
+    kbuf, vbuf = scratch[:2]
+    _slots, hk, t, d = kbuf.shape
     row = pl.program_id(0)
-    j = pl.program_id(2)
 
     length = lens_ref[row]
+    wphys = wphys_ref[row]
     woff = woff_ref[row]
     real = real_ref[row] != 0
     write_ok = (length < S) & real
-    wlog = length // bs                 # the write page IS the last
-    # live page of the sweep (s = 1)
+    wpage = _live_pages(length, 1, bs, mb) - 1   # the sweep's last page
+    wslot = wpage % (t // bs)                    # its place in the chunk
 
     def _rot_row(x_row):
         # half-rotation RoPE of (rows, d) at this row's position —
@@ -716,8 +817,9 @@ def _paged_fused_kernel(tables_ref, lens_ref, wphys_ref, woff_ref,
         # tables ``[cos, cos]`` / ``[sin, sin]`` — a slice at lane
         # ``half`` (64 at d=128) is not a shape the chip's compiler
         # takes.
+        if not half:
+            return x_row
         xf = x_row.astype(jnp.float32)
-        d = xf.shape[-1]
         lo = pltpu.roll(xf, half, 1)                 # x[lane - half]
         hi = lo if 2 * half == d else pltpu.roll(xf, d - half, 1)
         c, sn = cos_ref[0], sin_ref[0]
@@ -727,109 +829,84 @@ def _paged_fused_kernel(tables_ref, lens_ref, wphys_ref, woff_ref,
         return out if 2 * half == d else jnp.where(
             lane < 2 * half, out, x_row)
 
-    if half:
-        qt = _rot_row(q_ref[0, 0])
-        k_row = _rot_row(nk_ref[0, 0])
-    else:
-        qt = q_ref[0, 0]
-        k_row = nk_ref[0, 0]
-    v_row = nv_ref[0, 0]                             # (1, d)
+    def _code(x_row, sc):
+        ok = sc > _TINY_SCALE
+        inv = jnp.where(ok, qmax / jnp.maximum(sc, _TINY_SCALE), 0.0)
+        y = jnp.clip(x_row.astype(jnp.float32) * inv, -qmax, qmax)
+        if jnp.issubdtype(jnp.dtype(kbuf.dtype), jnp.integer):
+            y = jnp.round(y)
+        return y.astype(kbuf.dtype)
 
-    # the updated write tile (+ scales): computed at the first visit,
-    # persisted in the aliased out blocks (same index all sweep long)
-    @pl.when(j == 0)
-    def _prologue():
-        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        # the row lands at sublane ``woff`` of the write tile by a
-        # masked select (the compiler has no dynamic in-register slice)
+    def _writeback(slot, i):
+        rows = pl.ds(i * bs, bs)
+        return (pltpu.make_async_copy(kbuf.at[slot, :, rows],
+                                      kp_out.at[:, wphys], wsem.at[0]),
+                pltpu.make_async_copy(vbuf.at[slot, :, rows],
+                                      vp_out.at[:, wphys], wsem.at[1]))
+
+    def _prologue(slot):
+        # the row lands at its sublane of the chunk tile by a masked
+        # select (the compiler has no dynamic in-register slice)
         here = write_ok & (jax.lax.broadcasted_iota(
-            jnp.int32, wk_ref.shape[2:], 0) == woff)
-        if qmax is None:
-            kp_out[0, 0] = jnp.where(here, k_row, wk_ref[0, 0])
-            vp_out[0, 0] = jnp.where(here, v_row, wv_ref[0, 0])
-        else:
-            # monotone running-amax scale chain, width-1 form: the
-            # write page's new scale = max(row amax, previous scale)
-            # where "previous" is the prior page's scale at a fresh
-            # page (offset 0) and the page's own at an append —
-            # bitwise the reference's reset + scatter-max
-            ka = jnp.max(jnp.abs(k_row.astype(jnp.float32)))
-            va = jnp.max(jnp.abs(v_row.astype(jnp.float32)))
-            ka = jnp.where(real, ka, 0.0)
-            va = jnp.where(real, va, 0.0)
-            side = side_ref[0, 0]                    # (1, 4)
-            wks, wvs = side[:, 0:1], side[:, 1:2]
-            bk = jnp.where(length > 0, side[:, 2:3], 0.0)
-            bv = jnp.where(length > 0, side[:, 3:4], 0.0)
-            cur_k = jnp.where(woff == 0, 0.0, wks)
-            cur_v = jnp.where(woff == 0, 0.0, wvs)
-            nks = jnp.maximum(cur_k, jnp.maximum(ka, bk))
-            nvs = jnp.maximum(cur_v, jnp.maximum(va, bv))
+            jnp.int32, (t, d), 0) == wslot * bs + woff)
+        for head in range(hk):
+            k_row = _rot_row(nk_ref[0, head])            # (1, d)
+            v_row = nv_ref[0, head]
+            if qmax is not None:
+                # monotone running-amax scale chain, width-1 form: the
+                # write page's new scale = max(row amax, previous
+                # scale) where "previous" is the prior page's scale at
+                # a fresh page (offset 0) and the page's own at an
+                # append — bitwise the reference's reset + scatter-max
+                ka = jnp.max(jnp.abs(k_row.astype(jnp.float32)))
+                va = jnp.max(jnp.abs(v_row.astype(jnp.float32)))
+                ka = jnp.where(real, ka, 0.0)
+                va = jnp.where(real, va, 0.0)
+                side = side_ref[0, head]                 # (1, 4)
+                wks, wvs = side[:, 0:1], side[:, 1:2]
+                bk = jnp.where(length > 0, side[:, 2:3], 0.0)
+                bv = jnp.where(length > 0, side[:, 3:4], 0.0)
+                cur_k = jnp.where(woff == 0, 0.0, wks)
+                cur_v = jnp.where(woff == 0, 0.0, wvs)
+                nks = jnp.maximum(cur_k, jnp.maximum(ka, bk))
+                nvs = jnp.maximum(cur_v, jnp.maximum(va, bv))
+                k_row, v_row = _code(k_row, nks), _code(v_row, nvs)
+                ns_out[0, head] = jnp.concatenate(
+                    [jnp.where(write_ok, nks, wks),
+                     jnp.where(write_ok, nvs, wvs)], axis=1)
+            kbuf[slot, head] = jnp.where(here, k_row, kbuf[slot, head])
+            vbuf[slot, head] = jnp.where(here, v_row, vbuf[slot, head])
+        # the patched page goes home: one copy a side, all heads (the
+        # source slice is static per branch — a page's place in the
+        # chunk is not a tile boundary for every pool dtype)
+        for i in range(t // bs):
+            @pl.when(write_ok & (wslot == i))
+            def _start():
+                for cp in _writeback(slot, i):
+                    cp.start()
 
-            def _code(x_row, sc):
-                ok = sc > _TINY_SCALE
-                inv = jnp.where(
-                    ok, qmax / jnp.maximum(sc, _TINY_SCALE), 0.0)
-                y = jnp.clip(x_row.astype(jnp.float32) * inv,
-                             -qmax, qmax)
-                if jnp.issubdtype(jnp.dtype(k_ref.dtype),
-                                  jnp.integer):
-                    y = jnp.round(y)
-                return y.astype(k_ref.dtype)
+    def q_tile(head):
+        qt = _rot_row(q_ref[0, head])
+        return qt * jnp.asarray(scale * _LOG2E, qt.dtype)
 
-            kp_out[0, 0] = jnp.where(here, _code(k_row, nks),
-                                     wk_ref[0, 0])
-            vp_out[0, 0] = jnp.where(here, _code(v_row, nvs),
-                                     wv_ref[0, 0])
-            ns_out[0, 0] = jnp.concatenate(
-                [jnp.where(write_ok, nks, wks),
-                 jnp.where(write_ok, nvs, wvs)], axis=1)
+    def page_scales(head, j):
+        # the write page's scale moved with the write (ns_out holds it
+        # from the prologue on; no earlier chunk holds the write page)
+        use_new = write_ok & (j == wpage)
+        new = ns_out[0, head]
+        return (jnp.where(use_new, new[:, 0:1],
+                          _page_scale(ks_ref[0, head], j)),
+                jnp.where(use_new, new[:, 1:2],
+                          _page_scale(vs_ref[0, head], j)))
 
-    last_q = length                     # s == 1
+    _sweep_row(tables_ref, row, length, k_hbm, v_hbm, scratch, o_ref,
+               s=1, bs=bs, rep=rep, mb=mb, q_tile=q_tile, qmax=qmax,
+               page_scales=page_scales, on_last_chunk=_prologue)
 
-    def _step():
-        use_new = (j == wlog) & write_ok
-        qs = qt * jnp.asarray(scale * _LOG2E, qt.dtype)
-        kt = jnp.where(use_new, kp_out[0, 0], k_ref[0, 0])
-        kq = kt if qmax is None else kt.astype(qs.dtype)
-        sc = jax.lax.dot_general(
-            kq, qs, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)          # (bs, rep)
-        if qmax is not None:
-            ksc = jnp.where(use_new, ns_out[0, 0][:, 0:1],
-                            _page_scale(ks_ref, j))
-            sc = sc * (ksc * jnp.float32(1.0 / qmax))
-        k_pos = j * bs + jax.lax.broadcasted_iota(
-            jnp.int32, (bs, rep), 0)
-        sc = jnp.where(k_pos > length, _NEG_INF, sc)
-        m_prev = m_ref[:]
-        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=0, keepdims=True))
-        p = jnp.exp2(sc - m_new)
-        alpha = jnp.exp2(m_prev - m_new)
-        l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=0, keepdims=True)
-        vt = jnp.where(use_new, vp_out[0, 0], v_ref[0, 0])
-        if qmax is None:
-            vq, pv = vt, p.astype(vt.dtype)
-        else:
-            vsc = jnp.where(use_new, ns_out[0, 0][:, 1:2],
-                            _page_scale(vs_ref, j))
-            vq = vt.astype(jnp.float32) * (vsc * jnp.float32(1.0 / qmax))
-            pv = p
-        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-            vq, pv, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)          # (d, rep)
-        m_ref[:] = m_new
-
-    pl.when(j * bs <= last_q)(_step)
-
-    @pl.when(j == nb - 1)
-    def _final():
-        l = l_ref[:]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = jnp.transpose(acc_ref[:] / l_safe).astype(
-            o_ref.dtype)
+    @pl.when(write_ok)
+    def _landed():
+        for cp in _writeback(0, 0):      # any same-sized source waits
+            cp.wait()
 
 
 def _run_decode_fused(q4, k_new, v_new, k_pages, v_pages, tables,
@@ -845,7 +922,7 @@ def _run_decode_fused(q4, k_new, v_new, k_pages, v_pages, tables,
           .transpose(0, 2, 3, 1, 4).reshape(b, hk, rep, d))
     # the new row rides (b, hk, 1, d) and the RoPE tables (b, 1, half):
     # a block's last two dims must equal the array's (or tile by
-    # (8, 128)), so the per-(row, head) row keeps a unit axis before d
+    # (8, 128)), so the per-head row keeps a unit axis before d
     nk = k_new.reshape(b, hk, 1, d)
     nv = v_new.reshape(b, hk, 1, d)
     # the write target, resolved once in-trace (the kernel's scalar
@@ -863,28 +940,11 @@ def _run_decode_fused(q4, k_new, v_new, k_pages, v_pages, tables,
             else (chunk_lens > 0).astype(jnp.int32))
     wphys = jnp.where(real != 0, wphys, 0)
 
-    def _kv_map(row, head, j, *pref):
-        tables_ref, lens_ref = pref[0], pref[1]
-        live = jnp.maximum(lens_ref[row], 0) // bs
-        return head, tables_ref[row, jnp.minimum(j, live)], 0, 0
-
-    def _w_map(row, head, j, *pref):
-        return head, pref[2][row], 0, 0
-
-    def _row_map(row, head, j, *_):
-        return row, head, 0, 0
-
-    in_specs = [
-        pl.BlockSpec((1, 1, rep, d), _row_map),
-        pl.BlockSpec((1, 1, bs, d), _kv_map),
-        pl.BlockSpec((1, 1, bs, d), _kv_map),
-        pl.BlockSpec((1, 1, bs, d), _w_map),
-        pl.BlockSpec((1, 1, bs, d), _w_map),
-        pl.BlockSpec((1, 1, 1, d), _row_map),
-        pl.BlockSpec((1, 1, 1, d), _row_map),
-    ]
+    pool_spec = pl.BlockSpec(memory_space=pl.ANY)
+    in_specs = [_row_spec(hk, rep, d), pool_spec, pool_spec,
+                _row_spec(hk, 1, d), _row_spec(hk, 1, d)]
     args = [tables, lengths, wphys, woff, real,
-            q3, k_pages, v_pages, k_pages, v_pages, nk, nv]
+            q3, k_pages, v_pages, nk, nv]
     if half:
         # full-width tables for the kernel's lane-aligned rotation:
         # [cos, cos, 0] and [sin, sin, 0] over head_dim
@@ -893,15 +953,9 @@ def _run_decode_fused(q4, k_new, v_new, k_pages, v_pages, tables,
             return jnp.concatenate(
                 [t, t, jnp.zeros((b, 1, d - 2 * half))], axis=-1)
 
-        rope_spec = pl.BlockSpec((1, 1, d),
-                                 lambda row, head, j, *_: (row, 0, 0))
-        in_specs += [rope_spec, rope_spec]
+        in_specs += [_row_spec(1, d), _row_spec(1, d)]
         args += [_full(cos_b), _full(sin_b)]
-    out_specs = [
-        pl.BlockSpec((1, 1, rep, d), _row_map),
-        pl.BlockSpec((1, 1, bs, d), _w_map),
-        pl.BlockSpec((1, 1, bs, d), _w_map),
-    ]
+    out_specs = [_row_spec(hk, rep, d), pool_spec, pool_spec]
     out_shapes = [
         jax.ShapeDtypeStruct((b, hk, rep, d), q4.dtype),
         jax.ShapeDtypeStruct(k_pages.shape, k_pages.dtype),
@@ -918,27 +972,24 @@ def _run_decode_fused(q4, k_new, v_new, k_pages, v_pages, tables,
         side = jnp.stack([ksf[:, wphys], vsf[:, wphys],
                           ksf[:, base_phys], vsf[:, base_phys]],
                          axis=-1).transpose(1, 0, 2)[:, :, None, :]
-        in_specs += [_row_scale_spec(mb), _row_scale_spec(mb),
-                     pl.BlockSpec((1, 1, 1, 4), _row_map)]
+        in_specs += [_row_spec(hk, 1, mb), _row_spec(hk, 1, mb),
+                     _row_spec(hk, 1, 4)]
         args += [_row_page_scales(ksf, tables),
                  _row_page_scales(vsf, tables), side]
-        out_specs.append(pl.BlockSpec((1, 1, 1, 2), _row_map))
+        out_specs.append(_row_spec(hk, 1, 2))
         out_shapes.append(
             jax.ShapeDtypeStruct((b, hk, 1, 2), jnp.float32))
     kernel = functools.partial(
-        _paged_fused_kernel, bs=bs, rep=rep, scale=scale, nb=mb,
+        _paged_fused_kernel, bs=bs, rep=rep, scale=scale, mb=mb,
         S=S, half=half,
         qmax=_qmax_for_pool(k_pages.dtype) if quantized else None)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
-        grid=(b, hk, mb),
+        grid=(b,),
         in_specs=in_specs,
         out_specs=out_specs,
-        scratch_shapes=[
-            pltpu.VMEM((1, rep), jnp.float32),       # m
-            pltpu.VMEM((1, rep), jnp.float32),       # l
-            pltpu.VMEM((d, rep), jnp.float32),       # transposed acc
-        ],
+        scratch_shapes=[pltpu.SemaphoreType.DMA((2,)),   # write-back
+                        *_sweep_scratch(hk, bs, d, rep, k_pages.dtype)],
     )
     with jax.named_scope("paged_decode_fused"):
         outs = pl.pallas_call(
@@ -946,8 +997,8 @@ def _run_decode_fused(q4, k_new, v_new, k_pages, v_pages, tables,
             grid_spec=grid_spec,
             out_shape=out_shapes,
             # inputs count scalar prefetch first: 5 scalars, then q3
-            # (5), k_pages read view (6), v_pages (7) — aliased to the
-            # pool outputs so unvisited pages persist
+            # (5), k_pages (6), v_pages (7) — aliased to the pool
+            # outputs, so the one page a row writes moves in place
             input_output_aliases={6: 1, 7: 2},
             interpret=interpret,
         )(*args)
@@ -1025,9 +1076,9 @@ def paged_decode_fused(q, k_new, v_new, k_pages, v_pages, block_tables,
     Pallas kernel, so the row is rotated, coded and written
     in-register on its way into the attend (ISSUE 14's second fusion
     front).  Strictly the WIDTH-1 step: chunked prefill and the
-    speculative verify keep the one-pass XLA scatter (an in-kernel
-    multi-page scatter would re-DMA every page the chunk straddles
-    per (row, head) grid step).
+    speculative verify keep the one-pass XLA scatter (a chunk's rows
+    straddle pages, and only the decode row's write page is always
+    the sweep's last).
 
     ``q`` (b, 1, h, d) and ``k_new``/``v_new`` (b, 1, hk, d) arrive
     UNROTATED; ``cos_b``/``sin_b`` (b, 1, 1, rot/2) are the per-row

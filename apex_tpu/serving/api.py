@@ -798,6 +798,7 @@ class InferenceServer:
             out["live_tokens"] = self.engine.live_tokens
             out["shared_blocks"] = self.engine.shared_blocks
             out["cow_forks"] = self.engine.cow_forks
+            out["kv_pages_live"] = self.engine.kv_pages_live
             out["kv_dtype"] = self.engine.kv_dtype
             out["kv_bits"] = self.engine.kv_bits
             if getattr(self.engine, "spec_tokens", 0):

@@ -818,6 +818,9 @@ class PagedEngine:
         self.cow_forks = 0
         self.spec_proposed = 0
         self.spec_accepted = 0
+        #: pages the attention kernels' sweeps had to visit, summed
+        #: over steps and rows (an empty row sweeps the null page)
+        self.kv_pages_live = 0
         self._headroom = (2 * self.block_size if admit_headroom is None
                           else int(admit_headroom))
         self._variables = dict(params)
@@ -1322,6 +1325,8 @@ class PagedEngine:
                     emit[slot] = False
                     drafts[slot] = None
             with span(self.spans, DISPATCH):
+                self.kv_pages_live += int(
+                    (self._cursors // self.block_size + 1).sum())
                 if any_spec:
                     self.cache, self.state, toks, n_emit, finished = \
                         self._spec(self._variables, self.cache,

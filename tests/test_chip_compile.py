@@ -111,11 +111,11 @@ def test_flash_attention_fwd_bwd(compile_for_chip, b, s, h, hk, d,
 # ------------------------------------------- paged pool (Mistral-7B)
 B, H, HK, D = 16, 32, 8, 128          # slots, q heads, kv heads, head dim
 MAX_SEQ = 4096
-POOL_TOKENS = 32768
+POOLS = [32768, 65536]                # tokens; the serve cells hold 65536
 
 
-def _pool(bs, dtype):
-    nb = POOL_TOKENS // bs + 1
+def _pool(bs, dtype, pool_tokens):
+    nb = pool_tokens // bs + 1
     return (((HK, nb, bs, D), dtype), ((HK, nb, bs, D), dtype),
             ((B, MAX_SEQ // bs), jnp.int32), ((B,), jnp.int32)), nb
 
@@ -123,9 +123,10 @@ def _pool(bs, dtype):
 @pytest.mark.parametrize("s", [1, 32])        # decode, prefill chunk
 @pytest.mark.parametrize("bs", [16, 128])     # engine default, MXU-wide
 @pytest.mark.parametrize("kv", ["bf16", "int8"])
-def test_paged_attention(compile_for_chip, s, bs, kv):
+@pytest.mark.parametrize("pool_tokens", POOLS)
+def test_paged_attention(compile_for_chip, s, bs, kv, pool_tokens):
     quant = kv == "int8"
-    pool, nb = _pool(bs, jnp.int8 if quant else bf16)
+    pool, nb = _pool(bs, jnp.int8 if quant else bf16, pool_tokens)
     shapes = [((B, s, H, D), bf16), *pool]
     if quant:
         shapes += [((HK, nb), jnp.float32)] * 2
@@ -140,10 +141,11 @@ def test_paged_attention(compile_for_chip, s, bs, kv):
 
 @pytest.mark.parametrize("rope", [False, True])
 @pytest.mark.parametrize("kv", ["bf16", "int8"])
-def test_paged_decode_fused(compile_for_chip, rope, kv):
+@pytest.mark.parametrize("pool_tokens", POOLS)
+def test_paged_decode_fused(compile_for_chip, rope, kv, pool_tokens):
     bs = 16
     quant = kv == "int8"
-    pool, nb = _pool(bs, jnp.int8 if quant else bf16)
+    pool, nb = _pool(bs, jnp.int8 if quant else bf16, pool_tokens)
     shapes = [((B, 1, H, D), bf16), ((B, 1, HK, D), bf16),
               ((B, 1, HK, D), bf16), *pool]
     n_rope = 2 if rope else 0

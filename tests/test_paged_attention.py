@@ -22,7 +22,11 @@ Contracts under test:
   against the explicit quantize-dequant XLA reference (decode, GQA,
   ragged, spec-verify chunk, interpret mode), page+scale placement /
   pool-garbage invariance, the stated quantization-error bound vs the
-  float pool, and the scale-argument validation contract.
+  float pool, and the scale-argument validation contract;
+- the in-kernel page sweep (ISSUE 30): both kernels on cursors at
+  every edge of the chunked sweep (page and chunk boundaries, a full
+  table, at and past ``max_seq_len``), a result that does not depend
+  on the table's width, and a ``pallas_call`` grid with no page axis.
 """
 
 import numpy as np
@@ -46,15 +50,19 @@ _KV_DTYPES = [
 ]
 
 
-def _pool_setup(rng, *, b, hk, d, NB, BS, MB, lengths, s, dtype):
+def _pool_setup(rng, *, b, hk, d, NB, BS, MB, lengths, s, dtype,
+                cap=False):
     """Random pool + per-row tables covering ``lengths[i] + s`` tokens
-    with disjoint physical blocks (block 0 left as the null page)."""
+    with disjoint physical blocks (block 0 left as the null page);
+    ``cap`` lets a row run to the table's end and past it."""
     kp = jnp.asarray(rng.normal(size=(hk, NB, BS, d)), dtype)
     vp = jnp.asarray(rng.normal(size=(hk, NB, BS, d)), dtype)
     tables = np.zeros((b, MB), np.int32)
     free = list(range(1, NB))
     for i, L in enumerate(lengths):
         n = -(-(L + s) // BS)
+        if cap:
+            n = min(n, MB)
         assert n <= MB and len(free) >= n, "test pool too small"
         for j in range(n):
             tables[i, j] = free.pop()
@@ -779,3 +787,164 @@ class TestFusedDecodePrologue:
         with pytest.raises(ValueError, match="only apply"):
             paged_decode_fused(q, nk, nv, kp, vp, tables, lengths,
                                max_seq_len=S, k_scales=ks, v_scales=vs)
+
+
+# --------------------------------------------------------------------- #
+# the in-kernel page sweep (ISSUE 30) — chunk edges, table width, grid
+# --------------------------------------------------------------------- #
+def _chunk_positions(bs):
+    from apex_tpu.ops.paged_attention import _chunk_pages
+    return _chunk_pages(bs) * bs
+
+
+def _edge_lengths(bs, S, s=1):
+    """Cursors on every edge the sweep has: an empty slot, one token,
+    either side of a page boundary and of a chunk boundary, a full
+    table, and a cursor at and past ``max_seq_len``."""
+    t = _chunk_positions(bs)
+    assert t + 1 < S - s
+    return [0, 1, bs - 1, bs, t - 1, t, t + 1, S - s, S, S + bs + 3]
+
+
+def _edge_pool(rng, *, lengths, s, hk, d, BS, MB, dtype):
+    """Pool, tables and the live physical pages for ragged rows that
+    may run to (or past) the table's end."""
+    kp, vp, tables = _pool_setup(
+        rng, b=len(lengths), hk=hk, d=d, NB=len(lengths) * MB + 1,
+        BS=BS, MB=MB, lengths=lengths, s=s, dtype=dtype, cap=True)
+    live = tables.ravel()
+    return kp, vp, jnp.asarray(tables), live[live > 0]
+
+
+_POOLS = [(jnp.float32, None), (jnp.bfloat16, None),
+          (jnp.float32, "int8"),
+          pytest.param(jnp.float32, "fp8", marks=pytest.mark.skipif(
+              not hasattr(jnp, "float8_e4m3fn"),
+              reason="no float8_e4m3fn in this jax build"))]
+
+
+class TestSweepEdges:
+    """Both kernels against their XLA references on rows whose live
+    prefix ends on every edge of the chunked sweep."""
+
+    BS, MB, HK, H, D = 8, 24, 2, 4, 32
+
+    @pytest.mark.parametrize("s", [1, 4])
+    @pytest.mark.parametrize("dtype,kv_dtype", _POOLS)
+    def test_chunk_kernel(self, s, dtype, kv_dtype):
+        rng = np.random.default_rng(30)
+        BS, MB = self.BS, self.MB
+        S = BS * MB
+        lengths = _edge_lengths(BS, S, s)
+        kp, vp, tables, _live = _edge_pool(
+            rng, lengths=lengths, s=s, hk=self.HK, d=self.D, BS=BS,
+            MB=MB, dtype=dtype)
+        scales = {}
+        if kv_dtype is not None:
+            kp, vp, ks, vs = quantize_kv_pages(kp, vp, kv_dtype)
+            scales = dict(k_scales=ks, v_scales=vs)
+        q = jnp.asarray(rng.normal(size=(len(lengths), s, self.H, self.D)),
+                        dtype)
+        lens = jnp.asarray(lengths, jnp.int32)
+        ref = paged_attention_reference(q, kp, vp, tables, lens, **scales)
+        out = paged_attention(q, kp, vp, tables, lens,
+                              implementation="pallas_interpret", **scales)
+        tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
+        np.testing.assert_allclose(
+            np.asarray(out, np.float32), np.asarray(ref, np.float32),
+            atol=tol, rtol=tol)
+
+    @pytest.mark.parametrize("dtype,kv_dtype", _POOLS)
+    def test_fused_kernel(self, dtype, kv_dtype):
+        """The attend within tolerance; written pages, codes and
+        scales bitwise on live pages, at every edge — a row at or past
+        ``max_seq_len`` writes nothing live."""
+        from apex_tpu.ops.paged_attention import paged_decode_fused
+        from apex_tpu.ops.rope import rope_cos_sin
+
+        rng = np.random.default_rng(31)
+        BS, MB = self.BS, self.MB
+        S = BS * MB
+        lengths = np.asarray(_edge_lengths(BS, S), np.int32)
+        b = len(lengths)
+        kp, vp, tables, live = _edge_pool(
+            rng, lengths=list(lengths), s=1, hk=self.HK, d=self.D,
+            BS=BS, MB=MB, dtype=dtype)
+        kw = {}
+        if kv_dtype is not None:
+            kp, vp, ks, vs = quantize_kv_pages(kp, vp, kv_dtype)
+            cl = np.ones((b,), np.int32)
+            cl[2] = 0                        # one pad lane: no write
+            kw = dict(k_scales=ks, v_scales=vs,
+                      chunk_lens=jnp.asarray(cl))
+        q = jnp.asarray(rng.normal(size=(b, 1, self.H, self.D)), dtype)
+        nk = jnp.asarray(rng.normal(size=(b, 1, self.HK, self.D)), dtype)
+        nv = jnp.asarray(rng.normal(size=(b, 1, self.HK, self.D)), dtype)
+        cos, sin = rope_cos_sin(S, self.D)
+        pc = np.minimum(lengths[:, None], S - 1)
+        kw.update(cos_b=jnp.asarray(cos[pc][:, :, None, :]),
+                  sin_b=jnp.asarray(sin[pc][:, :, None, :]))
+        args = (q, nk, nv, kp, vp, tables, jnp.asarray(lengths))
+
+        def run(impl):
+            return jax.jit(lambda *a: paged_decode_fused(
+                *a, max_seq_len=S, implementation=impl, **kw))(*args)
+
+        ref, got = run("xla"), run("pallas_interpret")
+        tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
+        np.testing.assert_allclose(
+            np.asarray(got[0], np.float32), np.asarray(ref[0], np.float32),
+            atol=tol, rtol=tol)
+        assert len(got) == len(ref) == (3 if kv_dtype is None else 5)
+        for g, r in zip(got[1:], ref[1:]):
+            np.testing.assert_array_equal(np.asarray(g[:, live]),
+                                          np.asarray(r[:, live]))
+        # the rows at and past max_seq_len left every live page alone
+        own = np.asarray(tables)[-2:].ravel()
+        own = own[own > 0]
+        np.testing.assert_array_equal(np.asarray(got[1][:, own]),
+                                      np.asarray(kp[:, own]))
+
+
+class TestSweepFollowsLiveTokensNotTheTable:
+    """The sweep is a loop inside the kernel over the row's live
+    chunks: the table's width is neither in the grid nor in the
+    result."""
+
+    def _case(self, MB, s, fused):
+        from apex_tpu.ops.paged_attention import paged_decode_fused
+
+        rng = np.random.default_rng(32)
+        b, h, hk, d, BS, NB = 3, 4, 2, 32, 8, 40
+        lengths = [0, 13, 8 * BS - s]            # <= 8 pages a row
+        kp = jnp.asarray(rng.normal(size=(hk, NB, BS, d)), jnp.float32)
+        vp = jnp.asarray(rng.normal(size=(hk, NB, BS, d)), jnp.float32)
+        tables = np.zeros((b, MB), np.int32)
+        tables[:, :8] = 1 + np.arange(b * 8).reshape(b, 8)
+        q = jnp.asarray(rng.normal(size=(b, s, h, d)), jnp.float32)
+        lens = jnp.asarray(lengths, jnp.int32)
+        if not fused:
+            return (lambda q: paged_attention(
+                q, kp, vp, jnp.asarray(tables), lens,
+                implementation="pallas_interpret")), (q,)
+        nk = jnp.asarray(rng.normal(size=(b, 1, hk, d)), jnp.float32)
+        return (lambda q, nk: paged_decode_fused(
+            q, nk, nk, kp, vp, jnp.asarray(tables), lens,
+            max_seq_len=8 * BS, implementation="pallas_interpret")[0]
+            ), (q, nk)
+
+    @pytest.mark.parametrize("s,fused", [(1, False), (4, False),
+                                         (1, True)])
+    def test_table_width_changes_nothing(self, s, fused):
+        narrow, args = self._case(8, s, fused)
+        wide, _ = self._case(256, s, fused)
+        np.testing.assert_array_equal(np.asarray(jax.jit(narrow)(*args)),
+                                      np.asarray(jax.jit(wide)(*args)))
+
+    @pytest.mark.parametrize("s,fused", [(4, False), (1, True)])
+    def test_grid_has_no_page_axis(self, s, fused):
+        fn, args = self._case(256, s, fused)
+        calls = [e for e in jax.make_jaxpr(fn)(*args).jaxpr.eqns
+                 if e.primitive.name == "pallas_call"]
+        assert len(calls) == 1
+        assert tuple(calls[0].params["grid_mapping"].grid) == (3,)
