@@ -58,6 +58,11 @@ class TransformerConfig:
     num_layers: int = 24
     num_heads: int = 16
     num_kv_heads: Optional[int] = None      # GQA; None = num_heads
+    # width of one attention head where the model states it; None = the
+    # classic hidden_size // num_heads.  Models whose attention is
+    # narrower than the residual stream state it (Falcon-H1: 20 heads
+    # x 128 beside a hidden size of 5120).  Read it as ``cfg.head_dim``.
+    kv_channels: Optional[int] = None
     ffn_hidden_size: Optional[int] = None   # None = 4*hidden
     max_seq_len: int = 2048
     # positional scheme: "rope" (GPT-NeoX/Llama) or "learned" (BERT/GPT-2)
@@ -83,6 +88,12 @@ class TransformerConfig:
     # and up projections are separate ColumnParallel weights sharded
     # identically, so the elementwise product stays shard-local under TP.
     gated_mlp: bool = False
+    # muP-style branch multipliers that sit INSIDE a shared module (the
+    # ones around a module are the calling block's): on the keys after
+    # the qkv projection, and on the gate's pre-activation in a gated
+    # MLP.  1.0 leaves the classic recipes untouched.
+    key_multiplier: float = 1.0
+    mlp_gate_multiplier: float = 1.0
     # Mixture-of-Experts FFN (Mixtral-style; beyond-reference — the
     # reference has no EP, SURVEY §2.6 checklist): replaces every
     # layer's dense MLP with `num_moe_experts` experts under top-k
@@ -175,6 +186,10 @@ class TransformerConfig:
 
     @property
     def head_dim(self) -> int:
+        """Width of one attention head: ``kv_channels`` where stated,
+        else ``hidden_size // num_heads``."""
+        if self.kv_channels is not None:
+            return self.kv_channels
         return self.hidden_size // self.num_heads
 
     @property
@@ -186,10 +201,15 @@ class TransformerConfig:
         return self.ffn_hidden_size or 4 * self.hidden_size
 
     def __post_init__(self):
-        if self.hidden_size % self.num_heads:
+        if self.kv_channels is not None:
+            if self.kv_channels < 1:
+                raise ValueError(
+                    f"kv_channels must be >= 1, got {self.kv_channels}")
+        elif self.hidden_size % self.num_heads:
             raise ValueError(
                 f"num_heads ({self.num_heads}) must divide hidden_size "
-                f"({self.hidden_size})")
+                f"({self.hidden_size}) unless kv_channels states the "
+                f"head width")
         if self.num_kv_heads and self.num_heads % self.num_kv_heads:
             raise ValueError(
                 f"num_kv_heads ({self.num_kv_heads}) must divide "
@@ -670,6 +690,8 @@ class ParallelAttention(nn.Module):
             q = qkv[..., : h * d].reshape(b, s, h, d)
             k = qkv[..., h * d: (h + hk) * d].reshape(b, s, hk, d)
             v = qkv[..., (h + hk) * d:].reshape(b, s, hk, d)
+        if cfg.key_multiplier != 1.0:
+            k = k * jnp.asarray(cfg.key_multiplier, k.dtype)
         rot = int(cfg.rotary_pct * d) // 2 * 2
         if decode:
             if not cfg.causal:
@@ -866,6 +888,9 @@ class ParallelMLP(nn.Module):
                 sequence_parallel=cfg.sequence_parallel,
                 dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                 name="dense_h_to_4h_gate")(x)
+            if cfg.mlp_gate_multiplier != 1.0:
+                gate = gate * jnp.asarray(cfg.mlp_gate_multiplier,
+                                          gate.dtype)
             y = act(gate) * y
         else:
             y = act(y)

@@ -44,6 +44,8 @@ from apex_tpu.ops.fused_sampling import (
     fused_sample,
     fused_sample_reference,
 )
+from apex_tpu.ops.ssm import (
+    causal_conv_step, ssd_chunk_scan, ssm_decode_update)
 from apex_tpu.ops.multihead_attn import SelfMultiheadAttn, EncdecMultiheadAttn
 
 __all__ = [

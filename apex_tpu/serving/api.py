@@ -52,6 +52,12 @@ _SENTINEL = object()
 SERVE_STEP = "apex/serve/step"
 DELIVER = "apex/serve/deliver"
 
+#: after a step that finished a request, with an empty queue, the
+#: worker waits at most this long for a follow-up request before the
+#: next step's admission (a closed-loop client's next turn): a
+#: millisecond against a step of tens
+FOLLOW_UP_S = 0.001
+
 #: server-side observer a fleet router attaches to a handle:
 #: ``tap(token, finished, error)`` — token events carry ``(tok, fin,
 #: None)``, the terminal failure carries ``(None, True, exc)``.
@@ -290,6 +296,9 @@ class InferenceServer:
         self._thread: Optional[threading.Thread] = None
         self._steps = 0
         self._step_attempts = 0
+        #: the last step finished a request: wait FOLLOW_UP_S for its
+        #: client's next one before the next admission
+        self._slot_freed = False
         self._tokens_emitted = 0
         self._window_tokens = 0
         self._window_t0: Optional[float] = None
@@ -476,6 +485,8 @@ class InferenceServer:
                     continue            # idle until shutdown()
                 with span(self.spans, SERVE_STEP, step=self._steps):
                     now = self._serve_step()
+                if self._slot_freed:
+                    self._await_follow_up()
                 if now is not None and self.metrics is not None \
                         and self._steps % self.metrics_interval == 0:
                     self._emit_metrics(now)
@@ -572,9 +583,22 @@ class InferenceServer:
                     handle._deliver(ev.token, ev.finished)
                     if ev.finished:
                         self._handles.pop(id(req), None)
+        self._slot_freed = any(ev.finished for ev in events)
         with self._wakeup:
             self._wakeup.notify_all()   # queue space freed
         return now
+
+    def _await_follow_up(self) -> None:
+        """A slot has just come free and nobody is queued for it: a
+        client that was waiting for that last token gets
+        ``FOLLOW_UP_S`` to hand in its next request (the submit's
+        notify ends the wait at once) before the step runs without it.
+        The worker would otherwise keep the interpreter through the
+        next admission and the slot would idle a whole step."""
+        self._slot_freed = False
+        with self._wakeup:
+            if not self._stop and self.scheduler.queue_depth == 0:
+                self._wakeup.wait(FOLLOW_UP_S)
 
     def _drain_out(self) -> None:
         """Evict everything for :meth:`begin_drain` (worker thread):
@@ -803,6 +827,11 @@ class InferenceServer:
             out["kv_bits"] = self.engine.kv_bits
             if getattr(self.engine, "spec_tokens", 0):
                 out["spec_accept_rate"] = self.engine.spec_accept_rate
+            if getattr(self.engine, "ssm_state_bytes", 0):
+                # recurrent state beside the pages (docs/serving.md)
+                out["ssm_state_bytes"] = self.engine.ssm_state_bytes
+                out["ssm_state_resets"] = self.engine.ssm_state_resets
+                out["ssm_positions"] = self.engine.ssm_positions
         return out
 
     def prefix_hit_blocks(self, prompt) -> int:

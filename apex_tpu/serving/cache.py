@@ -202,12 +202,44 @@ def admit_slot(state: SlotState, slot, tok, budget, temperature,
     )
 
 
+def admit_slots(state: SlotState, ints, floats) -> SlotState:
+    """Functional admission of every row that ``ints[0]`` marks, in ONE
+    application (traceable): a burst of admissions costs the device one
+    call and two small host arrays, however many tenants arrive.
+
+    ``ints`` is int32 ``(6, max_slots)``: fresh (0/1), tok, budget,
+    top_k, eos_id and the seed's 32 bits; ``floats`` is float32
+    ``(2, max_slots)``: temperature, top_p.  A marked row ends exactly
+    as :func:`admit_slot` leaves it; the others are untouched.
+    """
+    fresh = ints[0] != 0
+    seeds = jax.lax.bitcast_convert_type(ints[5], jnp.uint32)
+    keys = jax.vmap(jax.random.PRNGKey)(seeds)
+    if keys.dtype != jnp.uint32:     # typed-key jax: store the raw bits
+        keys = jax.random.key_data(keys)
+
+    def put(new, old):
+        return jnp.where(fresh, new.astype(old.dtype), old)
+
+    return state._replace(
+        active=state.active | fresh,
+        tok=put(ints[1], state.tok),
+        produced=put(jnp.zeros_like(state.produced), state.produced),
+        budget=put(ints[2], state.budget),
+        temperature=put(floats[0], state.temperature),
+        top_k=put(ints[3], state.top_k),
+        top_p=put(floats[1], state.top_p),
+        eos_id=put(ints[4], state.eos_id),
+        rng=jnp.where(fresh[:, None], keys.astype(jnp.uint32), state.rng),
+    )
+
+
 def release_slot(state: SlotState, slot) -> SlotState:
     """Mark ``slot`` free (traceable)."""
     return state._replace(active=state.active.at[slot].set(False))
 
 
-__all__ += ["admit_slot", "release_slot"]
+__all__ += ["admit_slot", "admit_slots", "release_slot"]
 
 
 # --------------------------------------------------------------------- #
@@ -219,6 +251,10 @@ __all__ += ["admit_slot", "release_slot"]
 _TABLE_LEAF = "block_tables"
 _CURSOR_LEAVES = ("cursors", "position_index")
 _CHUNK_LENS_LEAF = "chunk_lens"
+#: per-slot recurrent state a model may keep beside the pages: the
+#: model owns the values (zeroed for a row at cursor 0, advanced over
+#: ``chunk_lens`` real lanes), the engine counts the bytes
+_RECURRENT_LEAVES = ("ssm_state", "conv_state")
 
 
 class BlockExhausted(RuntimeError):
@@ -518,8 +554,19 @@ def constrain_paged_cache(cache: Any, mesh, axis: str) -> Any:
                         paged_pool_shardings(cache, mesh, axis))
 
 
+__all__ += ["recurrent_state_bytes"]
 __all__ += ["paged_pool_shardings", "shard_paged_cache",
             "constrain_paged_cache"]
+
+
+def recurrent_state_bytes(cache: Any) -> int:
+    """Bytes of the per-slot recurrent-state leaves of a cache tree
+    (arrays or ShapeDtypeStructs), every layer's; 0 for a model that
+    keeps none."""
+    return sum(
+        int(np.prod(leaf.shape)) * jnp.dtype(leaf.dtype).itemsize
+        for path, leaf in jax.tree_util.tree_flatten_with_path(cache)[0]
+        if _leaf_name(path) in _RECURRENT_LEAVES)
 
 
 def set_paged_leaves(cache: Any, tables, cursors,

@@ -48,7 +48,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from typing import Any, List, NamedTuple, Optional, Sequence, Tuple
+from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -527,6 +527,11 @@ class Engine:
 # --------------------------------------------------------------------- #
 # paged engine — token-granular serving datapath
 # --------------------------------------------------------------------- #
+#: what PagedEngine reads off a model's ``.cfg`` (any model class)
+_MODEL_CONTRACT = ("max_seq_len", "vocab_size", "num_heads", "kv_heads",
+                   "head_dim", "dtype")
+
+
 @dataclasses.dataclass
 class _Tenant:
     """Host-side record of one slot's tenant (the device never sees
@@ -575,8 +580,23 @@ class PagedEngine:
     prefills collapse to this one shape), the optional ``spec_step``
     (the width-``1 + spec_tokens`` draft/verify step below), ``admit``
     (slot-state scatter; no cache writes — pages are overwritten
-    before they become visible, so admission and release never touch
-    the pool), and ``release``.
+    before they become visible and recurrent state is zeroed by the
+    step that finds its row at cursor 0, so admission and release
+    never touch the cache), and ``release``.
+
+    A model with **recurrent state** (a state-space mixer beside the
+    attention: :class:`~apex_tpu.models.falcon_h1.FalconH1Model`)
+    keeps, a layer, ``ssm_state`` / ``conv_state`` leaves with one row
+    a slot in the same ``"cache"`` collection.  The engine never writes
+    them: the model starts a row from zero state when its cursor is 0
+    (a fresh admission, and a re-admission after a preemption, which
+    re-prefills ``prompt ++ streamed``) and advances a row only over
+    its real lanes — ``chunk_lens``, fed every step (a decoding row in
+    a mixed step has 1 real lane of ``prefill_chunk``).  A slot's
+    previous tenant therefore never needs clearing, as with pages.
+    ``share_prefixes``, ``spec_tokens`` and ``mesh`` need a state
+    snapshot or a state sharding that does not exist and raise for
+    such a model.
 
     Block exhaustion preempts the YOUNGEST tenant (its blocks are
     freed, its slot state cleared) and reports it in
@@ -684,11 +704,21 @@ class PagedEngine:
                  kv_dtype: Optional[str] = None,
                  mesh=None):
         cfg = getattr(model, "cfg", None)
-        if cfg is None or not hasattr(cfg, "max_seq_len"):
+        missing = [f for f in _MODEL_CONTRACT if not hasattr(cfg, f)]
+        if cfg is None or missing:
             raise ValueError(
-                "PagedEngine needs a model with a .cfg carrying "
-                "max_seq_len and vocab_size (GPTModel / LlamaModel "
-                "contract)")
+                "PagedEngine needs a model that keeps the paged-serving "
+                "contract: a flax module built as type(model)(cfg=...) "
+                "whose dataclass .cfg carries "
+                f"{', '.join(_MODEL_CONTRACT)}; applied with "
+                "decode=True and a mutable 'cache' collection it "
+                "returns (batch, width, vocab) logits and keeps, a "
+                "layer, a page pool plus 'block_tables' / 'cursors' "
+                "leaves (and any per-slot recurrent state, leading "
+                "axis = slots) that the engine overwrites before "
+                "every step"
+                + (f" — missing on .cfg: {missing}" if cfg is not None
+                   else " — the model has no .cfg"))
         if not getattr(cfg, "causal", True):
             raise ValueError("PagedEngine requires a causal model "
                              "(decode=True contract)")
@@ -838,6 +868,37 @@ class PagedEngine:
             kv_shard_axis=(TENSOR_AXIS if self.mesh is not None
                            else None)))
         shapes = cache_shapes(self._paged_model, self.max_slots)
+        # recurrent state beside the pages (a state-space mixer's
+        # ``ssm_state`` / ``conv_state``, a slot a row): what of it a
+        # slot holds is a function of EVERY token the row has seen, so
+        # it cannot be shared by page, rolled back over a rejected
+        # draft or split over kv heads the way K/V rows can
+        self.ssm_state_bytes = slot_cache.recurrent_state_bytes(shapes)
+        if self.ssm_state_bytes:
+            for on, what, why in (
+                    (self.share_prefixes, "share_prefixes=True",
+                     "a shared prompt page would need a SNAPSHOT of "
+                     "the recurrent state at its last token, and no "
+                     "state snapshot exists"),
+                    (self.spec_tokens, f"spec_tokens={self.spec_tokens}",
+                     "a rejected draft would need the recurrent state "
+                     "ROLLED BACK to a snapshot before it, and no "
+                     "state snapshot exists"),
+                    (self.mesh is not None, "mesh=",
+                     "no SHARDING of the recurrent state over the "
+                     "tensor axis exists")):
+                if on:
+                    raise ValueError(
+                        f"PagedEngine: {what} is not supported for a "
+                        f"model with recurrent state — {why}; serve "
+                        "it with share_prefixes=False, spec_tokens=0, "
+                        "mesh=None")
+        #: rows started from zero state (admissions, and re-admissions
+        #: after a preemption) and real lanes advanced through the
+        #: recurrence, summed over steps and rows, a step counted once
+        #: and not a layer (lifetime; 0 without recurrent state)
+        self.ssm_state_resets = 0
+        self.ssm_positions = 0
         self.cache = jax.tree.map(
             lambda s: jnp.zeros(s.shape, s.dtype), shapes)
         self.state = slot_cache.init_slot_state(self.max_slots)
@@ -860,6 +921,10 @@ class PagedEngine:
         self._cursors = np.zeros((self.max_slots,), np.int32)
         self._tenants: List[Optional[_Tenant]] = [None] * self.max_slots
         self._admit_seq = 0
+        #: admissions the device has not seen yet, slot -> (ints,
+        #: floats) columns of ``admit_slots``: the next step installs
+        #: them all in one call (64 arrivals at once cost one, not 64)
+        self._pending: Dict[int, Tuple[tuple, tuple]] = {}
         self.spans = SpanTotals((STEP_PREFILL, STEP_DECODE, STEP_SPEC,
                                  PLAN, DISPATCH, FETCH, COMMIT))
         self._build()
@@ -994,11 +1059,8 @@ class PagedEngine:
             cache, state = pin_out(cache, state)
             return cache, state, sampled, n_emit, finished
 
-        def admit(state, slot, tok, budget, temperature, top_k, top_p,
-                  eos_id, seed):
-            state = slot_cache.admit_slot(
-                state, slot, tok, budget, temperature, top_k, top_p,
-                eos_id, seed)
+        def admit(state, ints, floats):
+            state = slot_cache.admit_slots(state, ints, floats)
             return (state if mesh is None
                     else _pin_replicated(state, mesh))
 
@@ -1101,7 +1163,11 @@ class PagedEngine:
         the tokens it writes).  With ``share_prefixes``, trie-resident
         prompt-prefix pages ARE mapped here (refcounted, read-only):
         ``fed``/``cursor`` start past them, so their KV is neither
-        recomputed nor re-stored."""
+        recomputed nor re-stored.  No device call either: the slot's
+        row of the device state (token, budget, sampling, key) waits in
+        ``_pending`` and the next step's dispatch installs every
+        waiting row in ONE call of the ``admit`` executable — a burst
+        of 64 arrivals costs one call, not 64."""
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         self.validate_request(prompt.shape[0], max_new_tokens,
                               temperature, top_k, top_p)
@@ -1136,13 +1202,24 @@ class PagedEngine:
             rec.registered = n_share
             self._cursors[slot] = rec.cursor
         self._tenants[slot] = rec
-        self.state = self._admit(
-            self.state, np.int32(slot), np.int32(prompt[-1]),
-            np.int32(max_new_tokens), np.float32(temperature),
-            np.int32(top_k or 0),
-            np.float32(0.0 if top_p is None else top_p),
-            np.int32(-1 if eos_id is None else eos_id),
-            np.uint32(seed))
+        self._pending[slot] = (
+            (1, int(prompt[-1]), int(max_new_tokens), int(top_k or 0),
+             -1 if eos_id is None else int(eos_id),
+             int(np.array(seed, np.uint32).view(np.int32))),
+            (float(temperature), 0.0 if top_p is None else float(top_p)))
+
+    def _install_admissions(self) -> None:
+        """Hand the device every admission since the last step, in one
+        call of the ``admit`` executable."""
+        if not self._pending:
+            return
+        ints = np.zeros((6, self.max_slots), np.int32)
+        floats = np.zeros((2, self.max_slots), np.float32)
+        for slot, (i, f) in self._pending.items():
+            ints[:, slot] = i
+            floats[:, slot] = f
+        self._pending.clear()
+        self.state = self._admit(self.state, ints, floats)
 
     def _youngest(self) -> int:
         live = [s for s, t in enumerate(self._tenants) if t is not None]
@@ -1163,6 +1240,8 @@ class PagedEngine:
             self._tables[slot] = 0
             self._cursors[slot] = 0
             self._tenants[slot] = None
+        if self._pending.pop(slot, None) is not None:
+            return              # the device never saw this tenant
         self.state = self._release(self.state, np.int32(slot))
 
     def _read_only(self, page: int) -> bool:
@@ -1324,7 +1403,15 @@ class PagedEngine:
                     is_prefill[slot] = False
                     emit[slot] = False
                     drafts[slot] = None
+                if self.ssm_state_bytes:
+                    live = np.fromiter(
+                        (rec is not None for rec in self._tenants),
+                        bool, self.max_slots)
+                    self.ssm_positions += int(n_tokens[live].sum())
+                    self.ssm_state_resets += int(
+                        (self._cursors[live] == 0).sum())
             with span(self.spans, DISPATCH):
+                self._install_admissions()
                 self.kv_pages_live += int(
                     (self._cursors // self.block_size + 1).sum())
                 if any_spec:

@@ -167,6 +167,66 @@ def test_paged_decode_fused(compile_for_chip, rope, kv, pool_tokens):
     assert "tpu_custom_call" in compile_for_chip(fn, *shapes)
 
 
+# ------------------- Falcon-H1-34B: 20 / 4 heads, the recurrent state
+FB, FH, FHK = 64, 20, 4               # slots; 5 query heads a KV head
+F_SEQ, F_POOL = 2048, 81920
+M_H, M_G, M_P, M_N = 32, 2, 128, 256  # mixer heads, groups, d_head, d_state
+
+
+def _falcon_pool(bs=16):
+    nb = F_POOL // bs + 1
+    return (((FHK, nb, bs, D), bf16), ((FHK, nb, bs, D), bf16),
+            ((FB, F_SEQ // bs), jnp.int32), ((FB,), jnp.int32))
+
+
+@pytest.mark.parametrize("s", [1, 32])
+def test_paged_attention_five_query_heads_a_kv_head(compile_for_chip, s):
+    def fn(q, kp, vp, bt, ln):
+        return paged_attention(q, kp, vp, bt, ln, implementation="pallas")
+
+    assert "tpu_custom_call" in compile_for_chip(
+        fn, ((FB, s, FH, D), bf16), *_falcon_pool())
+
+
+def test_paged_decode_fused_five_query_heads_a_kv_head(compile_for_chip):
+    def fn(q, nk, nv, kp, vp, bt, ln, cos, sin):
+        return paged_decode_fused(q, nk, nv, kp, vp, bt, ln,
+                                  max_seq_len=F_SEQ, cos_b=cos, sin_b=sin,
+                                  implementation="pallas")
+
+    assert "tpu_custom_call" in compile_for_chip(
+        fn, ((FB, 1, FH, D), bf16), ((FB, 1, FHK, D), bf16),
+        ((FB, 1, FHK, D), bf16), *_falcon_pool(),
+        *[((FB, 1, 1, D // 2), jnp.float32)] * 2)
+
+
+@pytest.mark.parametrize("s", [1, 32])        # decode update, mixed step
+def test_ssm_kernels_at_the_cells_shapes(compile_for_chip, s):
+    from apex_tpu.ops import ssm
+
+    state = ((FB, M_H, M_P, M_N), jnp.float32)
+    rows = [((FB,), jnp.int32), ((FB,), jnp.bool_)]
+    if s == 1:
+        def fn(x, dt, a, bm, cm, st, lens, reset):
+            return ssm.ssm_decode_update(x, dt, a, bm, cm, st, lens,
+                                         reset, implementation="pallas")
+        shapes = [((FB, M_H, M_P), bf16), ((FB, M_H), jnp.float32),
+                  ((M_H,), jnp.float32), ((FB, M_G, M_N), bf16),
+                  ((FB, M_G, M_N), bf16), state, *rows]
+        name = "ssm_decode_update"
+    else:
+        def fn(x, dt, a, bm, cm, st, lens, reset):
+            return ssm.ssd_chunk_scan(x, dt, a, bm, cm, st, lens, reset,
+                                      implementation="pallas")
+        shapes = [((FB, s, M_H, M_P), bf16), ((FB, s, M_H), jnp.float32),
+                  ((M_H,), jnp.float32), ((FB, s, M_G, M_N), bf16),
+                  ((FB, s, M_G, M_N), bf16), state, *rows]
+        name = "ssm_chunk_scan"
+    text = compile_for_chip(fn, *shapes)
+    # the scope names the kernel's instruction: what the trace shows
+    assert "tpu_custom_call" in text and f"%{name}." in text
+
+
 # --------------------------------------------------- fused sampling
 def _sample_with_the_kernel(logits, keys, t, k, p):
     return fs.fused_sample(logits, keys, t, k, p,

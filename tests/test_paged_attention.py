@@ -75,6 +75,8 @@ class TestGoldenKernel:
         (1, 8, 2, jnp.float32),        # decode, GQA 4:1
         (4, 4, 2, jnp.float32),        # chunk queries, GQA
         (4, 4, 4, jnp.bfloat16),       # chunk, bf16
+        (1, 10, 2, jnp.float32),       # decode, GQA 5:1 (Falcon-H1)
+        (4, 10, 2, jnp.bfloat16),      # chunk, 5 x 4 = 20 query rows
     ])
     def test_kernel_matches_reference(self, s, h, hk, dtype):
         rng = np.random.default_rng(0)
@@ -695,10 +697,11 @@ class TestFusedDecodePrologue:
             np.testing.assert_array_equal(np.asarray(a),
                                           np.asarray(b))
 
-    def test_kernel_matches_reference_unquantized(self):
+    @pytest.mark.parametrize("h,hk", [(8, 4), (10, 2)])   # 2:1, 5:1
+    def test_kernel_matches_reference_unquantized(self, h, hk):
         rng = np.random.default_rng(4)
         (q, nk, nv, kp, vp, tables, lengths, rope, sc, S,
-         live) = self._setup(rng)
+         live) = self._setup(rng, h=h, hk=hk)
         args = (q, nk, nv, kp, vp, tables, lengths)
         ref = self._run("xla", args, S, rope, sc)
         got = self._run("pallas_interpret", args, S, rope, sc)

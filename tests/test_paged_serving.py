@@ -41,6 +41,8 @@ Correctness contracts under test:
   code path.
 """
 
+import time
+
 import numpy as np
 import pytest
 
@@ -413,6 +415,77 @@ class TestSamplingDeterminism:
         assert run(False) == run(True)
 
 
+class TestBatchedAdmission:
+    """Admissions wait on the host and reach the device together, in
+    one call of the ``admit`` executable at the next step's dispatch
+    (a burst of arrivals used to cost a call each)."""
+
+    def test_admit_slots_is_admit_slot_row_by_row(self):
+        rows = {0: (5, 9, 0, -1, 123, 0.0, 0.0),
+                2: (7, 3, 20, 11, 2 ** 32 - 1, 0.9, 0.95),
+                3: (1, 1, 4, -1, 0, 1.3, 0.0)}
+        one = slot_cache.init_slot_state(5)
+        # row 1 holds an older tenant neither way may disturb
+        one = slot_cache.admit_slot(one, 1, 2, 6, 0.5, 3, 0.5, 4,
+                                    np.uint32(77))
+        many = one
+        ints = np.zeros((6, 5), np.int32)
+        floats = np.zeros((2, 5), np.float32)
+        for slot, (tok, budget, top_k, eos, seed, temp, top_p) in \
+                rows.items():
+            one = slot_cache.admit_slot(
+                one, slot, tok, budget, np.float32(temp), top_k,
+                np.float32(top_p), eos, np.uint32(seed))
+            ints[:, slot] = (1, tok, budget, top_k, eos,
+                             np.array(seed, np.uint32).view(np.int32))
+            floats[:, slot] = (temp, top_p)
+        many = jax.jit(slot_cache.admit_slots)(many, ints, floats)
+        for name, a, b in zip(one._fields, one, many):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                          err_msg=name)
+
+    def test_a_burst_costs_one_call_and_a_withdrawn_tenant_none(
+            self, gpt):
+        model, params = gpt
+        engine = PagedEngine(model, params, max_slots=4, block_size=8,
+                             prefill_chunk=4, pool_tokens=128)
+        calls = {"admit": 0, "release": 0}
+        for name in calls:
+            inner = getattr(engine, "_" + name)
+
+            def counted(*a, _inner=inner, _name=name):
+                calls[_name] += 1
+                return _inner(*a)
+
+            setattr(engine, "_" + name, counted)
+        rng = np.random.default_rng(3)
+        prompts = [rng.integers(0, model.cfg.vocab_size,
+                                size=(n,)).astype(np.int32)
+                   for n in (3, 6, 9)]
+        for slot, prompt in enumerate(prompts):
+            engine.admit(slot, prompt, max_new_tokens=4)
+        assert calls["admit"] == 0          # nothing has reached the device
+        engine.admit(3, prompts[0], max_new_tokens=4)
+        engine.release(3)                   # withdrawn before any step
+        assert calls == {"admit": 0, "release": 0}
+        chains = [[] for _ in prompts]
+        live = set(range(3))
+        while live:
+            out = engine.step()
+            for slot in sorted(live):
+                chains[slot].extend(
+                    int(t) for t in out.tokens[slot, :out.counts[slot]])
+                if out.finished[slot]:
+                    engine.release(slot)
+                    live.discard(slot)
+        assert calls == {"admit": 1, "release": 3}
+        for prompt, chain in zip(prompts, chains):
+            want = generate(model, params, jnp.asarray(prompt[None]),
+                            max_new_tokens=4)
+            assert chain == [int(t) for t in
+                             np.asarray(want)[0, prompt.size:]]
+
+
 class TestPagedServer:
     def test_streaming_parity_metrics_and_gauges(self, gpt):
         model, params = gpt
@@ -452,6 +525,46 @@ class TestPagedServer:
         assert merged["ttft_p50_s"] > 0
         summary = server.latency_summary()
         assert summary["ttft_p99_s"] >= summary["ttft_p50_s"]
+
+    def test_a_follow_up_request_rides_the_very_next_step(
+            self, gpt, monkeypatch):
+        """After a step that finished a request the worker waits (at
+        most ``FOLLOW_UP_S``) for the client's next request: it is
+        admitted by the next step, not the one after.  The wait is
+        made long here so that only the submit's notify can end it."""
+        import threading
+
+        from apex_tpu.serving import api
+
+        monkeypatch.setattr(api, "FOLLOW_UP_S", 30.0)
+        model, params = gpt
+        rng = np.random.default_rng(5)
+        server = InferenceServer(
+            model, params, max_slots=2, kv_cache="paged", block_size=8,
+            prefill_chunk=4)
+        done, seen = threading.Event(), {}
+
+        def last_token(token, finished, error):
+            if finished:
+                seen["finished_at_step"] = server._steps
+                done.set()
+
+        def first_token(token, finished, error):
+            seen.setdefault("first_at_step", server._steps)
+
+        with server:
+            server.submit(rng.integers(0, model.cfg.vocab_size, size=(5,)),
+                          max_new_tokens=3, tap=last_token)
+            assert done.wait(300)
+            t0 = time.monotonic()
+            # one chunk: its first token comes out of its first step
+            follow = server.submit(
+                rng.integers(0, model.cfg.vocab_size, size=(3,)),
+                max_new_tokens=2, tap=first_token)
+            assert len(follow.result(timeout=300)) == 2
+            waited = time.monotonic() - t0
+        assert seen["first_at_step"] == seen["finished_at_step"] + 1
+        assert waited < 20.0          # the notify ended the wait
 
     def test_invalid_kv_cache_rejected(self, gpt):
         model, params = gpt
