@@ -23,8 +23,10 @@ weight.
 
 The building blocks are the zoo's: RMSNorm, ``ParallelAttention`` (its
 paged path unchanged), the SwiGLU ``ParallelMLP``, the vocab-parallel
-embedding and head, layers under one ``nn.scan``.  New is the mixer and
-its recurrent state: under ``decode=True`` (paged serving only) each
+embedding and head, parameters stacked under one ``nn.scan`` (which a
+``decode=True`` application replaces by a loop over its layers,
+:func:`~apex_tpu.models.transformer.decode_layers`).  New is the mixer
+and its recurrent state: under ``decode=True`` (paged serving only) each
 layer keeps, a slot, ``ssm_state`` (heads, d_head, d_state) in float32
 and ``conv_state`` (d_conv - 1, channels), beside the KV pages in the
 ``"cache"`` collection, together with ``cursors`` and ``chunk_lens``
@@ -54,6 +56,8 @@ from apex_tpu.models.transformer import (
     ParallelAttention,
     ParallelMLP,
     _norm,
+    decode_layers,
+    parameters_only,
 )
 from apex_tpu.ops.ssm import (causal_conv_step, ssd_chunk_scan,
                               ssm_decode_update)
@@ -347,15 +351,23 @@ class FalconH1Model(nn.Module):
             dtype=cfg.dtype, param_dtype=cfg.param_dtype,
             name="embedding")(input_ids)
         x = _scaled(x.astype(cfg.dtype), cfg.embedding_multiplier)
-        stack = nn.scan(
-            _ScanBlock,
-            variable_axes={"params": 0, "cache": 0},
-            split_rngs={"params": True},
-            in_axes=nn.broadcast,
-            length=cfg.num_layers,
-            metadata_params={nn.PARTITION_NAME: None},
-        )
-        x, _ = stack(cfg, decode, name="layers")(x, None)
+        # a decode application never scans over its cache
+        # (transformer.decode_layers has why); at init the scan makes
+        # the stacked parameters all the same
+        if not decode or self.is_initializing():
+            stack = nn.scan(
+                _ScanBlock,
+                variable_axes={"params": 0, "cache": 0},
+                split_rngs={"params": True},
+                in_axes=nn.broadcast,
+                length=cfg.num_layers,
+                metadata_params={nn.PARTITION_NAME: None},
+            )
+            if decode:
+                stack = parameters_only(stack)
+            y, _ = stack(cfg, decode, name="layers")(x, None)
+        x = (decode_layers(self, FalconH1Block(cfg, parent=None),
+                           cfg.num_layers, x) if decode else y)
         x = _norm(cfg, "final_norm")(x).astype(cfg.dtype)
         logits = ColumnParallelLinear(
             features=cfg.vocab_size, use_bias=False,

@@ -14,7 +14,9 @@ all-gather/reduce-scatter pairs the reference hand-codes.  Layers are
 stacked with ``nn.scan`` (one trace/compile for N layers) and optionally
 ``nn.remat`` (activation checkpointing ≙
 ``tensor_parallel.random.checkpoint``, SURVEY.md §2.6 — RNG replay is
-free because everything is functional).
+free because everything is functional).  A ``decode=True`` application
+loops over the layers instead (:func:`decode_layers`): its cache is one
+subtree a layer, so the serving pools are updated in place.
 """
 
 from __future__ import annotations
@@ -46,7 +48,8 @@ from apex_tpu.transformer.layers import (
 )
 
 __all__ = ["TransformerConfig", "ParallelTransformerLayer",
-           "ParallelTransformer", "ParallelMLP", "ParallelAttention"]
+           "ParallelTransformer", "ParallelMLP", "ParallelAttention",
+           "decode_layers", "parameters_only"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -966,12 +969,81 @@ class _ScanBlock(nn.Module):
         return y, None
 
 
+def parameters_only(scanned):
+    """``scanned`` (an ``nn.scan`` over a layer) for ``init`` under
+    ``decode=True``: it makes the stacked parameters as ever, and the
+    stacked cache it would leave beside them is dropped —
+    :func:`decode_layers` makes the cache, a subtree a layer."""
+    return nn.map_variables(scanned, "cache", mutable=True,
+                            trans_out_fn=lambda _: {})
+
+
+def decode_layers(stack: nn.Module, layer: nn.Module, num_layers: int,
+                  x, **kwargs):
+    """The layers of a scanned stack under ``decode=True``, WITHOUT a
+    scan over the cache.
+
+    ``stack`` is the bound module whose ``layers`` child ``nn.scan``
+    made — parameters stacked under ``layers/layer``, a leading layer
+    axis on every leaf — and ``layer`` one unbound layer
+    (``parent=None``).  Layer ``i`` is applied to slice ``i`` of every
+    stacked parameter and to ITS OWN cache subtree, which ``stack``
+    keeps as ``cache/layer_{i}``: the cache tree of an unrolled
+    (``scan_layers=False``) stack, no layer axis on any leaf, every
+    leaf under the name its layer gave it.
+
+    Why not ``nn.scan``: a scan OVER the cache makes every stacked
+    pool an input and an output of the loop, so XLA copies the whole
+    stack once a step and slices each layer's pool out and back every
+    iteration — three passes over the whole cache around kernels that
+    move one page or one row (PERF.md, PR 33: most of a serving step's
+    device time).  With a leaf a layer the donated buffers
+    alias straight through the kernels and nothing pool-shaped is
+    left.  The parameters stay stacked (checkpoints, amp and the TP
+    annotations see one tree whatever ``decode`` is); their static
+    slices fuse into the GEMMs that read them.  What grows is the
+    program: a decode step compiles in time linear in depth.
+    """
+    stacked = stack.get_variable("params", "layers")["layer"]
+    key = stack.make_rng("dropout") if stack.has_rng("dropout") else None
+
+    # one trace and one lowered function for all the layers: their
+    # shapes are the same, and XLA inlines the calls
+    @jax.jit
+    def apply_layer(variables, x, key):
+        return layer.apply(
+            variables, x, decode=True, mutable=["cache"],
+            rngs=None if key is None else {"dropout": key}, **kwargs)
+
+    for i in range(num_layers):
+        # the slice keeps a Partitioned box; its names drop the layer
+        # axis, as nn.scan's metadata_params does on the way in
+        params = nn.meta.remove_axis(
+            jax.tree.map(lambda a: a[i], stacked), 0,
+            {nn.PARTITION_NAME: None})
+        name = f"layer_{i}"
+        variables = {"params": params}
+        if stack.has_variable("cache", name):
+            variables["cache"] = stack.get_variable("cache", name)
+        x, updated = apply_layer(
+            variables, x,
+            None if key is None else jax.random.fold_in(key, i))
+        stack.put_variable("cache", name, updated["cache"])
+    return x
+
+
 class ParallelTransformer(nn.Module):
     """N stacked layers via ``nn.scan`` (+ optional ``nn.remat``).
 
-    ``scan_layers=True`` compiles ONE layer and iterates it — compile
-    time stays flat in depth; parameters get a leading layer axis
-    (sharded spec-compatible).  ``remat=True`` recomputes each layer's
+    ``scan_layers=True`` gives the parameters a leading layer axis
+    (sharded spec-compatible).  Training and the full-sequence forward
+    compile ONE layer and iterate it — compile time stays flat in
+    depth.  A ``decode=True`` application never scans over its cache:
+    it runs :func:`decode_layers`, a Python loop over slices of the
+    same stacked parameters with one cache subtree a layer
+    (``cache/layer_{i}``, as ``scan_layers=False`` names them), so the
+    KV pools are updated in place and a decode program's size is
+    linear in depth.  ``remat=True`` recomputes each layer's
     activations in backward (``jax.checkpoint``), the functional
     equivalent of the reference's ``tensor_parallel.random.checkpoint``.
     """
@@ -983,21 +1055,31 @@ class ParallelTransformer(nn.Module):
                  decode: bool = False):
         cfg = self.cfg
         if cfg.scan_layers:
-            block_cls = _ScanBlock
-            if cfg.remat:
-                block_cls = nn.remat(
-                    block_cls, prevent_cse=False,
-                    policy=_remat_policy(cfg.remat_policy))
-            stack = nn.scan(
-                block_cls,
-                variable_axes={"params": 0, "cache": 0, "losses": 0},
-                split_rngs={"params": True, "dropout": True},
-                in_axes=nn.broadcast,
-                length=cfg.num_layers,
-                metadata_params={nn.PARTITION_NAME: None},
-            )
-            x, _ = stack(cfg, deterministic, decode,
-                         name="layers")(x, mask_bias)
+            if not decode or self.is_initializing():
+                block_cls = _ScanBlock
+                if cfg.remat:
+                    block_cls = nn.remat(
+                        block_cls, prevent_cse=False,
+                        policy=_remat_policy(cfg.remat_policy))
+                stack = nn.scan(
+                    block_cls,
+                    variable_axes={"params": 0, "cache": 0, "losses": 0},
+                    split_rngs={"params": True, "dropout": True},
+                    in_axes=nn.broadcast,
+                    length=cfg.num_layers,
+                    metadata_params={nn.PARTITION_NAME: None},
+                )
+                if decode:
+                    stack = parameters_only(stack)
+                y, _ = stack(cfg, deterministic, decode,
+                             name="layers")(x, mask_bias)
+            if decode:
+                x = decode_layers(
+                    self, ParallelTransformerLayer(cfg, parent=None),
+                    cfg.num_layers, x, mask_bias=mask_bias,
+                    deterministic=deterministic)
+            else:
+                x = y
         else:
             remat_cls = ParallelTransformerLayer
             # decode never remats (inference has no backward) — and the

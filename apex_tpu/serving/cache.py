@@ -504,8 +504,9 @@ class PrefixTrie:
 def _tp_spec_for_leaf(name: str, ndim: int, axis: str):
     """PartitionSpec of one paged-cache leaf under tensor-parallel
     serving: the K/V pool leaves shard their ``kv_heads`` dim (at
-    ``ndim - 4`` — the scanned layer stack prepends a layer axis, the
-    unrolled form doesn't), the per-(kv_head, page) quant-scale leaves
+    ``ndim - 4``: counted from the back, so it holds for the decode
+    cache's one leaf a layer, which has no layer axis, and would for a
+    stacked one), the per-(kv_head, page) quant-scale leaves
     shard the same dim at ``ndim - 2``, and EVERYTHING else — block
     tables, cursors, chunk_lens, position_index, the SlotState twin —
     is replicated, which is what keeps the engine's host-side
@@ -573,10 +574,11 @@ def set_paged_leaves(cache: Any, tables, cursors,
                      chunk_lens=None) -> Any:
     """Overwrite the paged cache tree's ``block_tables`` and cursor
     leaves (``cursors`` / ``position_index``) with the engine's
-    host-authoritative values, broadcast to each leaf's shape (the
-    scanned layer stack adds a leading layer axis — every layer shares
-    one logical→physical mapping because the per-layer pools are
-    parallel).  ``chunk_lens`` (per-row REAL lane counts for the
+    host-authoritative values, broadcast to each leaf's shape (every
+    layer keeps its own leaves — ``models.transformer.decode_layers``
+    — and all of them share one logical→physical mapping because the
+    per-layer pools are parallel).  ``chunk_lens`` (per-row REAL lane
+    counts for the
     coming mixed step) overwrites the quantized pool's ``chunk_lens``
     leaf the same way — the write path routes lanes past it to the
     null page so pad-lane amax never reaches a live page scale; pass

@@ -570,7 +570,12 @@ class PagedEngine:
     - the whole ragged batch is ONE model application — per-row
       cursors/block tables in the cache collection replace the dense
       engine's per-slot vmap, and attention goes through
-      :func:`apex_tpu.ops.paged_attention`.
+      :func:`apex_tpu.ops.paged_attention`.  The collection holds one
+      subtree a LAYER (``.../layer_{i}/attention/paged_key``, ...;
+      no leaf has a layer axis, whether the model scans its layers or
+      unrolls them): the steps donate the tree, and each layer's
+      kernels write its own pool in place
+      (:func:`apex_tpu.models.transformer.decode_layers`).
 
     Exactly FOUR executables for the process lifetime — FIVE with
     speculative decoding on — each under an exact

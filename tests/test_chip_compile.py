@@ -227,6 +227,133 @@ def test_ssm_kernels_at_the_cells_shapes(compile_for_chip, s):
     assert "tpu_custom_call" in text and f"%{name}." in text
 
 
+# ------------------- the serve engine's step programs, whole (PR 33)
+# family, config of its cell, layers, width: width 1 at the cells'
+# depth, the mixed step at 2 layers (its findings are a layer's)
+STEP_PROGRAMS = [
+    ("mistral", "mistral_7b_l8", 8, 1), ("mistral", "mistral_7b_l8", 2, 32),
+    ("falcon_h1", "falcon_h1_34b_l4", 4, 1),
+    ("falcon_h1", "falcon_h1_34b_l4", 2, 32),
+]
+#: what may hold a whole pool or state: the program's arguments and
+#: results, and the Pallas kernels, which alias theirs
+_IN_PLACE = ("parameter", "tuple", "get-tuple-element", "bitcast",
+             "custom-call")
+
+
+def _cell_engine(family, config, layers):
+    """The cell's ``PagedEngine`` at ``layers`` layers, over shapes
+    alone: built under ``eval_shape``, so neither weights nor pool are
+    ever allocated (its ``cache`` and ``state`` hold what the trace
+    left there, good for their shapes)."""
+    import json
+    import pathlib
+
+    from apex_tpu.models import (FalconH1Config, FalconH1Model,
+                                 LlamaConfig, LlamaModel)
+    from apex_tpu.serving import PagedEngine
+
+    c = json.loads((pathlib.Path(__file__).resolve().parents[1]
+                    / "benchmarks" / "configs" / f"{config}.json"
+                    ).read_text())
+    c["num_hidden_layers"] = layers
+    kw = dict(dtype=bf16, param_dtype=bf16)
+    if family == "falcon_h1":
+        model = FalconH1Model(FalconH1Config.from_hf(c, **kw))
+    else:
+        model = LlamaModel(LlamaConfig(
+            vocab_size=c["vocab_size"], hidden_size=c["hidden_size"],
+            num_layers=layers, num_heads=c["num_attention_heads"],
+            num_kv_heads=c["num_key_value_heads"],
+            ffn_hidden_size=c["intermediate_size"],
+            max_seq_len=c["max_position_embeddings"],
+            layernorm_eps=c["rms_norm_eps"], rope_base=c["rope_theta"],
+            **kw))
+    params = {"params": jax.eval_shape(
+        model.init, jax.random.PRNGKey(0),
+        jnp.zeros((1, 8), jnp.int32))["params"]}
+    server = c["serve"]["server"]
+    made = []
+    jax.eval_shape(lambda: made.append(PagedEngine(
+        model, params, max_slots=server["max_slots"],
+        pool_tokens=server["pool_tokens"])))
+    return made[0], params
+
+
+def _moved(text, sizes):
+    """``{(opcode, type): count}`` over the instructions of the
+    compiled text (fused computations included) whose result holds one
+    of ``sizes`` elements and is not held in place."""
+    import collections
+    import math
+    import re
+
+    found = collections.Counter()
+    for line in text.splitlines():
+        m = re.search(r" = (.*?) ([a-z][a-z\-]*)\(", line)
+        if m is None or m.group(2) in _IN_PLACE:
+            continue
+        for dtype, dims in re.findall(r"\b([a-z]+\d+)\[([\d,]+)\]",
+                                      m.group(1)):
+            if math.prod(map(int, dims.split(","))) in sizes:
+                found[m.group(2), f"{dtype}[{dims}]"] += 1
+    return dict(found)
+
+
+@pytest.mark.parametrize("family,config,layers,width", STEP_PROGRAMS)
+def test_serve_step_programs_copy_no_cache(topo, monkeypatch, family,
+                                           config, layers, width):
+    """A decode application does not scan over its cache, so the
+    compiled step holds no copy, slice or update of a pool or of the
+    recurrent state around the kernels that write them in place."""
+    # "auto" resolves as it does on the chip: the kernels where their
+    # envelopes admit the call, XLA elsewhere
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    engine, params = _cell_engine(family, config, layers)
+    assert engine._chunk == 32 or width == 1
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    slots = engine.max_slots
+    rows = jax.ShapeDtypeStruct((slots,), jnp.int32)
+    flags = jax.ShapeDtypeStruct((slots,), jnp.bool_)
+    step = engine._decode if width == 1 else engine._prefill
+    compiled = step.lower(*on_chip((
+        params, engine.cache, engine.state, engine._tables, rows,
+        jax.ShapeDtypeStruct((slots, width), jnp.int32), rows, flags,
+        flags))).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+
+    big = [a for path, a in
+           jax.tree_util.tree_flatten_with_path(engine.cache)[0]
+           if path[-1].key in ("paged_key", "paged_value", "ssm_state")]
+    # a leaf a layer, none stacked
+    assert len(big) == (3 if family == "falcon_h1" else 2) * layers
+    a_layer = {a.size for a in big}
+    stacked = {n * layers for n in a_layer}
+    assert not _moved(text, stacked)
+    moved = _moved(text, a_layer)
+    if width == 1:
+        assert not moved, moved
+        cache_bytes = sum(a.size * a.dtype.itemsize
+                          for a in jax.tree.leaves(engine.cache))
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        # the scan's temporaries exceeded the cache they copied
+        assert temp < cache_bytes / 2, (temp, cache_bytes)
+    else:
+        # what is left to the next PR: the XLA scatter's two
+        # transposed copies a pool a layer (the write of width > 1)
+        copies = sum(n for (op, _), n in moved.items() if op == "copy")
+        assert copies <= 4 * layers, (
+            f"{copies} pool-shaped copies in a {layers}-layer mixed "
+            f"step (the scatter's: 4 a layer); all of pool size: "
+            f"{moved}")
+
+
 # --------------------------------------------------- fused sampling
 def _sample_with_the_kernel(logits, keys, t, k, p):
     return fs.fused_sample(logits, keys, t, k, p,
