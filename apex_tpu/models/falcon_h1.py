@@ -222,9 +222,9 @@ class FalconH1Mixer(nn.Module):
             if cfg.kv_cache != "paged":
                 raise ValueError(
                     "FalconH1Model decodes through the paged serving "
-                    "engine only (kv_cache='paged'): the dense "
-                    "generate() cache keeps no recurrent state — serve "
-                    "with InferenceServer(..., kv_cache='paged')")
+                    "engine only (PagedEngine, which InferenceServer "
+                    "builds): the dense generate() cache keeps no "
+                    "recurrent state")
             st = self.variable("cache", "ssm_state", jnp.zeros,
                                (b, heads, p, n), f32)
             win = self.variable("cache", "conv_state", jnp.zeros,

@@ -9,7 +9,7 @@ tenant.  This op computes one decode/chunk attention step over that
 layout: each query row attends over exactly its own pages, gathered
 through its block table.
 
-Why it matters: the dense slab's steady decode reads (or at best
+Why it matters: a dense slab's steady decode reads (or at best
 cond-skips over) a ``max_seq_len`` cache row per slot per step, and its
 HBM *footprint* reserves ``max_slots × max_seq_len`` tokens no matter
 how short the live sequences are.  Here the footprint, the per-step
@@ -95,7 +95,8 @@ conventions:
   rep·s)`` tile per chunk and kv head.
 - **XLA gather reference** (``implementation="xla"``; golden semantics,
   CPU/GPU fallback): ``k_pages[:, block_tables]`` then a masked fp32
-  einsum — bit-comparable to the dense engine's cache attention.
+  einsum — bit-comparable to the dense cache's attention in
+  ``generate()``.
 
 The *block size itself* is the tunable (the analogue of the row-wise
 kernels' block-rows): sweep it offline with
@@ -341,7 +342,7 @@ def paged_attention_reference(q, k_pages, v_pages, block_tables,
     the Pallas kernel never does); masking is by absolute position, so
     pool garbage beyond ``lengths[b] + i`` is unreachable.  fp32
     softmax, output in ``q.dtype`` — the same numerics contract as the
-    dense engine's cache attention.
+    dense cache's attention in ``generate()``.
 
     With quantized pages (``k_scales``/``v_scales`` given, one fp32
     amax per (kv_head, pool block)), the GATHERED pages are dequantized
@@ -1187,8 +1188,7 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
     follows :mod:`apex_tpu.ops._dispatch`: ``"auto"`` picks the Pallas
     kernel on TPU when the geometry fits its envelope (``block_size``
     and ``head_dim`` multiples of 8, GQA head ratio integral) and the
-    gather reference elsewhere; the serving engine's ``kv_cache="dense"``
-    slab path remains the non-paged fallback one level up.
+    gather reference elsewhere.
 
     Quantized pools (int8 / fp8 pages) REQUIRE ``k_scales``/``v_scales``
     — ``(kv_heads, num_blocks)`` fp32 per-page amax arrays (see the

@@ -292,7 +292,8 @@ def serving_traffic_model(*, num_layers, kv_heads, head_dim,
     """Analytic per-step KV-cache traffic of the serving decode step —
     the measured defect behind the ISSUE-5 paged tentpole, in bytes:
 
-    - **dense** (``serving.Engine``): the slab reserves
+    - **dense** (``generate()``'s cache, ``cfg.kv_cache="dense"``,
+      one row a sequence): the slab reserves
       ``slots × max_seq_len`` tokens of K+V per layer
       (``dense_pool_bytes``), and the steady-decode attention reads a
       whole ``max_seq_len`` row per slot per step — the cursor only

@@ -217,7 +217,7 @@ def emit_plan(model_cfg: Any, layout: Layout,
         slices = [devices[i * tp:(i + 1) * tp]
                   for i in range(layout.dp)]
         tuned = score.get("autotune") or {}
-        kwargs: Dict[str, Any] = {"kv_cache": "paged"}
+        kwargs: Dict[str, Any] = {}
         if tuned.get("autotuned"):
             kwargs["block_size"] = tuned["block_size"]
             kwargs["kv_dtype"] = tuned["kv_dtype"]

@@ -1,28 +1,25 @@
 """apex_tpu.serving — continuous-batching TPU inference engine.
 
 Multi-tenant serving over the model zoo's ``decode=True`` KV-cache
-path, in two cache layouts:
-
-- **paged** (:class:`PagedEngine`, the hot path): a block-pool
-  KV-cache sized in TOKENS with per-slot block tables
-  (:mod:`~apex_tpu.serving.cache`), chunked prefill riding inside the
-  fused mixed prefill+decode step, token-budget admission and
-  block-exhaustion preemption — HBM footprint and per-step bytes
-  scale with live tokens, not ``max_slots × max_seq_len``.  On top:
-  refcounted **copy-on-write prefix sharing** (``share_prefixes=True``
-  — a hot system prompt's KV pages are trie-matched at admission and
-  mapped once per replica instead of once per tenant) and
-  **speculative decoding** (``spec_tokens=K`` — host-side
-  prompt-lookup drafts verified K-at-a-time in one mixed-step
-  application, accepted-prefix + bonus token per step) and
-  **tensor-parallel replicas** (``tp=M`` / ``mesh=`` — ONE replica
-  spans M chips: weights ride the GSPMD TP layers, the pool shards on
-  ``kv_heads`` via the shard_map path of
-  :func:`~apex_tpu.ops.paged_attention.paged_attention`, block
-  tables / trie / allocator stay replicated host logic — the first
-  path that serves a model too big for one chip);
-- **dense** (:class:`Engine`, the fallback): the fixed
-  ``max_slots × max_seq_len`` slotted slab with bucket-padded prefill.
+path.  One engine, :class:`PagedEngine`: a block-pool KV-cache sized
+in TOKENS with per-slot block tables
+(:mod:`~apex_tpu.serving.cache`), chunked prefill riding inside the
+fused mixed prefill+decode step, token-budget admission and
+block-exhaustion preemption — HBM footprint and per-step bytes scale
+with live tokens, not ``max_slots × max_seq_len``.  On top:
+refcounted **copy-on-write prefix sharing** (``share_prefixes=True``
+— a hot system prompt's KV pages are trie-matched at admission and
+mapped once per replica instead of once per tenant),
+**speculative decoding** (``spec_tokens=K`` — host-side
+prompt-lookup drafts verified K-at-a-time in one mixed-step
+application, accepted-prefix + bonus token per step),
+**quantized pages** (``kv_dtype``) and
+**tensor-parallel replicas** (``tp=M`` / ``mesh=`` — ONE replica
+spans M chips: weights ride the GSPMD TP layers, the pool shards on
+``kv_heads`` via the shard_map path of
+:func:`~apex_tpu.ops.paged_attention.paged_attention`, block
+tables / trie / allocator stay replicated host logic — the first
+path that serves a model too big for one chip).
 
 Plus a bounded FIFO queue with admission/eviction at step boundaries
 (:mod:`~apex_tpu.serving.scheduler`), a threaded submit/stream
@@ -31,7 +28,7 @@ front-end with TTFT / step-latency / pool-occupancy telemetry
 (:mod:`~apex_tpu.serving.fleet`): least-loaded health-gated routing
 across N replica servers with circuit breakers, graceful drain,
 replica-kill tenant migration, and queue-depth/TTFT-driven scale
-hooks.  Greedy decode through either engine is token-identical to
+hooks.  Greedy decode through the engine is token-identical to
 ``apex_tpu.models.generate`` — including across a migration; steady
 state is retrace-free and *enforced* so by
 ``tracecheck.retrace_guard``.  See docs/serving.md and docs/fleet.md.
@@ -51,8 +48,6 @@ from apex_tpu.serving.fleet import (
     FleetRouter,
 )
 from apex_tpu.serving.engine import (
-    DEFAULT_BUCKETS,
-    Engine,
     PagedEngine,
     StepOutput,
     prompt_lookup_draft,
@@ -81,7 +76,6 @@ __all__ = [
     "FleetHandle",
     "CircuitBreaker",
     "AutoscaleConfig",
-    "Engine",
     "PagedEngine",
     "StepOutput",
     "BlockAllocator",
@@ -90,7 +84,6 @@ __all__ = [
     "chain_digests",
     "prompt_lookup_draft",
     "tp_mesh",
-    "DEFAULT_BUCKETS",
     "Scheduler",
     "Request",
     "StepEvent",

@@ -24,13 +24,13 @@ import queue as queue_mod
 import threading
 import time
 from collections import deque
-from typing import Any, Callable, Dict, List, Optional, Sequence
+from typing import Any, Callable, Dict, List, Optional
 
 import numpy as np
 
 from apex_tpu.core.mesh import TENSOR_AXIS
 from apex_tpu.resilience import faults
-from apex_tpu.serving.engine import DEFAULT_BUCKETS, Engine, PagedEngine
+from apex_tpu.serving.engine import PagedEngine
 from apex_tpu.serving.scheduler import QueueFull, Request, Scheduler
 from apex_tpu.utils.metrics import (
     MetricsWriter,
@@ -209,75 +209,46 @@ class InferenceServer:
     """
 
     def __init__(self, model, params, *, max_slots: int = 4,
-                 prompt_buckets: Optional[Sequence[int]] = None,
-                 prefill_chunk: int = 0, queue_capacity: int = 64,
+                 prefill_chunk: int = 32, queue_capacity: int = 64,
                  metrics: Optional[MetricsWriter] = None,
                  metrics_interval: int = 32,
-                 kv_cache: str = "dense", block_size: int = 0,
+                 kv_cache: str = "paged", block_size: int = 0,
                  pool_tokens: Optional[int] = None,
                  admit_headroom: Optional[int] = None,
                  share_prefixes: bool = False,
                  spec_tokens: int = 0, spec_ngram: int = 3,
                  kv_dtype: Optional[str] = None,
                  tp: int = 0, mesh: Optional[Any] = None):
-        if kv_cache == "paged":
-            if prompt_buckets is not None:
-                raise ValueError(
-                    "prompt_buckets only applies to kv_cache='dense' "
-                    "— chunked prefill admits any prompt length; "
-                    "tune prefill_chunk (step width) and pool_tokens "
-                    "instead")
-            if tp and mesh is not None:
-                # mesh may be a Mesh or an int (the engine accepts
-                # both); either way its tensor width must agree with
-                # an explicit tp
-                mesh_tp = (mesh if isinstance(mesh, int)
-                           else dict(mesh.shape).get(TENSOR_AXIS, 1))
-                if mesh_tp != tp:
-                    raise ValueError(
-                        f"tp={tp} disagrees with mesh "
-                        f"({TENSOR_AXIS} axis {mesh_tp}) — pass one "
-                        f"or make them match")
-            # chunked prefill needs a chunk width; 0 (the dense
-            # single-call convention) maps to the engine default
-            self.engine: Any = PagedEngine(
-                model, params, max_slots=max_slots,
-                block_size=block_size, pool_tokens=pool_tokens,
-                prefill_chunk=prefill_chunk or 32,
-                admit_headroom=admit_headroom,
-                share_prefixes=share_prefixes,
-                spec_tokens=spec_tokens, spec_ngram=spec_ngram,
-                kv_dtype=kv_dtype,
-                mesh=(mesh if mesh is not None
-                      else (tp if tp and tp > 1 else None)))
-        elif kv_cache == "dense":
-            if share_prefixes or spec_tokens:
-                raise ValueError(
-                    "share_prefixes / spec_tokens require "
-                    "kv_cache='paged' — the dense slab has no page "
-                    "pool to share and no mixed multi-token step to "
-                    "verify drafts in")
-            if (tp and tp > 1) or mesh is not None:
-                raise ValueError(
-                    "tp / mesh require kv_cache='paged' — "
-                    "tensor-parallel serving shards the paged pool "
-                    "on its kv_heads axis (and the matmuls over the "
-                    "GSPMD layers); the dense slab engine is "
-                    "single-chip")
-            if kv_dtype is not None:
-                raise ValueError(
-                    "kv_dtype requires kv_cache='paged' — quantized "
-                    "KV pages live in the paged pool (per-page "
-                    "scales beside the block table); the dense slab "
-                    "stores K/V in the model's compute dtype")
-            self.engine = Engine(
-                model, params, max_slots=max_slots,
-                prompt_buckets=(DEFAULT_BUCKETS if prompt_buckets
-                                is None else prompt_buckets),
-                prefill_chunk=prefill_chunk)
-        else:
+        # kept only because benchmarks/configs/*.json still pass
+        # "kv_cache": "paged" (ROADMAP D2b); there is one engine
+        if kv_cache != "paged":
             raise ValueError(
-                f"kv_cache={kv_cache!r} not in ('dense', 'paged')")
+                f"kv_cache={kv_cache!r}: the dense slab engine was "
+                "removed in PR 34 — PagedEngine is the server's only "
+                "engine and 'paged' the only value; the keyword itself "
+                "goes once the benchmark's configuration files stop "
+                "passing it (ROADMAP D2b), so drop it from the call")
+        if tp and mesh is not None:
+            # mesh may be a Mesh or an int (the engine accepts
+            # both); either way its tensor width must agree with
+            # an explicit tp
+            mesh_tp = (mesh if isinstance(mesh, int)
+                       else dict(mesh.shape).get(TENSOR_AXIS, 1))
+            if mesh_tp != tp:
+                raise ValueError(
+                    f"tp={tp} disagrees with mesh "
+                    f"({TENSOR_AXIS} axis {mesh_tp}) — pass one "
+                    f"or make them match")
+        self.engine = PagedEngine(
+            model, params, max_slots=max_slots,
+            block_size=block_size, pool_tokens=pool_tokens,
+            prefill_chunk=prefill_chunk,
+            admit_headroom=admit_headroom,
+            share_prefixes=share_prefixes,
+            spec_tokens=spec_tokens, spec_ngram=spec_ngram,
+            kv_dtype=kv_dtype,
+            mesh=(mesh if mesh is not None
+                  else (tp if tp and tp > 1 else None)))
         self.scheduler = Scheduler(self.engine,
                                    queue_capacity=queue_capacity)
         self.metrics = metrics
@@ -357,7 +328,7 @@ class InferenceServer:
         each handle with :class:`ReplicaDraining` so a fleet router
         can migrate it (``prompt ++ streamed tokens`` onto a
         survivor).  The engine releases every slot through the normal
-        compiled ``release`` — a paged pool returns to
+        compiled ``release`` — the pool returns to
         ``blocks_in_use == 0`` — and the worker then idles until
         :meth:`shutdown`.  Without a router on top, clients simply
         observe :class:`ServerClosed` (its base class)."""
@@ -374,7 +345,7 @@ class InferenceServer:
         """SIGKILL-equivalent death for chaos drills (the
         ``replica.kill`` fault site routes here): the worker stops
         WITHOUT draining and WITHOUT releasing engine state — a real
-        SIGKILL takes the host's device memory with it — so a paged
+        SIGKILL takes the host's device memory with it — so the
         pool's accounting is abandoned mid-flight (``blocks_in_use``
         stays nonzero; the replica is dead, not reusable).  Every
         queued and in-flight handle fails with :class:`ServerClosed`;
@@ -637,7 +608,7 @@ class InferenceServer:
         ``exc.slots`` names the poisoned slots when attribution exists;
         with none, every active slot is suspect (the fault fired before
         any of them stepped).  A tenant already requeued once — or one
-        whose continuation no longer fits a bucket — fails terminally
+        whose continuation no longer fits the context — fails terminally
         with :class:`RequestFailed`; the server itself keeps serving.
         """
         counters.inc("serving.step_fault")
@@ -709,7 +680,8 @@ class InferenceServer:
 
     def _emit_metrics(self, now: float) -> None:
         dt = max(now - (self._window_t0 or now), 1e-9)
-        chips = int(getattr(self.engine, "chips_per_replica", 1))
+        engine = self.engine
+        chips = engine.chips_per_replica
         payload = {
             "tokens_per_sec": self._window_tokens / dt,
             # the Gemma-paper serving protocol reports throughput PER
@@ -725,26 +697,23 @@ class InferenceServer:
             "failed_requests": self._failed_requests,
             "deadline_expired": self._deadline_expired,
             "preempts": self.scheduler.preempts,
-        }
-        payload.update(self.latency_summary())
-        blocks_total = getattr(self.engine, "blocks_total", None)
-        if blocks_total:
-            # pool occupancy gauge (paged engine): the overcommit dial
-            payload["blocks_in_use"] = self.engine.blocks_in_use
-            payload["blocks_total"] = blocks_total
-            payload["live_tokens"] = self.engine.live_tokens
-            # prefix-sharing gauges (0 when off); the accept rate only
-            # when drafting is configured — a fleet-mean over
-            # spec-disabled replicas' hardwired 0.0 would dilute it
-            payload["shared_blocks"] = self.engine.shared_blocks
-            payload["cow_forks"] = self.engine.cow_forks
+            # pool occupancy gauge: the overcommit dial
+            "blocks_in_use": engine.blocks_in_use,
+            "blocks_total": engine.blocks_total,
+            "live_tokens": engine.live_tokens,
+            # prefix-sharing gauges (0 when off)
+            "shared_blocks": engine.shared_blocks,
+            "cow_forks": engine.cow_forks,
             # pool storage width (8 = quantized int8/fp8 pages) —
             # numeric so any sink can plot/aggregate it; the dtype
             # NAME rides health()
-            payload["kv_bits"] = self.engine.kv_bits
-            if getattr(self.engine, "spec_tokens", 0):
-                payload["spec_accept_rate"] = \
-                    self.engine.spec_accept_rate
+            "kv_bits": engine.kv_bits,
+        }
+        payload.update(self.latency_summary())
+        if engine.spec_tokens:
+            # only when drafting is configured — a fleet-mean over
+            # spec-disabled replicas' hardwired 0.0 would dilute it
+            payload["spec_accept_rate"] = engine.spec_accept_rate
         self.metrics(self._steps, payload)
         self.metrics.drain()
         self._last_emit_step = self._steps
@@ -768,6 +737,7 @@ class InferenceServer:
         ``docs/serving.md``.
         """
         now = time.monotonic()
+        engine = self.engine
         with self._wakeup:
             alive = self._thread is not None and self._thread.is_alive()
             stopping = self._stop
@@ -799,47 +769,43 @@ class InferenceServer:
             # changed state
             "spans": {**self.spans.snapshot(),
                       **self.scheduler.spans.snapshot(),
-                      **self.engine.spans.snapshot()},
+                      **engine.spans.snapshot()},
             "admitted": self.scheduler.admitted,
             "queue_wait_s": self.scheduler.queue_wait_s,
             "first_tokens": self._first_tokens,
             "prefill_s": self._prefill_s,
-            "compiles": sum(self.engine.trace_counts.values()),
+            "compiles": sum(engine.trace_counts.values()),
             "error": None if error is None else repr(error),
-            # chips this ONE replica spans (tensor-parallel paged
-            # engine; 1 everywhere else) — the fleet's capacity math
+            # chips this ONE replica spans (the tensor-parallel
+            # degree; 1 on a single chip) — the fleet's capacity math
             # and the per-chip throughput protocol both read it
-            "chips_per_replica": int(
-                getattr(self.engine, "chips_per_replica", 1)),
+            "chips_per_replica": engine.chips_per_replica,
+            "blocks_in_use": engine.blocks_in_use,
+            "blocks_total": engine.blocks_total,
+            "live_tokens": engine.live_tokens,
+            "shared_blocks": engine.shared_blocks,
+            "cow_forks": engine.cow_forks,
+            "kv_pages_live": engine.kv_pages_live,
+            "kv_dtype": engine.kv_dtype,
+            "kv_bits": engine.kv_bits,
         }
-        mesh_shape = getattr(self.engine, "mesh_shape", None)
+        mesh_shape = engine.mesh_shape
         if mesh_shape:
             out["mesh_shape"] = mesh_shape
-        blocks_total = getattr(self.engine, "blocks_total", None)
-        if blocks_total:
-            out["blocks_in_use"] = self.engine.blocks_in_use
-            out["blocks_total"] = blocks_total
-            out["live_tokens"] = self.engine.live_tokens
-            out["shared_blocks"] = self.engine.shared_blocks
-            out["cow_forks"] = self.engine.cow_forks
-            out["kv_pages_live"] = self.engine.kv_pages_live
-            out["kv_dtype"] = self.engine.kv_dtype
-            out["kv_bits"] = self.engine.kv_bits
-            if getattr(self.engine, "spec_tokens", 0):
-                out["spec_accept_rate"] = self.engine.spec_accept_rate
-            if getattr(self.engine, "ssm_state_bytes", 0):
-                # recurrent state beside the pages (docs/serving.md)
-                out["ssm_state_bytes"] = self.engine.ssm_state_bytes
-                out["ssm_state_resets"] = self.engine.ssm_state_resets
-                out["ssm_positions"] = self.engine.ssm_positions
+        if engine.spec_tokens:
+            out["spec_accept_rate"] = engine.spec_accept_rate
+        if engine.ssm_state_bytes:
+            # recurrent state beside the pages (docs/serving.md)
+            out["ssm_state_bytes"] = engine.ssm_state_bytes
+            out["ssm_state_resets"] = engine.ssm_state_resets
+            out["ssm_positions"] = engine.ssm_positions
         return out
 
     def prefix_hit_blocks(self, prompt) -> int:
         """Pages of ``prompt``'s prefix already resident in this
-        server's trie (0 for dense engines or with sharing off) — the
-        fleet router's prefix-affinity key."""
-        fn = getattr(self.engine, "prefix_hit_blocks", None)
-        return 0 if fn is None else int(fn(prompt))
+        server's trie (0 with sharing off) — the fleet router's
+        prefix-affinity key."""
+        return self.engine.prefix_hit_blocks(prompt)
 
     # ---------------------------------------------------------- telemetry
     @property

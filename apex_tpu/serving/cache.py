@@ -1,26 +1,24 @@
-"""Slotted KV-cache pool — the static-shape substrate of the engine.
+"""The serving engine's state: slot rows on the device, pages on the host.
 
-Continuous batching needs per-sequence cache state (each tenant sits at
-its own decode position), but TPU-friendly programs need *one* set of
-shapes for the process lifetime.  The resolution: the model's per-slot
-decode cache (the ``init_cache`` pytree at batch=1) is stacked along a
-new leading **slot** axis into a ``(max_slots, ...)`` pool, and every
-mutation is a functional scatter at a *traced* slot index — admission
-overwrites one slot row, eviction zeroes it, decode advances all rows
-together.  Shapes never change: one compiled executable serves any mix
-of tenants.
+Continuous batching needs per-sequence state (each tenant sits at its
+own decode position), but TPU-friendly programs need *one* set of
+shapes for the process lifetime.  Two pieces resolve that:
 
 Per-slot scalar bookkeeping (active mask, next token, produced count,
 token budget, sampling params, rng key) lives in :class:`SlotState` —
 plain ``(max_slots,)`` device arrays carried through the jitted step,
 NOT static jit arguments, so heterogeneous sampling configs share one
-executable (the ISSUE 2 tentpole contract).
+executable.  Admission and release are functional updates of those
+rows (:func:`admit_slots`, :func:`release_slot`).
 
-Only the **dense** cache layout is supported: the rolling ring-buffer
-cache of sliding-window models keys visibility off per-slot positions,
-which the engine's rewind-on-admit trick (see
-:func:`rewind_index_leaves`) cannot restate; :func:`validate_cache_tree`
-rejects it loudly.
+The K/V cache is a pool of fixed-size **pages** shared by every
+tenant.  Which page holds which tokens is host state: the refcounted
+:class:`BlockAllocator`, the :class:`PrefixTrie` of sharable prompt
+pages, and the per-slot block tables and cursors the engine writes
+over the cache tree's leaves before every step
+(:func:`set_paged_leaves`).  Under a tensor-parallel mesh the pool
+leaves shard on ``kv_heads`` and everything else stays replicated
+(:func:`paged_pool_shardings`).
 """
 
 from __future__ import annotations
@@ -36,12 +34,6 @@ import numpy as np
 __all__ = [
     "SlotState",
     "init_slot_state",
-    "validate_cache_tree",
-    "stacked_zeros",
-    "zeros_from_shapes",
-    "write_slot",
-    "reset_slot",
-    "rewind_index_leaves",
     "BlockAllocator",
     "BlockExhausted",
     "blocks_for",
@@ -49,14 +41,6 @@ __all__ = [
     "PrefixTrie",
     "chain_digests",
 ]
-
-# cache leaves that hold *positions* rather than keys/values: the
-# per-layer attention write cursor and (learned-position models) the
-# model-level position cursor.  rewind_index_leaves targets these.
-_INDEX_LEAF_NAMES = ("cache_index", "position_index")
-
-# ring-buffer-only leaf: its presence marks a sliding-window cache
-_RING_LEAF = "slot_positions"
 
 
 def _leaf_name(path) -> str:
@@ -67,76 +51,6 @@ def _leaf_name(path) -> str:
         if val is not None:
             return str(val)
     return str(last)
-
-
-def validate_cache_tree(shapes: Any) -> None:
-    """Reject cache structures the slot pool cannot manage.
-
-    ``shapes``: the per-slot cache as ShapeDtypeStructs (from
-    ``apex_tpu.models.generate.cache_shapes(model, 1)``).  Raises
-    ``ValueError`` for ring-buffer (sliding-window) caches.
-    """
-    leaves = jax.tree_util.tree_flatten_with_path(shapes)[0]
-    for path, _leaf in leaves:
-        if _leaf_name(path) == _RING_LEAF:
-            raise ValueError(
-                "the serving engine requires the dense KV-cache layout; "
-                "this model uses the sliding-window ring-buffer cache "
-                f"(found a {_RING_LEAF!r} leaf).  Serve sliding-window "
-                "models with sliding_window=None (or >= max_seq_len) — "
-                "the dense cache computes the same function whenever "
-                "sequences stay within the window")
-
-
-def stacked_zeros(shapes: Any, max_slots: int) -> Any:
-    """All-zero slot pool: each per-slot leaf gains a leading
-    ``(max_slots,)`` axis.  Zeros ARE the initialized cache (the
-    ``init_cache`` zeros-from-shape invariant)."""
-    return jax.tree.map(
-        lambda s: jnp.zeros((max_slots,) + tuple(s.shape), s.dtype),
-        shapes)
-
-
-def zeros_from_shapes(shapes: Any) -> Any:
-    """One slot's fresh zero cache (used inside the jitted prefill)."""
-    return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), shapes)
-
-
-def write_slot(pool: Any, slot, one: Any) -> Any:
-    """Scatter a per-slot cache into row ``slot`` of the pool
-    (traceable; ``slot`` is a traced scalar, so admission into any slot
-    replays one compiled executable)."""
-    return jax.tree.map(lambda big, small: big.at[slot].set(small),
-                        pool, one)
-
-
-def reset_slot(pool: Any, slot) -> Any:
-    """Zero row ``slot`` (eviction hygiene: stale K/V never outlives
-    its tenant, even though admission fully overwrites the row)."""
-    return jax.tree.map(
-        lambda big: big.at[slot].set(jnp.zeros_like(big[slot])), pool)
-
-
-def rewind_index_leaves(cache: Any, position) -> Any:
-    """Set every index leaf (``cache_index`` / ``position_index``) to
-    ``position``, leaving K/V leaves untouched.
-
-    The admission trick: a prompt right-padded to its bucket prefills
-    positions ``[0, bucket)``; rewinding the cursors to
-    ``true_len - 1`` makes the next decode step re-feed the last real
-    prompt token at its true position.  Pad K/V beyond the cursor is
-    invisible — cache attention masks positions ``> index``, and every
-    later token overwrites its slot before attending — so the padded
-    prefill computes exactly the unpadded function.
-    """
-    pos = jnp.asarray(position, jnp.int32)
-
-    def fix(path, leaf):
-        if _leaf_name(path) in _INDEX_LEAF_NAMES:
-            return jnp.full(leaf.shape, pos, leaf.dtype)
-        return leaf
-
-    return jax.tree_util.tree_map_with_path(fix, cache)
 
 
 class SlotState(NamedTuple):
@@ -275,8 +189,8 @@ class BlockAllocator:
     """Host-side refcounted free list over the physical page pool.
 
     The pool is sized in TOKENS (``num_blocks × block_size``), shared
-    by every tenant — the paged tentpole's replacement for the dense
-    ``max_slots × max_seq_len`` reservation.  Physical block 0 is the
+    by every tenant — no slot reserves ``max_seq_len`` positions of
+    its own.  Physical block 0 is the
     reserved **null page**: unallocated block-table entries point at
     it, pad-token writes land in it, and the position mask keeps its
     contents unreachable — so it is never handed out.

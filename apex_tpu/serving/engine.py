@@ -1,54 +1,55 @@
-"""Continuous-batching decode engine over the slotted KV-cache pool.
+"""Continuous-batching decode engine over a paged KV-cache pool.
 
-One model, ``max_slots`` concurrent tenants, four compiled
-executables for the engine's whole lifetime:
+One model, ``max_slots`` concurrent tenants, and at most five compiled
+executables for the engine's whole lifetime (:class:`PagedEngine`):
 
-- ``decode_step``  — ONE trace: vmap over slots of the model's
-  ``decode=True`` single-token path, followed by branchless per-slot
-  sampling whose parameters (temperature / top_k / top_p / eos /
-  budget) are device arrays in
+- ``decode_step``  — the width-1 step: ONE application of the model's
+  ``decode=True`` path over the whole ragged batch (per-row cursors
+  and block tables live in the cache collection; attention goes through
+  :func:`apex_tpu.ops.paged_attention`), followed by branchless
+  per-slot sampling whose parameters (temperature / top_k / top_p /
+  eos / budget) are device arrays in
   :class:`~apex_tpu.serving.cache.SlotState` — mixed sampling configs
   (nucleus sampling included) share the executable.  The sampling
-  tail is the FUSED epilogue of :mod:`apex_tpu.ops.fused_sampling`
-  (ISSUE 14): one Pallas pass over the ``(slots, vocab)`` logits on
-  TPU, the sort-based reference elsewhere — token-identical either
-  way, and the reference now ``lax.cond``-skips its sort when no
-  admitted row enables top-k/top-p.
-- ``prefill``      — one trace PER PROMPT BUCKET: the prompt, right-
-  padded to its bucket length, runs through the shared chunked-prefill
-  path (``apex_tpu.models.generate.prefill_tokens``) into a fresh
-  per-slot cache, whose cursors are then rewound to ``true_len - 1``
-  so the first decode step re-feeds the last real prompt token (pad
-  K/V beyond the cursor is masked, then overwritten — the padded
-  prefill computes exactly the unpadded function).
-- ``admit``        — ONE trace: scatter the prefilled slot cache +
-  tenant params into the pool at a traced slot index.
-- ``release``      — ONE trace: zero the slot row, clear the active bit.
+  tail is the FUSED epilogue of :mod:`apex_tpu.ops.fused_sampling`:
+  one Pallas pass over the ``(slots, vocab)`` logits on TPU, the
+  sort-based reference elsewhere — token-identical either way, and
+  the reference ``lax.cond``-skips its sort when no admitted row
+  enables top-k/top-p.
+- ``prefill_step`` — the same function at width ``prefill_chunk``:
+  the mixed step in which prompts ride as chunks beside decoding
+  tenants, so any prompt length replays one shape.
+- ``spec_step``    — only with ``spec_tokens > 0``: the width
+  ``1 + spec_tokens`` draft/verify step.
+- ``admit``        — scatter every waiting tenant's token, budget,
+  sampling parameters and key into the slot state in one call; no
+  cache writes.
+- ``release``      — clear a slot's active bit.
 
 Every executable is wrapped in
-:func:`apex_tpu.utils.tracecheck.retrace_guard` with exactly that
-budget, so a shape or signature leak raises ``RetraceError`` instead of
-silently recompiling per request — the engine *enforces* its own
+:func:`apex_tpu.utils.tracecheck.retrace_guard` with a budget of
+exactly 1, so a shape or signature leak raises ``RetraceError`` instead
+of silently recompiling per request — the engine *enforces* its own
 zero-retrace steady state rather than merely promising it.
 
 Greedy decoding through the engine is token-identical to
-``generate()``: same prefill path, same fp32 argmax; the refeed step
-recomputes the last prompt position's K/V bit-compatibly up to
-blocked-vs-einsum accumulation order (≈1e-7 — far below argmax
-resolution on real logits).
+``generate()``: the same model code computes every position (chunked
+prefill and paged attention change the schedule and the cache layout,
+not the function), followed by the same fp32 argmax; sampled chains
+are a function of the request's seed alone.
 
 The step boundary is the only device→host sync: ``step()`` returns the
-per-slot tokens and finished flags as numpy so the scheduler can evict
-and refill.  Inactive slots still compute (static shapes — no dynamic
-batch); their outputs are ignored on the host and their slot rows are
-fully rebuilt at the next admission.
+per-slot tokens, counts and finished flags as numpy
+(:class:`StepOutput`) so the scheduler can evict and refill.  Inactive
+slots still compute (static shapes — no dynamic batch) into the null
+page; their outputs are ignored on the host.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import time
-from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Tuple
+from typing import Dict, List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -56,11 +57,7 @@ import jax
 import jax.numpy as jnp
 
 from apex_tpu.core.mesh import TENSOR_AXIS
-from apex_tpu.models.generate import (
-    apply_decode,
-    cache_shapes,
-    prefill_tokens,
-)
+from apex_tpu.models.generate import apply_decode, cache_shapes
 from apex_tpu.ops.fused_sampling import fused_sample, \
     fused_sample_reference
 from apex_tpu.ops.paged_attention import tp_head_shards
@@ -69,15 +66,14 @@ from apex_tpu.utils import tracecheck
 from apex_tpu.utils.metrics import counters
 from apex_tpu.utils.profiler import SpanTotals, span
 
-__all__ = ["Engine", "PagedEngine", "StepOutput", "sample_dynamic",
-           "prompt_lookup_draft", "DEFAULT_BUCKETS", "tp_mesh"]
+__all__ = ["PagedEngine", "StepOutput", "sample_dynamic",
+           "prompt_lookup_draft", "tp_mesh"]
 
-#: the engines' spans (``apex_tpu.utils.profiler.span``): one engine
+#: the engine's spans (``apex_tpu.utils.profiler.span``): one engine
 #: step, named by the program it runs, and its four parts — ``plan``
 #: (drafts, feed, page allocation), ``dispatch`` (the jitted call:
 #: enqueue only), ``fetch`` (the host's wait for the device and the
-#: copy back) and ``commit`` (host mirrors of the step).  The dense
-#: engine has ``step_decode`` and ``fetch`` only.
+#: copy back) and ``commit`` (host mirrors of the step).
 STEP_PREFILL = "apex/engine/step_prefill"
 STEP_DECODE = "apex/engine/step_decode"
 STEP_SPEC = "apex/engine/step_spec"
@@ -152,9 +148,6 @@ def _pin_replicated(tree, mesh):
         lambda x: jax.lax.with_sharding_constraint(x, repl), tree)
 
 
-DEFAULT_BUCKETS: Tuple[int, ...] = (32, 128, 512)
-
-
 class StepOutput(NamedTuple):
     """One engine step's host-visible result.
 
@@ -211,7 +204,7 @@ def prompt_lookup_draft(context: np.ndarray, k: int,
 
 
 def _check_sampling(vocab_size: int, top_k, top_p) -> None:
-    """Shared sampling-parameter validation (dense + paged engines)."""
+    """Sampling-parameter validation (``validate_request``)."""
     if top_k is not None and top_k != 0 \
             and not 1 <= top_k <= vocab_size:
         raise ValueError(
@@ -227,7 +220,7 @@ def sample_dynamic(logits, keys, temperature, top_k, top_p,
                    vocab_size: int):
     """Branchless per-row sampling with DEVICE-ARRAY parameters.
 
-    The engines' historical sampling tail, now living in
+    The engine's historical sampling tail, now living in
     :func:`apex_tpu.ops.fused_sampling.fused_sample_reference` as the
     golden semantics (and the non-Pallas dispatch target) of the fused
     one-pass sampling kernel — this name stays as the reference entry
@@ -238,7 +231,7 @@ def sample_dynamic(logits, keys, temperature, top_k, top_p,
     parameters; an all-greedy / plain-temperature step now
     ``lax.cond``-skips the whole sort + softmax + cumsum tail at
     runtime (bitwise-equivalent on that predicate — see the ops
-    module).  The engines themselves call
+    module).  The engine itself calls
     :func:`~apex_tpu.ops.fused_sampling.fused_sample`, which resolves
     to the one-pass Pallas kernel on TPU and to exactly this
     composition elsewhere.
@@ -265,268 +258,6 @@ def _active_sampling_params(state):
             jnp.where(state.active, state.top_p, 0.0))
 
 
-class Engine:
-    """Multi-tenant KV-cached decode over one model.
-
-    Host API (single-threaded — callers serialize; the
-    ``apex_tpu.serving.api`` server owns one engine per worker thread):
-
-    - ``admit(slot, prompt, *, max_new_tokens, ...)`` — prefill +
-      install one request into a free slot.
-    - ``step()`` — decode every slot one token; returns
-      ``(tokens, finished)`` numpy arrays of length ``max_slots``
-      (only slots the caller knows to be occupied carry meaning).
-    - ``release(slot)`` — zero + free a slot.
-    - ``warmup()`` — trace all executables (one dummy request per
-      prompt bucket) so steady state is retrace-free from request one.
-
-    ``prompt_buckets`` quantizes prompt lengths: a prompt compiles
-    nothing new as long as its length fits an existing bucket, so the
-    compile count is ``len(buckets) + 3`` for the process lifetime.
-    """
-
-    #: dense slab layout — :class:`PagedEngine` is the paged twin
-    paged = False
-
-    def __init__(self, model, params, *, max_slots: int = 4,
-                 prompt_buckets: Sequence[int] = DEFAULT_BUCKETS,
-                 prefill_chunk: int = 0):
-        cfg = getattr(model, "cfg", None)
-        if cfg is None or not hasattr(cfg, "max_seq_len"):
-            raise ValueError(
-                "Engine needs a model with a .cfg carrying max_seq_len "
-                "and vocab_size (GPTModel / LlamaModel contract)")
-        if not getattr(cfg, "causal", True):
-            raise ValueError("Engine requires a causal model "
-                             "(decode=True contract)")
-        if getattr(cfg, "kv_cache", "dense") == "paged":
-            raise ValueError(
-                "this model is configured for the paged KV-cache "
-                "(cfg.kv_cache='paged') — serve it through "
-                "PagedEngine, or pass the dense twin (the engines "
-                "build their own layout twin from cfg)")
-        if max_slots < 1:
-            raise ValueError(f"max_slots must be >= 1, got {max_slots}")
-        if prefill_chunk < 0:
-            raise ValueError(
-                f"prefill_chunk must be >= 0, got {prefill_chunk}")
-        self.model = model
-        self.max_slots = int(max_slots)
-        self.max_seq_len = int(cfg.max_seq_len)
-        self.vocab_size = int(cfg.vocab_size)
-        buckets = sorted({int(b) for b in prompt_buckets})
-        if not buckets or buckets[0] < 1:
-            raise ValueError(
-                f"prompt_buckets must be positive, got {prompt_buckets}")
-        if buckets[-1] >= self.max_seq_len:
-            # == is useless too: a max_seq_len prompt has no cache room
-            # left to generate even one token
-            raise ValueError(
-                f"largest prompt bucket ({buckets[-1]}) must be < "
-                f"max_seq_len ({self.max_seq_len}) — the cache must "
-                f"hold prompt + generated tokens")
-        self.prompt_buckets = tuple(buckets)
-        self._prefill_chunk = int(prefill_chunk)
-        self._variables = dict(params)
-        if "cache" in self._variables:
-            raise ValueError(
-                "params must not carry a 'cache' collection — the "
-                "engine owns the cache pool")
-        self._shapes = cache_shapes(model, 1)
-        slot_cache.validate_cache_tree(self._shapes)
-        self.cache = slot_cache.stacked_zeros(self._shapes, max_slots)
-        self.state = slot_cache.init_slot_state(max_slots)
-        self.spans = SpanTotals((STEP_DECODE, FETCH))
-        self._build()
-
-    # ------------------------------------------------------------- jits
-    def _build(self) -> None:
-        model = self.model
-        shapes = self._shapes
-        vocab = self.vocab_size
-        prefill_chunk = self._prefill_chunk
-
-        def decode_step(variables, pool, state):
-            # one token for every slot: vmap of the b=1 decode path
-            # over the slot axis — per-slot cache cursors make each
-            # row attend at its own position (the scalar cache_index
-            # of the plain batched path advances in lockstep and
-            # cannot express ragged tenants)
-            def one_slot(cache_i, tok_i):
-                logits, cache_o = apply_decode(
-                    model, variables, cache_i, tok_i[None, None])
-                return logits[0, -1], cache_o
-
-            logits, pool = jax.vmap(one_slot)(pool, state.tok)
-            split = jax.vmap(jax.random.split)(state.rng)
-            # the fused decode epilogue: one-pass Pallas sampling on
-            # TPU, the sample_dynamic reference elsewhere — tokens
-            # identical either way (ops/fused_sampling parity
-            # contract); released slots' stale filter params are
-            # masked so the sort short-circuit tracks live traffic
-            temp, top_k, top_p = _active_sampling_params(state)
-            nxt = fused_sample(logits, split[:, 0], temp, top_k,
-                               top_p, vocab_size=vocab)
-            produced = state.produced + state.active.astype(jnp.int32)
-            hit_budget = produced >= state.budget
-            hit_eos = (state.eos_id >= 0) & (nxt == state.eos_id)
-            finished = state.active & (hit_budget | hit_eos)
-            state = state._replace(
-                tok=jnp.where(state.active, nxt, state.tok),
-                produced=produced,
-                active=state.active & ~finished,
-                rng=split[:, 1])
-            return pool, state, nxt, finished
-
-        def prefill(variables, prompt, true_len):
-            # prompt: (1, bucket_len) right-padded; true_len: traced
-            fresh = slot_cache.zeros_from_shapes(shapes)
-            _last, filled = prefill_tokens(
-                model, variables, fresh, prompt, prefill_chunk)
-            return slot_cache.rewind_index_leaves(filled, true_len - 1)
-
-        def admit(pool, state, slot, one, tok, budget, temperature,
-                  top_k, top_p, eos_id, seed):
-            pool = slot_cache.write_slot(pool, slot, one)
-            state = slot_cache.admit_slot(
-                state, slot, tok, budget, temperature, top_k, top_p,
-                eos_id, seed)
-            return pool, state
-
-        def release(pool, state, slot):
-            return (slot_cache.reset_slot(pool, slot),
-                    slot_cache.release_slot(state, slot))
-
-        # exact retrace budgets: ANY excess trace raises RetraceError —
-        # the engine's zero-retrace steady state is enforced, not
-        # aspirational.  The pool/state threads through with donation
-        # (two live copies of max_slots × max_seq_len K/V would double
-        # the engine's HBM footprint).
-        self._step = tracecheck.retrace_guard(
-            decode_step, max_traces=1, name="serving.decode_step",
-            donate_argnums=(1, 2))
-        self._prefill = tracecheck.retrace_guard(
-            prefill, max_traces=len(self.prompt_buckets),
-            name="serving.prefill")
-        self._admit = tracecheck.retrace_guard(
-            admit, max_traces=1, name="serving.admit",
-            donate_argnums=(0, 1))
-        self._release = tracecheck.retrace_guard(
-            release, max_traces=1, name="serving.release",
-            donate_argnums=(0, 1))
-
-    # ------------------------------------------------------------- host
-    def bucket_for(self, prompt_len: int) -> int:
-        """Smallest configured bucket holding ``prompt_len`` tokens."""
-        for b in self.prompt_buckets:
-            if prompt_len <= b:
-                return b
-        raise ValueError(
-            f"prompt of {prompt_len} tokens exceeds the largest "
-            f"prompt bucket ({self.prompt_buckets[-1]}); configure "
-            f"larger prompt_buckets")
-
-    def validate_request(self, prompt_len: int, max_new_tokens: int,
-                         temperature: float = 0.0,
-                         top_k: Optional[int] = None,
-                         top_p: Optional[float] = None) -> int:
-        """Static admission checks; returns the prompt's bucket."""
-        if prompt_len < 1:
-            raise ValueError("empty prompt")
-        if max_new_tokens < 1:
-            raise ValueError(
-                f"max_new_tokens must be >= 1, got {max_new_tokens}")
-        bucket = self.bucket_for(prompt_len)
-        if prompt_len + max_new_tokens > self.max_seq_len:
-            raise ValueError(
-                f"prompt_len ({prompt_len}) + max_new_tokens "
-                f"({max_new_tokens}) exceeds max_seq_len "
-                f"({self.max_seq_len})")
-        _check_sampling(self.vocab_size, top_k, top_p)
-        del temperature      # any float is admissible (<=0 -> greedy)
-        return bucket
-
-    def can_admit(self, prompt_len: int, max_new_tokens: int,
-                  prompt=None) -> bool:
-        """Dense pool: the slab reserves worst-case room per slot, so
-        a free slot is always admissible (the scheduler gates on slot
-        availability; the paged engine gates on free blocks — shared-
-        prefix-discounted — here)."""
-        del prompt_len, max_new_tokens, prompt
-        return True
-
-    def admit(self, slot: int, prompt, *, max_new_tokens: int,
-              temperature: float = 0.0, top_k: Optional[int] = None,
-              top_p: Optional[float] = None,
-              eos_id: Optional[int] = None, seed: int = 0) -> None:
-        """Prefill ``prompt`` (1-D int tokens) and install it in
-        ``slot``.  The caller owns slot accounting (the scheduler's
-        host-side table); admitting over an occupied slot silently
-        replaces the tenant."""
-        prompt = np.asarray(prompt, np.int32).reshape(-1)
-        bucket = self.validate_request(
-            prompt.shape[0], max_new_tokens, temperature, top_k, top_p)
-        if not 0 <= slot < self.max_slots:
-            raise ValueError(
-                f"slot must be in [0, {self.max_slots}), got {slot}")
-        padded = np.zeros((1, bucket), np.int32)
-        padded[0, :prompt.shape[0]] = prompt
-        one = self._prefill(self._variables, jnp.asarray(padded),
-                            np.int32(prompt.shape[0]))
-        self.cache, self.state = self._admit(
-            self.cache, self.state, np.int32(slot), one,
-            np.int32(prompt[-1]), np.int32(max_new_tokens),
-            np.float32(temperature), np.int32(top_k or 0),
-            np.float32(0.0 if top_p is None else top_p),
-            np.int32(-1 if eos_id is None else eos_id),
-            np.uint32(seed))
-
-    def step(self) -> Tuple[np.ndarray, np.ndarray]:  # graftlint: hot-step
-        """Decode one token for every slot.
-
-        Returns ``(tokens, finished)`` — numpy, length ``max_slots``.
-        ``finished[i]`` latches when slot i produced its eos or spent
-        its budget this step (the slot is already marked free on
-        device; the caller should :meth:`release` it to zero the row).
-        The single per-step host sync lives here.
-        """
-        with span(self.spans, STEP_DECODE):
-            self.cache, self.state, toks, finished = self._step(
-                self._variables, self.cache, self.state)
-            with span(self.spans, FETCH):
-                # graftlint: unsharded(the engine's single per-step host sync — the scheduler needs the sampled tokens to route)
-                return np.asarray(toks), np.asarray(finished)
-
-    def release(self, slot: int) -> None:
-        """Zero and free ``slot``."""
-        self.cache, self.state = self._release(
-            self.cache, self.state, np.int32(slot))
-
-    def warmup(self) -> None:
-        """Trace every executable up front: one dummy tenant per
-        prompt bucket through admit → step → release.  After this, a
-        steady-state soak over any request mix triggers zero retraces
-        (and the retrace guards would raise if it did)."""
-        for bucket in self.prompt_buckets:
-            self.admit(0, np.zeros((bucket,), np.int32),
-                       max_new_tokens=1)
-            self.step()
-            self.release(0)
-
-    @property
-    def trace_counts(self) -> dict:
-        """Observed traces per executable (diagnostics / tests)."""
-        return {
-            "decode_step": self._step.trace_count,
-            "prefill": self._prefill.trace_count,
-            "admit": self._admit.trace_count,
-            "release": self._release.trace_count,
-        }
-
-
-# --------------------------------------------------------------------- #
-# paged engine — token-granular serving datapath
-# --------------------------------------------------------------------- #
 #: what PagedEngine reads off a model's ``.cfg`` (any model class)
 _MODEL_CONTRACT = ("max_seq_len", "vocab_size", "num_heads", "kv_heads",
                    "head_dim", "dtype")
@@ -553,23 +284,21 @@ class _Tenant:
 class PagedEngine:
     """Continuous-batching decode over a PAGED KV-cache pool.
 
-    The dense :class:`Engine` reserves a ``max_slots × max_seq_len``
-    K/V slab and admits via bucket-padded whole-prompt prefill.  This
-    engine instead:
+    The engine:
 
     - stores K/V in fixed-size **pages** of a pool sized in TOKENS
       (``pool_tokens``), shared across tenants through per-slot block
       tables (:class:`~apex_tpu.serving.cache.BlockAllocator`) — HBM
       footprint and per-step attention bytes scale with live tokens,
-      so the same budget holds several times the dense slot count;
+      not with ``max_slots × max_seq_len``;
     - runs **chunked prefill inside the decode step**: prompts are
       split into ``prefill_chunk``-token pieces that ride the regular
       step beside decoding tenants (ONE fused mixed prefill+decode
       executable), so a long prompt can never head-of-line-block
       co-tenants and per-step latency is bounded by the chunk;
     - the whole ragged batch is ONE model application — per-row
-      cursors/block tables in the cache collection replace the dense
-      engine's per-slot vmap, and attention goes through
+      cursors/block tables in the cache collection let every row
+      attend at its own position, and attention goes through
       :func:`apex_tpu.ops.paged_attention`.  The collection holds one
       subtree a LAYER (``.../layer_{i}/attention/paged_key``, ...;
       no leaf has a layer axis, whether the model scans its layers or
@@ -581,8 +310,8 @@ class PagedEngine:
     speculative decoding on — each under an exact
     :func:`~apex_tpu.utils.tracecheck.retrace_guard` budget of 1:
     ``decode_step`` (width-1 step), ``prefill_step`` (the width-
-    ``prefill_chunk`` mixed step — the dense engine's per-bucket
-    prefills collapse to this one shape), the optional ``spec_step``
+    ``prefill_chunk`` mixed step — every prompt length replays this
+    one shape), the optional ``spec_step``
     (the width-``1 + spec_tokens`` draft/verify step below), ``admit``
     (slot-state scatter; no cache writes — pages are overwritten
     before they become visible and recurrent state is zeroed by the
@@ -649,8 +378,9 @@ class PagedEngine:
     allocator, refcounts, CoW forks, preemption and the trie are
     untouched — a shared or forked page carries its scale with it —
     and ``pool_tokens`` keeps counting TOKENS, which are now ~2×
-    (bf16) / ~4× (fp32) cheaper: the default pool converts the dense
-    slab's byte budget into quantized token capacity, and the
+    (bf16) / ~4× (fp32) cheaper: the default pool converts the byte
+    budget of ``max_slots × max_seq_len`` unquantized tokens into
+    quantized token capacity, and the
     shared-aware admission gate therefore admits the reclaimed HBM as
     occupancy.  ``kv_dtype="auto"`` adopts the (block_size, kv_dtype)
     pair a joint :func:`~apex_tpu.ops.autotune.tune_paged_attention`
@@ -689,14 +419,12 @@ class PagedEngine:
     keyed on head_dim + the pool's STORAGE dtype + the PER-SHARD
     kv_heads count — a TP engine must not adopt a block size swept at
     full head count) and falls back to 16.
-    ``pool_tokens`` defaults to ``max_slots × max_seq_len`` —
-    the dense slab's footprint (converted into quantized tokens at
-    equal bytes when ``kv_dtype`` is set); shrink it to trade capacity
+    ``pool_tokens`` defaults to ``max_slots × max_seq_len`` — every
+    slot can reach its full context (converted into quantized tokens
+    at equal bytes when ``kv_dtype`` is set); shrink it to trade capacity
     for memory (admission token-gates and preemption backstops the
     overcommit).
     """
-
-    paged = True
 
     def __init__(self, model, params, *, max_slots: int = 4,
                  block_size: int = 0,
@@ -822,9 +550,9 @@ class PagedEngine:
         if pool_tokens is None:
             pool_tokens = self.max_slots * self.max_seq_len
             if store_dt is not None:
-                # equal-HBM default: the dense-slab byte budget
-                # (max_slots × max_seq_len tokens at the compute
-                # dtype) buys ~itemsize× the QUANTIZED tokens, scale
+                # equal-HBM default: the byte budget of
+                # max_slots × max_seq_len tokens at the compute
+                # dtype buys ~itemsize× the QUANTIZED tokens, scale
                 # overhead included — the reclaimed HBM becomes
                 # admitted occupancy instead of idle savings (same
                 # formula the bench traffic model counts with)
@@ -865,7 +593,8 @@ class PagedEngine:
                 "engine owns the cache pool")
         # the paged twin: same parameters, paged cache layout — the
         # layout is part of the module hash, so its executables can
-        # never collide with a dense model's in any jit cache
+        # never collide with those of the model as given (whose
+        # dense cache is generate()'s) in any jit cache
         self._paged_model = type(model)(cfg=dataclasses.replace(
             cfg, kv_cache="paged", kv_block_size=self.block_size,
             kv_pool_blocks=num_blocks, kv_dtype=self.kv_dtype,
@@ -1074,9 +803,9 @@ class PagedEngine:
             return (state if mesh is None
                     else _pin_replicated(state, mesh))
 
-        # exact budgets: decode/spec/admit/release = 1 and the dense
-        # engine's per-bucket prefills collapse to ONE mixed-step
-        # shape — any excess trace raises RetraceError
+        # exact budgets: decode/spec/admit/release = 1 and every
+        # prompt length rides ONE mixed-step shape — any excess trace
+        # raises RetraceError
         self._decode = tracecheck.retrace_guard(
             step_fn, max_traces=1, name="serving.decode_step",
             donate_argnums=(1, 2))
@@ -1098,8 +827,8 @@ class PagedEngine:
                          temperature: float = 0.0,
                          top_k: Optional[int] = None,
                          top_p: Optional[float] = None) -> None:
-        """Static admission checks (no buckets: chunked prefill admits
-        any prompt length that fits the cache and the pool)."""
+        """Static admission checks (chunked prefill admits any prompt
+        length that fits the cache and the pool)."""
         if prompt_len < 1:
             raise ValueError("empty prompt")
         if max_new_tokens < 1:

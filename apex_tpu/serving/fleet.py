@@ -6,10 +6,10 @@ traffic needs N replicas that individually fail, drain, and scale
 without client-visible loss.  :class:`FleetRouter` is the front door:
 
 - **Routing** — ``submit()`` goes to the least-loaded *routable*
-  replica, ranked by the paged engine's ``blocks_in_use /
-  blocks_total`` occupancy gauge (slot occupancy for dense replicas),
-  queue depth breaking ties.  A failed routing attempt (full queue,
-  closed replica, injected ``fleet.route`` fault) retries with capped,
+  replica, ranked by the engine's ``blocks_in_use /
+  blocks_total`` occupancy gauge, queue depth breaking ties.  A failed
+  routing attempt (full queue, closed replica, injected
+  ``fleet.route`` fault) retries with capped,
   deterministically-jittered backoff onto the next-best replica before
   surfacing :class:`~apex_tpu.serving.api.RequestFailed`.
 - **Health gating** — a supervisor thread probes every replica's
@@ -51,8 +51,7 @@ acceptance soaks live in ``tests/test_chaos.py``.
 
 Usage::
 
-    factory = lambda: InferenceServer(model, params, max_slots=16,
-                                      kv_cache="paged")
+    factory = lambda: InferenceServer(model, params, max_slots=16)
     router = FleetRouter(factory, replicas=3)
     with router:
         h = router.submit(prompt_tokens, max_new_tokens=256)
@@ -237,10 +236,10 @@ class CircuitBreaker:
 # --------------------------------------------------------------------- #
 def load_score(health: Mapping[str, Any]) -> float:
     """Least-loaded routing key for one replica ``health()`` dict: the
-    paged pool's ``blocks_in_use / blocks_total`` occupancy when the
-    gauge is present, else the dense slot ``occupancy`` — both in
-    [0, 1], comparable across layouts.  Queue depth breaks ties
-    upstream (:func:`select_replica`)."""
+    pool's ``blocks_in_use / blocks_total`` occupancy; a dict that
+    lacks the gauge (a hand-made one) falls back to its slot
+    ``occupancy`` — both in [0, 1].  Queue depth breaks ties upstream
+    (:func:`select_replica`)."""
     total = health.get("blocks_total") or 0
     if total:
         return float(health.get("blocks_in_use", 0)) / float(total)

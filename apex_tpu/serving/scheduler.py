@@ -3,13 +3,14 @@
 The scheduler owns the host-side view the device never needs: which
 request occupies which slot, what has been emitted, and who is waiting.
 At every step boundary it (1) refills free slots from the queue in FIFO
-order — prompts quantized to the engine's length buckets so admission
-replays compiled prefills — then (2) runs one engine decode step and
-routes each produced token to its request, evicting tenants that
-finished (eos or budget).  Requests never wait for each other's
-completion: a 512-token generation and a 3-token one share the batch,
-and the short one's slot is re-used the step after it finishes — the
-continuous-batching property that fixed-batch ``generate()`` lacks.
+order — as far as the engine's page pool can take the head request —
+then (2) runs one engine step (prompt chunks and decoding tenants in
+the same batch) and routes each produced token to its request,
+evicting tenants that finished (eos or budget).  Requests never wait
+for each other's completion: a 512-token generation and a 3-token one
+share the batch, and the short one's slot is re-used the step after it
+finishes — the continuous-batching property that fixed-batch
+``generate()`` lacks.
 
 Thread-safety: ``submit`` may be called from any thread (the queue has
 its own lock); ``run_step`` must be called from the single thread that
@@ -28,7 +29,6 @@ from typing import Deque, List, Optional, Tuple
 import numpy as np
 
 from apex_tpu.resilience import faults
-from apex_tpu.serving.engine import StepOutput
 from apex_tpu.utils.metrics import counters
 from apex_tpu.utils.profiler import SpanTotals, span
 
@@ -99,7 +99,7 @@ class StepEvent:
 
 class Scheduler:
     """Bounded-queue continuous batcher over one
-    :class:`~apex_tpu.serving.engine.Engine`."""
+    :class:`~apex_tpu.serving.engine.PagedEngine`."""
 
     def __init__(self, engine, *, queue_capacity: int = 64):
         if queue_capacity < 1:
@@ -118,7 +118,7 @@ class Scheduler:
         # graftlint: unguarded(fixed-size list, item writes by the engine-owning worker only; iteration safe)
         self._slots: List[Optional[Request]] = [None] * engine.max_slots
         self._admit_failures: List[Tuple[Request, BaseException]] = []
-        #: block-exhaustion preemptions requeued so far (paged engine)
+        #: block-exhaustion preemptions requeued so far
         self.preempts = 0
         #: admissions so far (re-admissions after a preempt or a fault
         #: included) and the seconds they had waited in the queue
@@ -160,10 +160,10 @@ class Scheduler:
         prefills ``original prompt ++ tokens emitted so far`` with the
         remaining budget, so clients keep their streamed prefix and the
         total token count is unchanged.  Validates the continuation
-        (the longer prompt must still fit a bucket) — a ``ValueError``
-        here means the request cannot be resumed and the caller must
-        fail it terminally.  Bypasses the capacity check: accepted
-        requests are never dropped for queue pressure.
+        (the longer prompt must still fit the context and the pool) —
+        a ``ValueError`` here means the request cannot be resumed and
+        the caller must fail it terminally.  Bypasses the capacity
+        check: accepted requests are never dropped for queue pressure.
         """
         prompt = np.asarray(request._prompt0, np.int32)  # type: ignore[attr-defined]
         if request.tokens:
@@ -225,9 +225,8 @@ class Scheduler:
         decoding.  Any other exception propagates (fatal, as before).
 
         Admission is TOKEN-gated, not just slot-gated: the engine's
-        ``can_admit`` must also clear the queue head (the paged engine
-        requires free pages to cover prompt + decode headroom; the
-        dense engine always says yes).  The check stays FIFO — a
+        ``can_admit`` must also clear the queue head (free pages must
+        cover prompt + decode headroom).  The check stays FIFO — a
         too-big head blocks the queue rather than being overtaken,
         so admission order cannot starve large requests.  Under a
         quantized pool (``kv_dtype="int8"``/``"fp8"``, ISSUE 8) the
@@ -294,7 +293,7 @@ class Scheduler:
 
     # graftlint: thread-entry(serving-worker)
     def evict(self, slot: int) -> Optional[Request]:
-        """Release ``slot`` (zero the engine row) and return its
+        """Release ``slot`` (its pages go back to the pool) and return its
         tenant — deadline-expiry and fault-recovery path.  Call from
         the engine-owning thread only."""
         req = self._slots[slot]
@@ -309,7 +308,7 @@ class Scheduler:
         """Evict every active tenant and return them in slot order —
         the graceful-drain path (``InferenceServer.begin_drain``).
         Engine rows are released through the same compiled ``release``
-        as normal completion, so a paged pool gets all its pages back
+        as normal completion, so the pool gets all its pages back
         (``blocks_in_use`` returns to 0 once the queue is also
         cancelled).  Call from the engine-owning thread only."""
         evicted: List[Request] = []
@@ -326,7 +325,7 @@ class Scheduler:
         Returns the tokens produced this step (empty when idle).  Call
         from the engine-owning thread only.
 
-        Paged engines return a :class:`~apex_tpu.serving.engine.
+        The engine returns a :class:`~apex_tpu.serving.engine.
         StepOutput`: only ``emitted`` slots route a token (mid-prefill
         tenants compute but emit nothing), and ``preempted`` tenants —
         evicted by the engine for block exhaustion, pages already
@@ -345,11 +344,7 @@ class Scheduler:
     def _route(self, out) -> List[StepEvent]:
         """Requeue the step's preempted tenants, route its tokens to
         their requests, release the slots that finished."""
-        if isinstance(out, StepOutput):
-            tokens, finished, _emitted, preempted, counts = out
-        else:
-            tokens, finished = out
-            counts, preempted = None, ()
+        tokens, finished, _emitted, preempted, counts = out
         for slot in preempted:
             req = self._slots[slot]
             if req is None:
@@ -367,12 +362,12 @@ class Scheduler:
                 continue
             # a drafted (speculative) step can emit SEVERAL tokens for
             # one slot — route each in order, finishing on the last
-            n_emit = 1 if counts is None else int(counts[slot])
+            n_emit = int(counts[slot])
             if n_emit == 0:
                 continue
             row = tokens[slot]
             for j in range(n_emit):
-                tok = int(row[j]) if np.ndim(row) else int(row)
+                tok = int(row[j])
                 fin = bool(finished[slot]) and j == n_emit - 1
                 req.tokens.append(tok)
                 events.append(StepEvent(req, tok, fin))

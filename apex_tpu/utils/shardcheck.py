@@ -396,13 +396,12 @@ def wrap_step(fn: Callable, *, declared: Any, mesh: Any = None,
 
 #: step-attr -> how many leading outputs carry the engine's committed
 #: placement (cache pool, then slot state); admit/release return the
-#: state alone on the paged engine
+#: state alone
 _PAGED_STEPS = {"_decode": ("cache", "state"),
                 "_prefill": ("cache", "state"),
                 "_spec": ("cache", "state"),
                 "_admit": ("state",),
                 "_release": ("state",)}
-_DENSE_STEPS = ("_step", "_prefill", "_admit", "_release")
 
 
 def _paged_declared_of(engine: Any, parts: Tuple[str, ...]
@@ -443,9 +442,9 @@ def instrument(obj: Any, *, strict: Optional[bool] = None,
     returns ``obj``.
 
     - An engine's guarded step functions are replaced by recording
-      proxies.  A tensor-parallel paged engine (``mesh`` committed)
+      proxies.  A tensor-parallel engine (``mesh`` committed)
       gets declared-vs-actual output checks (pool on the ``tensor``
-      axis, slot state replicated); a dense or single-chip engine gets
+      axis, slot state replicated); a single-chip engine gets
       transfer-window accounting only — there is no multi-device
       placement to verify.
     - ``strict=None`` follows ``APEX_TPU_SHARDCHECK=strict`` (the
@@ -486,11 +485,11 @@ def instrument(obj: Any, *, strict: Optional[bool] = None,
         if not (callable(value) and hasattr(value, "trace_count")):
             continue                    # only the guarded step fns
         site = f"{cls_name}.{attr}"
-        declared_of = None
-        if attr in _PAGED_STEPS and mesh is not None:
-            declared_of = _paged_declared_of(obj, _PAGED_STEPS[attr])
-        elif attr not in _PAGED_STEPS and attr not in _DENSE_STEPS:
+        if attr not in _PAGED_STEPS:
             continue
+        declared_of = None
+        if mesh is not None:
+            declared_of = _paged_declared_of(obj, _PAGED_STEPS[attr])
         _originals.append((d, attr, value))
         d[attr] = _StepProxy(value, site, declared_of, mesh)
     if recurse > 0:

@@ -32,8 +32,6 @@ Legs (reference workloads per BASELINE.json):
   llama_1b           1.03B GQA+SwiGLU recipe + GQA/MLP A/B rows
   decode             llama_1b generate(): prefill + decode tokens/s,
                      bytes/token roofline, blocked-vs-einsum A/B
-  serving_decode     continuous-batching engine tokens/s at fixed
-                     occupancy vs single-stream generate() baseline
   prefix_spec_serving  CoW prefix sharing A/B at equal HBM (tokens/s,
                      TTFT, pool capacity shared vs unshared) + the
                      prompt-lookup speculative-decoding tokens/step
@@ -2088,211 +2086,6 @@ def _long_context_single():
 # (lifted to apex_tpu/plan/costs.py — imported back above as _serving_traffic_model)
 
 
-def bench_serving_decode():
-    """Continuous-batching engine scoreboard (ISSUE 2): steady-state
-    tokens/sec of ``apex_tpu.serving`` at FIXED slot occupancy on the
-    llama_1b GQA recipe, against the single-stream ``generate()``
-    baseline.  Decode is HBM-bound — every step streams all params
-    regardless of batch — so ``slots`` co-resident tenants amortize the
-    same param read ``slots`` ways; the ratio row quantifies how much
-    of that consolidation the slotted engine (vmapped b=1 decode +
-    per-slot cursors) actually delivers vs. the lockstep batch loop.
-
-    Env: BENCH_SERVE_SLOTS (8), BENCH_SERVE_PROMPT (128),
-    BENCH_DECODE_MAXLEN (2048), BENCH_SERVE_TOKENS (64),
-    BENCH_LLAMA_LAYERS (20 — shrink for CPU smoke)."""
-    import dataclasses
-    import time
-
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from apex_tpu.models import LlamaModel, generate
-    from apex_tpu.serving import Engine
-
-    slots = int(os.environ.get("BENCH_SERVE_SLOTS", "8"))
-    S = int(os.environ.get("BENCH_DECODE_MAXLEN", "2048"))
-    P = int(os.environ.get("BENCH_SERVE_PROMPT", "128"))
-    N = int(os.environ.get("BENCH_SERVE_TOKENS", "64"))
-    k_windows = max(1, int(os.environ.get("BENCH_WINDOWS", "3")))
-    cfg = dataclasses.replace(_llama_1b_cfg("gqa"), max_seq_len=S)
-    model = LlamaModel(cfg)
-
-    rng = np.random.default_rng(0)
-    prompts = rng.integers(0, cfg.vocab_size,
-                           size=(slots, P)).astype(np.int32)
-    params = model.init(jax.random.PRNGKey(0),
-                        jnp.asarray(prompts[:1, :8]))
-    # inference: bf16 params (the O2 compute copy; no masters needed)
-    params = {"params": jax.tree.map(
-        lambda x: x.astype(jnp.bfloat16)
-        if jnp.issubdtype(x.dtype, jnp.floating) else x,
-        params["params"])}
-    n_params = sum(x.size for x in jax.tree.leaves(params))
-
-    # steps the measurement needs per tenant: 1 warm + the windows —
-    # budgets and cache room must outlast them so occupancy stays
-    # pinned at 1.0 (no mid-window eviction/refill)
-    total_steps = 1 + k_windows * N
-    room = S - P - 1
-    if total_steps > room:
-        N = max(1, (room - 1) // k_windows)
-        total_steps = 1 + k_windows * N
-    engine = Engine(model, params, max_slots=slots,
-                    prompt_buckets=(P,))
-    engine.warmup()
-    for slot in range(slots):
-        engine.admit(slot, prompts[slot],
-                     max_new_tokens=total_steps + 1)
-    engine.step()                              # warm the full pool
-    ovh = bench._call_overhead()
-
-    def serve_window():
-        t0 = time.perf_counter()
-        for _ in range(N):
-            engine.step()          # step() syncs (host token routing)
-        return (time.perf_counter() - t0 - ovh) / N
-
-    t_step, step_w = bench._time_windows(serve_window, k_windows)
-    for slot in range(slots):
-        engine.release(slot)
-    serving_tps = slots / t_step
-
-    # single-stream baseline: generate() at b=1, same prompt length
-    ids1 = jnp.asarray(prompts[:1])
-    out = generate(model, params, ids1, max_new_tokens=N)   # compile
-    bench._sync(out)
-
-    def gen_window():
-        t0 = time.perf_counter()
-        out = generate(model, params, ids1, max_new_tokens=N)
-        bench._sync(out)
-        return (time.perf_counter() - t0 - ovh) / N
-
-    t_gen, gen_w = bench._time_windows(gen_window, k_windows)
-    single_tps = 1.0 / t_gen
-
-    _emit({
-        "metric": f"serving_decode_s{slots}_S{S}_tokens_per_sec",
-        "value": round(serving_tps, 1),
-        "unit": "tokens/sec/chip",
-        "slots": slots, "max_seq_len": S, "prompt": P,
-        "tokens_per_window": N,
-        "occupancy": 1.0,
-        "num_params": int(n_params),
-        "step_ms": round(t_step * 1e3, 3),
-        "step_window_ms": [round(d * 1e3, 2) for d in step_w],
-        "single_stream_generate_tokens_per_sec": round(single_tps, 1),
-        "single_stream_ms_per_token": round(t_gen * 1e3, 3),
-        "single_stream_window_ms": [round(d * 1e3, 2) for d in gen_w],
-        "consolidation_speedup": round(serving_tps / single_tps, 2),
-        "trace_counts": engine.trace_counts,
-        "note": ("serving step() includes the per-step host sync "
-                 "(token routing); generate() loops on-device — the "
-                 "speedup is net of that overhead"),
-    })
-
-    # -------- paged A/B + occupancy sweep (ISSUE 5 acceptance) --------
-    # equal HBM budget = the dense slab just measured (slots × S
-    # tokens of K/V per layer).  The A/B row (mult=1) answers "same
-    # slot count, paged layout: how much does the per-step gather
-    # cost?" (target: tokens/s per slot within 10% of dense); the
-    # sweep rows hold 2× and 4× the slot count in the SAME budget —
-    # possible only because live tokens/slot ≈ prompt + generated
-    # « max_seq_len, exactly the overcommit the dense slab forbids.
-    from apex_tpu.serving import PagedEngine
-
-    del engine                      # free the dense slab first
-    pool_tokens = slots * S
-    block = int(os.environ.get("BENCH_PAGED_BLOCK", "16"))
-    # +2 decode headroom beyond the measurement, capped so
-    # prompt + budget never exceeds max_seq_len when the room cap
-    # already pinned total_steps at its edge
-    paged_budget = min(total_steps + 2, S - P)
-    live = P + paged_budget
-    kv_bytes = 2 if cfg.dtype == jnp.bfloat16 else 4
-    paged_base_tps = None
-    live_pages = -(-live // block)
-    total_pages = -(-pool_tokens // block)
-    for mult in (1, 2, 4):
-        pslots = slots * mult
-        if pslots * live_pages > total_pages:
-            # capacity counted in PAGES (per-slot ceil rounding —
-            # token arithmetic under-counts near the edge and would
-            # let mid-window preemption silently shrink the
-            # measurement): record the bound instead
-            _emit({
-                "metric": (f"serving_decode_paged_x{mult}_"
-                           f"s{pslots}_S{S}_tokens_per_sec"),
-                "value": None,
-                "skipped": (f"{pslots} slots × {live_pages} live "
-                            f"pages exceed the {total_pages}-page "
-                            f"pool"),
-            })
-            continue
-        pengine = PagedEngine(model, params, max_slots=pslots,
-                              block_size=block,
-                              pool_tokens=pool_tokens,
-                              prefill_chunk=min(P, 128))
-        pengine.warmup()
-        pprompts = rng.integers(0, cfg.vocab_size,
-                                size=(pslots, P)).astype(np.int32)
-        for slot in range(pslots):
-            pengine.admit(slot, pprompts[slot],
-                          max_new_tokens=paged_budget)
-        # chunked prefill to completion, then one warm decode step
-        while any(t is not None and t.fed < P
-                  for t in pengine._tenants):
-            pengine.step()
-        pengine.step()
-        occupancy_blocks = pengine.blocks_in_use / pengine.blocks_total
-
-        def paged_window():
-            t0 = time.perf_counter()
-            for _ in range(N):
-                pengine.step()
-            return (time.perf_counter() - t0 - ovh) / N
-
-        t_paged, paged_w = bench._time_windows(paged_window, k_windows)
-        paged_tps = pslots / t_paged
-        per_slot = paged_tps / pslots
-        if mult == 1:
-            paged_base_tps = paged_tps
-        tm = _serving_traffic_model(
-            num_layers=cfg.num_layers, kv_heads=cfg.kv_heads,
-            head_dim=cfg.head_dim, max_seq_len=S, live_tokens=live,
-            slots=pslots, block_size=pengine.block_size,
-            dtype_bytes=kv_bytes)
-        row = {
-            "metric": (f"serving_decode_paged_x{mult}_s{pslots}_S{S}"
-                       f"_tokens_per_sec"),
-            "value": round(paged_tps, 1),
-            "unit": "tokens/sec/chip",
-            "slots": pslots, "max_seq_len": S, "prompt": P,
-            "block_size": pengine.block_size,
-            "pool_tokens": pool_tokens,
-            "hbm_budget": f"= dense slab at {slots} slots",
-            "occupancy_blocks": round(occupancy_blocks, 3),
-            "step_ms": round(t_paged * 1e3, 3),
-            "step_window_ms": [round(d * 1e3, 2) for d in paged_w],
-            "tokens_per_sec_per_slot": round(per_slot, 2),
-            "dense_tokens_per_sec_per_slot":
-                round(serving_tps / slots, 2),
-            "per_slot_vs_dense":
-                round(per_slot / (serving_tps / slots), 3),
-            "analytic_kv_traffic": tm,
-            "trace_counts": pengine.trace_counts,
-        }
-        if mult > 1 and paged_base_tps is not None:
-            row["tps_vs_paged_x1"] = round(
-                paged_tps / paged_base_tps, 2)
-        for slot in range(pslots):
-            pengine.release(slot)
-        _emit(row)
-        del pengine
-
-
 def bench_prefix_spec_serving():
     """Prefix-sharing + speculative-decoding scoreboard (ISSUE 7).
 
@@ -2369,7 +2162,7 @@ def bench_prefix_spec_serving():
 
     def run_wave(share):
         server = InferenceServer(
-            model, params, max_slots=slots, kv_cache="paged",
+            model, params, max_slots=slots,
             block_size=block, pool_tokens=pool_tokens,
             prefill_chunk=32, share_prefixes=share)
         peak_saved = 0
@@ -2594,7 +2387,7 @@ def bench_quantized_kv_serving():
 
     def run_wave(kv_dtype, max_slots, pool_tokens):
         server = InferenceServer(
-            model, params, max_slots=max_slots, kv_cache="paged",
+            model, params, max_slots=max_slots,
             block_size=block, pool_tokens=pool_tokens,
             prefill_chunk=8, kv_dtype=kv_dtype)
         with server:
@@ -2643,9 +2436,8 @@ def bench_quantized_kv_serving():
             / max(base["tokens_per_sec"], 1e-9), 2),
         "analytic_kv_traffic": tm,
         "note": ("equal-HBM A/B: the int8 pool admits 2x the slots in "
-                 "the same bytes; on-chip the occupancy-sweep protocol "
-                 "(serving_decode: 2x slots -> 2.25x tokens/s) "
-                 "converts that into >= 1.5x sustained tokens/s — the "
+                 "the same bytes; what that buys in sustained tokens/s "
+                 "is a chip measurement — the "
                  "CPU wall ratio here is compute-bound (dequant is "
                  "arithmetic, not bandwidth, on CPU) and reported for "
                  "honesty; the asserted artifact is the capacity side, "
@@ -3357,7 +3149,7 @@ def bench_fleet_serving():
 
     def factory():
         return InferenceServer(
-            model, params, max_slots=slots, kv_cache="paged",
+            model, params, max_slots=slots,
             block_size=8, prefill_chunk=4,
             pool_tokens=slots * cfg.max_seq_len)
 
@@ -3500,7 +3292,7 @@ def bench_tp_serving():
             dev = devices[next(idx) % len(devices)]
             return InferenceServer(
                 model, jax.device_put(params, dev), max_slots=slots,
-                kv_cache="paged", block_size=8, prefill_chunk=4)
+                block_size=8, prefill_chunk=4)
 
         router = FleetRouter(factory, replicas=chips,
                              probe_interval=0.05)
@@ -3520,7 +3312,7 @@ def bench_tp_serving():
     def run_tp():
         # 1 replica × C chips: one engine spans the mesh
         server = InferenceServer(
-            model, params, max_slots=slots, kv_cache="paged",
+            model, params, max_slots=slots,
             block_size=8, prefill_chunk=4, tp=chips)
         with server:
             t0 = time.perf_counter()
@@ -3824,7 +3616,6 @@ LEGS = {
     "moe_mixtral": bench_moe_mixtral,
     "llama_1b": bench_llama_1b,
     "decode": bench_decode,
-    "serving_decode": bench_serving_decode,
     "decode_epilogue": bench_decode_epilogue,
     "prefix_spec_serving": bench_prefix_spec_serving,
     "quantized_kv_serving": bench_quantized_kv_serving,

@@ -7,7 +7,7 @@ process and ONE TPU chip:
 * **train** — BERT-Large (24 x 1024, 16 heads, vocab 30528) at b=16,
   s=512, 80 MLM positions: amp O2 (bf16 compute, fp32 masters) +
   FusedAdam + full remat, the donated jitted step ``bench.py`` times.
-* **serve** — ``InferenceServer(kv_cache="paged")`` over Mistral-7B
+* **serve** — an ``InferenceServer`` over Mistral-7B
   widths (hidden 4096, 32/8 heads x d128, ffn 14336, vocab 32000) with
   the depth cut to what leaves a KV pool beside the weights on 16 GB;
   mixed-length greedy and sampled requests through ``submit()`` /
@@ -364,8 +364,7 @@ def phase_serve(device, rehearse):
                        for x in jax.tree.leaves(params))
     reqs = requests_for(cfg, rehearse)
 
-    server = InferenceServer(model, params, kv_cache="paged",
-                             max_slots=8)
+    server = InferenceServer(model, params, max_slots=8)
     engine = server.engine
     t0 = time.perf_counter()
     server.start()                    # warm-up traces every executable

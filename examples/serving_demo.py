@@ -6,23 +6,24 @@ lengths, budgets and sampling configs, streams each request's tokens as
 they decode, and prints the server's throughput/occupancy metrics.
 
 The interesting property on display: every request shape/config mix
-runs through ONE compiled decode step (per-slot sampling params are
-device arrays, prompts are bucketed) — the engine's retrace guards
-would raise if anything recompiled mid-traffic.
+runs through ONE compiled decode step and ONE mixed prefill step
+(per-slot sampling params are device arrays, prompts ride as
+fixed-width chunks) — the engine's retrace guards would raise if
+anything recompiled mid-traffic.
 
 With ``--replicas N`` (N > 1) the same traffic goes through a
-:class:`apex_tpu.serving.FleetRouter` front door instead: N paged
+:class:`apex_tpu.serving.FleetRouter` front door instead: N
 replica servers, least-loaded health-gated routing by the
 blocks-occupancy gauge, and per-replica metrics aggregated into one
 fleet view (docs/fleet.md).
 
 With ``--kv-dtype int8`` (or ``fp8`` where the jax build has
-``float8_e4m3fn``) the server runs the PAGED datapath with a quantized
-KV pool: 1-byte pages + per-page amax scales, ~2–4× the token capacity
-at equal HBM admitted as occupancy (docs/serving.md).
+``float8_e4m3fn``) the server's KV pool is quantized: 1-byte pages +
+per-page amax scales, ~2–4× the token capacity at equal HBM admitted
+as occupancy (docs/serving.md).
 
 With ``--tp M`` (M > 1) each replica spans M chips (tensor-parallel
-paged serving, docs/serving.md): the KV pool shards on kv_heads, the
+serving, docs/serving.md): the KV pool shards on kv_heads, the
 matmuls ride the GSPMD TP layers, and everything above — sharing,
 drafting, quantized pages, the fleet router — is unchanged.  Composes
 with ``--replicas N`` into an N×M fleet, each replica on its own
@@ -108,8 +109,8 @@ def main():
         f"metrics step={step} " + " ".join(
             f"{k}={v:.3g}" for k, v in sorted(row.items()))))
 
-    # mixed traffic: lengths spanning three buckets, greedy and
-    # sampled tenants side by side in the same compiled step
+    # mixed traffic: lengths from under one prefill chunk to several,
+    # greedy and sampled tenants side by side in the same compiled step
     configs = [
         {"length": 3, "max_new_tokens": 6, "temperature": 0.0},
         {"length": 7, "max_new_tokens": 4, "temperature": 0.8,
@@ -147,7 +148,7 @@ def main():
             f"{len(devices)} (on CPU run with XLA_FLAGS="
             f"--xla_force_host_platform_device_count=8)")
 
-    block_size = 8                 # the demo's paged-pool page size
+    block_size = 8                 # the demo's page size
     if args.plan == "auto" and (args.tp is None
                                 or args.replicas is None):
         # ISSUE 15: enumerate the replicas×tp splits over the chip
@@ -222,8 +223,7 @@ def main():
                     for j in range(args.tp)])
             return InferenceServer(
                 model, params, max_slots=args.max_slots,
-                kv_cache="paged", block_size=block_size,
-                prefill_chunk=4,
+                block_size=block_size, prefill_chunk=4,
                 pool_tokens=args.max_slots * cfg.max_seq_len,
                 kv_dtype=args.kv_dtype, mesh=mesh,
                 metrics_interval=4)
@@ -246,19 +246,11 @@ def main():
               f"{health['chips_per_replica']} chips")
         return
 
-    if args.kv_dtype is not None or args.tp > 1:
-        # quantized pools and tensor-parallel replicas live in the
-        # paged datapath (a dense server rejects both loudly)
-        server = InferenceServer(
-            model, params, max_slots=args.max_slots,
-            kv_cache="paged", block_size=block_size, prefill_chunk=4,
-            kv_dtype=args.kv_dtype, tp=args.tp if args.tp > 1 else 0,
-            metrics=metrics, metrics_interval=4)
-    else:
-        server = InferenceServer(
-            model, params, max_slots=args.max_slots,
-            prompt_buckets=(4, 8, 16), metrics=metrics,
-            metrics_interval=4)
+    server = InferenceServer(
+        model, params, max_slots=args.max_slots,
+        block_size=block_size, prefill_chunk=4,
+        kv_dtype=args.kv_dtype, tp=args.tp if args.tp > 1 else 0,
+        metrics=metrics, metrics_interval=4)
     with server:
         handles = submit_and_stream(server)
         if args.kv_dtype is not None:

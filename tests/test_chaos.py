@@ -573,7 +573,7 @@ class TestServingChaosSoak:
     def test_soak_no_lost_requests_no_retraces(self):
         model, params = self._tiny()
         server = InferenceServer(model, params, max_slots=3,
-                                 prompt_buckets=(4, 8, 16))
+                                 block_size=8, prefill_chunk=4)
         # runtime lock sanitizer, strict: order-inversion recording on
         # every lock in the stack plus guarded-by field verification
         # (docs/graftlint.md) — instrumented before the worker starts
@@ -592,8 +592,8 @@ class TestServingChaosSoak:
                       times=1),
         ])
         rng = np.random.default_rng(23)
-        # budgets small enough that continuation prompts (prompt ++
-        # emitted tokens) always re-bucket: L + n <= 16
+        # short requests: a requeued continuation (prompt ++ emitted
+        # tokens) is a few chunks at most
         cases = [
             (3, 4, 0.0, None, None), (7, 3, 0.8, 20, None),
             (5, 5, 1.2, 5, 0.9), (2, 6, 0.0, None, None),
@@ -645,22 +645,26 @@ class TestServingChaosSoak:
         # programs — warmup budgets exactly, zero traces during soak
         assert after == before, "chaos soak retraced after warmup"
         assert server.engine.trace_counts == {
-            "decode_step": 1, "prefill": 3, "admit": 1, "release": 1}
+            "decode_step": 1, "prefill_step": 1, "admit": 1,
+            "release": 1}
+        # every path out of a slot (finish, requeue, deadline) freed
+        # its pages
+        assert health["blocks_in_use"] == 0
         # the strict lock sanitizer observed the whole storm: zero
         # order inversions, zero guarded-field touches without locks
         lockcheck.assert_clean()
         # ... and the placement sanitizer: the engine's per-step host
         # sync happens OUTSIDE the compiled-step windows it watched
         shardcheck.assert_clean()
-        assert shardcheck.site_shardings()["Engine._step"]["calls"] \
-            >= 1
+        assert shardcheck.site_shardings()[
+            "PagedEngine._decode"]["calls"] >= 1
 
     def test_worker_survives_and_serves_after_faults(self):
         """After the fault plan is exhausted the same server keeps
         taking new traffic — self-healing, not merely not-crashing."""
         model, params = self._tiny()
         server = InferenceServer(model, params, max_slots=2,
-                                 prompt_buckets=(4, 8))
+                                 block_size=8, prefill_chunk=4)
         plan = FaultPlan([FaultSpec(site="serving.step",
                                     kind="transient", steps=(1, 2))])
         with active(plan):
@@ -695,7 +699,7 @@ class TestPagedServingChaosSoak:
     def test_soak_releases_all_blocks_no_retraces(self):
         model, params = self._tiny()
         server = InferenceServer(model, params, max_slots=3,
-                                 kv_cache="paged", block_size=8,
+                                 block_size=8,
                                  pool_tokens=256, prefill_chunk=4)
         plan = FaultPlan([
             FaultSpec(site="serving.step", kind="transient", every=5,
@@ -767,7 +771,7 @@ class TestPagedServingChaosSoak:
         trace budget is exactly the warmed 5 × 1."""
         model, params = self._tiny()
         server = InferenceServer(model, params, max_slots=3,
-                                 kv_cache="paged", block_size=8,
+                                 block_size=8,
                                  pool_tokens=160, prefill_chunk=4,
                                  admit_headroom=0, share_prefixes=True,
                                  spec_tokens=3)
@@ -864,7 +868,7 @@ class TestPagedServingChaosSoak:
         this soak pins is accounting + trace discipline under fire."""
         model, params = self._tiny()
         server = InferenceServer(model, params, max_slots=3,
-                                 kv_cache="paged", block_size=8,
+                                 block_size=8,
                                  pool_tokens=160, prefill_chunk=4,
                                  admit_headroom=0, share_prefixes=True,
                                  spec_tokens=3, kv_dtype="int8")
@@ -968,7 +972,7 @@ class TestFleetChaosSoak:
             # time (the same hook covers autoscale replacements)
             return shardcheck.instrument(lockcheck.instrument(
                 InferenceServer(
-                    model, params, max_slots=2, kv_cache="paged",
+                    model, params, max_slots=2,
                     block_size=8, pool_tokens=256, prefill_chunk=4),
                 strict=True), strict=True)
         return factory
@@ -1180,7 +1184,7 @@ class TestTPFleetChaosSoak:
             mesh = tp_mesh(2, jax.devices()[:2]) if i == 0 else None
             return shardcheck.instrument(lockcheck.instrument(
                 InferenceServer(
-                    model, params, max_slots=2, kv_cache="paged",
+                    model, params, max_slots=2,
                     block_size=8, pool_tokens=256, prefill_chunk=4,
                     mesh=mesh), strict=True), strict=True)
 

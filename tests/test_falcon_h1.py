@@ -280,7 +280,7 @@ def test_a_preempted_request_resumes_token_identically(falcon, greedy):
 
 def test_health_counts_state_bytes_resets_and_positions(falcon):
     model, params, _ = falcon
-    server = InferenceServer(model, params, kv_cache="paged", max_slots=2,
+    server = InferenceServer(model, params, max_slots=2,
                              block_size=8, prefill_chunk=8, pool_tokens=256)
     server.start()
     try:
@@ -304,7 +304,7 @@ def _llama_health():
     model = LlamaModel(cfg)
     params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 4), jnp.int32))
     server = InferenceServer(model, {"params": params["params"]},
-                             kv_cache="paged", max_slots=2, block_size=8,
+                             max_slots=2, block_size=8,
                              prefill_chunk=8, pool_tokens=128)
     return server.health()
 

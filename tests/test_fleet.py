@@ -4,8 +4,8 @@ Everything here runs against pure functions and duck-typed fake
 replicas, so the whole file costs milliseconds:
 
 - router selection math: least-loaded by the ``blocks_in_use /
-  blocks_total`` gauge (dense ``occupancy`` fallback), queue-depth tie
-  break, not-ready/ejected exclusion;
+  blocks_total`` gauge (slot ``occupancy`` where a health dict lacks
+  it), queue-depth tie break, not-ready/ejected exclusion;
 - circuit-breaker transitions: healthy → suspect (K failures or a
   latency-p99 breach) → ejected → probation (cooldown) → healthy, and
   probation's fail-fast re-ejection;
@@ -65,7 +65,7 @@ class FakeServer:
         self.reject = reject            # exception class raised on submit
         self.prefix_hit = prefix_hit    # scripted trie hit (affinity)
         self.kv_dtype = kv_dtype        # scripted pool storage dtype
-        self.kv_bits = kv_bits          # ... and width (None = dense)
+        self.kv_bits = kv_bits          # ... and width (None = unreported)
         self.chips = chips              # scripted chips_per_replica
         self.mesh_shape = mesh_shape    # scripted TP mesh shape
         self.running = False
@@ -162,8 +162,8 @@ class TestSelectionMath:
         paged = {"ready": True, "blocks_in_use": 4, "blocks_total": 16,
                  "occupancy": 1.0}
         assert load_score(paged) == 0.25     # gauge wins over occupancy
-        dense = {"ready": True, "occupancy": 0.5}
-        assert load_score(dense) == 0.5
+        bare = {"ready": True, "occupancy": 0.5}     # no pool gauge
+        assert load_score(bare) == 0.5
 
     def test_least_loaded_wins(self):
         healths = [
@@ -651,7 +651,7 @@ class TestFleetHealth:
 
         a = FakeServer(blocks=(0, 16), kv_dtype="int8", kv_bits=8)
         b = FakeServer(blocks=(0, 16), kv_dtype=None, kv_bits=32)
-        c = FakeServer()                     # dense: no kv fields
+        c = FakeServer()                     # reports no kv fields
         rows = []
         writer = MetricsWriter(sink=lambda s, m: rows.append((s, m)))
         router = _router([a, b, c], metrics=writer)

@@ -1,15 +1,15 @@
-"""Paged serving datapath (apex_tpu.serving.PagedEngine).
+"""The serving engine (apex_tpu.serving.PagedEngine).
 
 Correctness contracts under test:
 
-- greedy decode through the paged engine is TOKEN-IDENTICAL to
+- greedy decode through the engine is TOKEN-IDENTICAL to
   ``generate()`` for prompt lengths straddling every boundary that
-  matters (page size, chunk size, and their multiples);
+  matters (page size, chunk size, and their multiples), and for
+  requests that queue behind a first wave and refill its slots;
 - a steady-state soak of mixed chunked-prefill + decode traffic with
   heterogeneous sampling params triggers ZERO retraces after warmup at
   the EXACT documented budget — decode_step/prefill_step/admit/release
-  = 1 each (the dense engine's per-bucket prefills collapse to one
-  mixed-step shape);
+  = 1 each (every prompt length rides one mixed-step shape);
 - the block allocator: fragmentation-tolerant reuse, atomic
   exhaustion, double-free detection, the reserved null page;
 - token-budget admission (free pages must cover prompt + headroom)
@@ -149,21 +149,27 @@ class TestGreedyParityAcrossBoundaries:
     @pytest.mark.l0
     @pytest.mark.parametrize("which", [
         "gpt", pytest.param("llama", marks=pytest.mark.slow)])
-    def test_engine_matches_generate(self, which, request):
-        """block_size=8, chunk=4: prompt lengths straddle the page
-        boundary (7/8/9), the chunk boundary (3/4/5), their common
-        multiples (15/16/17) and a multi-page prompt (23) — every
-        chain must reproduce generate() exactly, including requests
-        that queue behind the first wave."""
+    @pytest.mark.parametrize("slots,chunk,lengths,budgets", [
+        (3, 4, (7, 8, 9, 3, 4, 5, 15, 16, 17, 23),
+         (6, 3, 5, 7, 4, 8, 3, 5, 6, 4)),
+        (2, 16, (3, 5, 8, 4, 11), (6, 3, 5, 7, 4)),
+    ], ids=["boundaries", "queued_refill"])
+    def test_engine_matches_generate(self, which, slots, chunk,
+                                     lengths, budgets, request):
+        """block_size=8.  ``boundaries`` (chunk 4): prompt lengths
+        straddle the page boundary (7/8/9), the chunk boundary
+        (3/4/5), their common multiples (15/16/17) and a multi-page
+        prompt (23).  ``queued_refill`` (chunk 16, every prompt one
+        chunk): five requests through two slots, three of them queued
+        behind the first wave and admitted as slots come free.  Every
+        chain must reproduce generate() exactly."""
         model, params = request.getfixturevalue(which)
         rng = np.random.default_rng(3)
-        lengths = (7, 8, 9, 3, 4, 5, 15, 16, 17, 23)
-        budgets = [6, 3, 5, 7, 4, 8, 3, 5, 6, 4]
         prompts = [rng.integers(0, model.cfg.vocab_size,
                                 size=(L,)).astype(np.int32)
                    for L in lengths]
-        engine = PagedEngine(model, params, max_slots=3, block_size=8,
-                             prefill_chunk=4)
+        engine = PagedEngine(model, params, max_slots=slots,
+                             block_size=8, prefill_chunk=chunk)
         sched = Scheduler(engine)
         reqs = [sched.submit(Request(prompt=p, max_new_tokens=n))
                 for p, n in zip(prompts, budgets)]
@@ -213,16 +219,18 @@ class TestGreedyParityAcrossBoundaries:
             max_new_tokens=2))[0, 10:]
         np.testing.assert_array_equal(np.asarray(rb.tokens), ref_b)
 
-    def test_eos_stops_early_and_matches_generate(self, gpt):
+    @pytest.mark.parametrize("plen", [9, 5], ids=[
+        "across_a_page", "inside_a_page"])
+    def test_eos_stops_early_and_matches_generate(self, gpt, plen):
         model, params = gpt
         rng = np.random.default_rng(5)
         prompt = rng.integers(0, model.cfg.vocab_size,
-                              size=(9,)).astype(np.int32)
+                              size=(plen,)).astype(np.int32)
         n = 8
         ref = np.asarray(generate(
             model, params, jnp.asarray(prompt[None]),
-            max_new_tokens=n))[0, 9:]
-        eos = int(ref[2])
+            max_new_tokens=n))[0, plen:]
+        eos = int(ref[2])            # force a stop three tokens in
         engine = PagedEngine(model, params, max_slots=1, block_size=8,
                              prefill_chunk=4)
         sched = Scheduler(engine)
@@ -230,22 +238,29 @@ class TestGreedyParityAcrossBoundaries:
                                    eos_id=eos))
         sched.drain()
         got = np.asarray(req.tokens)
+        # the engine stops AT the produced eos; generate's chain up to
+        # the first eos must match token for token
         first = int(np.argmax(ref == eos))
         np.testing.assert_array_equal(got, ref[:first + 1])
         assert got[-1] == eos and len(got) < n
 
 
 class TestSoakZeroRetraces:
-    def test_mixed_chunked_prefill_decode_soak(self, gpt):
-        """The acceptance soak: chunked-prefill admissions interleave
-        with steady decode across 14 requests / 3 slots, mixed
+    @pytest.mark.parametrize("chunk", [4, 32], ids=[
+        "chunked_prompts", "whole_prompts"])
+    def test_mixed_chunked_prefill_decode_soak(self, gpt, chunk):
+        """The acceptance soak: prefill admissions interleave with
+        steady decode across 14 requests / 3 slots, mixed
         temperature / top_k / top_p / eos / budgets — zero jaxpr
         traces after warmup, and the guards pin the budget to the
         documented constants: decode_step = prefill_step = admit =
-        release = 1."""
+        release = 1.  At chunk 4 the longer prompts take several
+        steps; at the default 32 every prompt is one chunk.  Nucleus
+        (top_p) traffic rides the same executable as everything else:
+        per-slot device-array params, budgets unchanged."""
         model, params = gpt
         engine = PagedEngine(model, params, max_slots=3, block_size=8,
-                             prefill_chunk=4)
+                             prefill_chunk=chunk)
         sched = Scheduler(engine)
         engine.warmup()
         assert engine.trace_counts == {
@@ -385,7 +400,9 @@ class TestPreemption:
 
 
 class TestSamplingDeterminism:
-    def test_tokens_independent_of_cotenants(self, gpt):
+    @pytest.mark.parametrize("chunk", [4, 32], ids=[
+        "chunked_prompts", "whole_prompts"])
+    def test_tokens_independent_of_cotenants(self, gpt, chunk):
         """A sampled request's chain is a function of its own seed —
         co-tenant traffic (and the chunked prefill it causes) must not
         perturb it: the k-th produced token always consumes the k-th
@@ -397,7 +414,7 @@ class TestSamplingDeterminism:
 
         def run(extra_traffic):
             engine = PagedEngine(model, params, max_slots=2,
-                                 block_size=8, prefill_chunk=4)
+                                 block_size=8, prefill_chunk=chunk)
             sched = Scheduler(engine)
             req = sched.submit(Request(
                 prompt=prompt, max_new_tokens=5, temperature=0.9,
@@ -498,7 +515,7 @@ class TestPagedServer:
         rows = []
         writer = MetricsWriter(sink=lambda s, m: rows.append((s, m)))
         server = InferenceServer(
-            model, params, max_slots=2, kv_cache="paged", block_size=8,
+            model, params, max_slots=2, block_size=8,
             prefill_chunk=4, metrics=writer, metrics_interval=2)
         with server:
             h1 = server.submit(prompt, max_new_tokens=5)
@@ -540,7 +557,7 @@ class TestPagedServer:
         model, params = gpt
         rng = np.random.default_rng(5)
         server = InferenceServer(
-            model, params, max_slots=2, kv_cache="paged", block_size=8,
+            model, params, max_slots=2, block_size=8,
             prefill_chunk=4)
         done, seen = threading.Event(), {}
 
@@ -567,9 +584,16 @@ class TestPagedServer:
         assert waited < 20.0          # the notify ended the wait
 
     def test_invalid_kv_cache_rejected(self, gpt):
+        """One engine, one value: anything but "paged" is refused, and
+        "dense" is told where its engine went."""
         model, params = gpt
         with pytest.raises(ValueError, match="kv_cache"):
             InferenceServer(model, params, kv_cache="sparse")
+        with pytest.raises(ValueError,
+                           match="dense slab engine was removed in PR 34"):
+            InferenceServer(model, params, kv_cache="dense")
+        server = InferenceServer(model, params, kv_cache="paged")
+        assert type(server.engine) is PagedEngine
 
 
 class TestTrafficModel:
@@ -973,20 +997,15 @@ class TestSpeculativeDecoding:
 
     def test_server_knobs_and_gauges(self, gpt):
         """InferenceServer plumbs the knobs through and surfaces the
-        new gauges in health() and metrics emissions; dense servers
-        reject them loudly."""
+        new gauges in health() and metrics emissions."""
         model, params = gpt
-        with pytest.raises(ValueError, match="paged"):
-            InferenceServer(model, params, spec_tokens=2)
-        with pytest.raises(ValueError, match="paged"):
-            InferenceServer(model, params, share_prefixes=True)
         rng = np.random.default_rng(67)
         pref = rng.integers(0, model.cfg.vocab_size,
                             size=(16,)).astype(np.int32)
         rows = []
         writer = MetricsWriter(sink=lambda s, m: rows.append((s, m)))
         server = InferenceServer(
-            model, params, max_slots=2, kv_cache="paged", block_size=8,
+            model, params, max_slots=2, block_size=8,
             prefill_chunk=4, share_prefixes=True, spec_tokens=3,
             metrics=writer, metrics_interval=2)
         prompt = np.tile(pref[:4], 4).astype(np.int32)
@@ -1028,14 +1047,15 @@ class TestQuantizedKV:
             dataclasses.replace(
                 model.cfg, kv_cache="paged", kv_block_size=8,
                 kv_pool_blocks=4, kv_dtype="int4")
-        with pytest.raises(ValueError, match="paged"):
-            InferenceServer(model, params, kv_dtype="int8")
+        with pytest.raises(ValueError, match="kv_dtype"):
+            InferenceServer(model, params, kv_dtype="int4")
         with pytest.raises(ValueError, match="kv_dtype"):
             PagedEngine(model, params, kv_dtype="int4")
 
     def test_equal_hbm_default_pool_capacity_at_least_1p9x(self, gpt):
-        """The quantized engine's default pool converts the dense
-        slab's byte budget into quantized tokens, SCALES INCLUDED:
+        """The quantized engine's default pool converts the byte
+        budget of max_slots × max_seq_len unquantized tokens into
+        quantized tokens, SCALES INCLUDED:
         ≥1.9× the unquantized token capacity at int8 (~3.9× here —
         the fp32 test model stores 4-byte K/V unquantized)."""
         model, params = gpt
@@ -1245,7 +1265,7 @@ class TestQuantizedKV:
         rows = []
         writer = MetricsWriter(sink=lambda s, m: rows.append((s, m)))
         server = InferenceServer(
-            model, params, max_slots=2, kv_cache="paged", block_size=8,
+            model, params, max_slots=2, block_size=8,
             prefill_chunk=4, kv_dtype="int8", metrics=writer,
             metrics_interval=2)
         with server:
@@ -1262,7 +1282,7 @@ class TestQuantizedKV:
         # unquantized servers report the storage width of the compute
         # dtype and kv_dtype None
         server2 = InferenceServer(
-            model, params, max_slots=1, kv_cache="paged", block_size=8,
+            model, params, max_slots=1, block_size=8,
             prefill_chunk=4)
         with server2:
             h2 = server2.health()

@@ -354,9 +354,10 @@ class TestPredictionFidelity:
             < fleet["hbm_residency"]["params"]
 
     def test_occupancy_sweep_curve_shape(self, recorded=None):
-        # the serving_decode sweep (docs/perf_serving.md): 1×/2×/4×
-        # the slots in one budget measured 1 / 2.25 / 3.96× tokens/s —
-        # increasing, sublinear at the top, ×4 under 2× the ×2 gain
+        # a CPU-dev-box occupancy sweep (the serving_decode leg that
+        # went with the dense engine in PR 34): 1×/2×/4× the slots in
+        # one budget measured 1 / 2.25 / 3.96× tokens/s — increasing,
+        # sublinear at the top, ×4 under 2× the ×2 gain
         prof = profile_of(LlamaConfig.llama_1b())
         tps = {m: score_layout(
             prof, Layout(objective="serve", dp=1),
@@ -443,7 +444,7 @@ class TestPlanSmoke:
         p = apex_tpu.plan(GPTConfig.tiny(), devices=N,
                           objective="serve")
         assert p.replicas * p.tp == N
-        assert p.engine_kwargs["kv_cache"] == "paged"
+        assert "kv_cache" not in p.engine_kwargs
         flat = [d for devs in p.replica_devices for d in devs]
         assert sorted(flat, key=str) \
             == sorted(jax.devices()[:N], key=str)

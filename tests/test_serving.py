@@ -1,17 +1,19 @@
-"""Continuous-batching serving engine (apex_tpu.serving).
+"""The serving stack above the engine (apex_tpu.serving).
 
-Correctness contracts under test:
-- greedy decode through the slotted engine is TOKEN-IDENTICAL to the
-  fixed-batch ``generate()`` loop for the same prompts;
-- a steady-state soak interleaving admissions/evictions across >= 3
-  prompt-length buckets with heterogeneous sampling params triggers
-  ZERO retraces after warmup (asserted both via the process-wide
-  trace-event counter and the engine's own ``retrace_guard`` budgets,
-  which would raise ``RetraceError`` on any excess trace);
-- a request's sampled tokens depend on its own seed, not on its
-  co-tenants (per-slot rng);
-- the threaded ``InferenceServer`` streams tokens, emits metrics, and
-  shuts down cleanly.
+``tests/test_paged_serving.py`` holds the engine's own contracts
+(parity with ``generate()`` across page and chunk boundaries, the
+zero-retrace soak, the allocator, preemption, sharing, drafting,
+quantized pages).  Here:
+
+- what the engine and the scheduler refuse: a sliding-window model, a
+  request that can never fit, a full queue, bad sampling parameters, a
+  second shape through a guarded executable;
+- the threaded ``InferenceServer``: the default construction builds
+  the paged engine and serves ``generate()``'s tokens, streams, emits
+  metrics, shuts down, survives (or reports) a worker crash, drains
+  and dies as the fleet router expects;
+- the handle's error contract, and the key sets of ``health()`` and of
+  the metrics payload.
 """
 
 import numpy as np
@@ -22,14 +24,13 @@ import jax.numpy as jnp
 
 from apex_tpu.models import GPTConfig, GPTModel, LlamaConfig, LlamaModel, generate
 from apex_tpu.serving import (
-    Engine,
     InferenceServer,
+    PagedEngine,
     QueueFull,
     Request,
     Scheduler,
 )
-from apex_tpu.serving import cache as slot_cache
-from apex_tpu.utils import MetricsWriter, tracecheck
+from apex_tpu.utils import MetricsWriter
 from apex_tpu.utils.tracecheck import RetraceError
 
 
@@ -42,109 +43,60 @@ def _tiny_gpt():
     return model, {"params": params["params"]}
 
 
-def _tiny_llama():
-    cfg = LlamaConfig.tiny(scan_layers=True)
-    model = LlamaModel(cfg)
-    params = model.init(jax.random.PRNGKey(0),
-                        jnp.zeros((1, 4), jnp.int32))
-    return model, {"params": params["params"]}
-
-
 @pytest.fixture(scope="module")
 def gpt():
     return _tiny_gpt()
 
 
-@pytest.fixture(scope="module")
-def llama():
-    return _tiny_llama()
+def _engine(gpt, **kw):
+    model, params = gpt
+    kw.setdefault("block_size", 8)
+    kw.setdefault("prefill_chunk", 4)
+    return PagedEngine(model, params, **kw)
 
 
-def _prompts(rng, vocab, lengths):
-    return [rng.integers(0, vocab, size=(L,)).astype(np.int32)
-            for L in lengths]
+def _server(gpt, **kw):
+    """A server over the tiny model: pages of 8 and chunks of 4, so a
+    short prompt still crosses a chunk boundary."""
+    model, params = gpt
+    kw.setdefault("block_size", 8)
+    kw.setdefault("prefill_chunk", 4)
+    return InferenceServer(model, params, **kw)
 
 
-class TestSlotCache:
-    def test_pool_shapes_and_reset(self, gpt):
-        model, _ = gpt
-        from apex_tpu.models.generate import cache_shapes
-
-        shapes = cache_shapes(model, 1)
-        pool = slot_cache.stacked_zeros(shapes, 3)
-        flat = jax.tree.leaves(pool)
-        per_slot = jax.tree.leaves(shapes)
-        assert all(p.shape == (3,) + tuple(s.shape)
-                   for p, s in zip(flat, per_slot))
-        # write then reset roundtrips to zeros
-        one = jax.tree.map(
-            lambda s: jnp.ones(s.shape, s.dtype), shapes)
-        pool = slot_cache.write_slot(pool, 1, one)
-        assert all(float(jnp.sum(jnp.abs(leaf[1].astype(jnp.float32))))
-                   > 0 for leaf in jax.tree.leaves(pool))
-        pool = slot_cache.reset_slot(pool, 1)
-        assert all(float(jnp.sum(jnp.abs(leaf.astype(jnp.float32))))
-                   == 0 for leaf in jax.tree.leaves(pool))
-
-    def test_rewind_targets_only_index_leaves(self, gpt):
-        model, _ = gpt
-        from apex_tpu.models.generate import init_cache
-
-        cache = init_cache(model, 1)
-        cache = jax.tree.map(
-            lambda x: x + jnp.ones_like(x), cache)
-        out = slot_cache.rewind_index_leaves(cache, 7)
-        flat = jax.tree_util.tree_flatten_with_path(out)[0]
-        saw_index = 0
-        for path, leaf in flat:
-            name = slot_cache._leaf_name(path)
-            if name in ("cache_index", "position_index"):
-                saw_index += 1
-                assert np.all(np.asarray(leaf) == 7), name
-            else:
-                assert np.all(np.asarray(leaf) == 1), name
-        assert saw_index >= 2       # per-layer cache_index + model pos
-
+class TestEngineValidation:
     def test_sliding_window_cache_rejected(self):
         cfg = LlamaConfig.tiny(sliding_window=5, scan_layers=False)
         model = LlamaModel(cfg)
         params = model.init(jax.random.PRNGKey(0),
                             jnp.zeros((1, 4), jnp.int32))
-        with pytest.raises(ValueError, match="ring-buffer"):
-            Engine(model, {"params": params["params"]},
-                   max_slots=2, prompt_buckets=(8,))
-
-
-class TestEngineValidation:
-    def test_bucket_exceeding_max_seq_len_rejected(self, gpt):
-        model, params = gpt
-        S = model.cfg.max_seq_len
-        with pytest.raises(ValueError, match="bucket"):
-            Engine(model, params, prompt_buckets=(S,))
+        with pytest.raises(ValueError, match="sliding-window"):
+            PagedEngine(model, {"params": params["params"]},
+                        max_slots=2, block_size=8)
 
     def test_oversized_request_rejected_at_submit(self, gpt):
-        model, params = gpt
-        engine = Engine(model, params, max_slots=1,
-                        prompt_buckets=(8,))
-        sched = Scheduler(engine)
-        with pytest.raises(ValueError, match="bucket"):
-            sched.submit(Request(prompt=np.zeros(9, np.int32),
-                                 max_new_tokens=1))
+        model, _ = gpt
+        S = model.cfg.max_seq_len
+        sched = Scheduler(_engine(gpt, max_slots=1, pool_tokens=32))
+        # fits the context, but the whole pool could never hold it
+        with pytest.raises(ValueError, match="pool"):
+            sched.submit(Request(prompt=np.zeros(30, np.int32),
+                                 max_new_tokens=10))
         with pytest.raises(ValueError, match="max_seq_len"):
-            sched.submit(Request(
-                prompt=np.zeros(8, np.int32),
-                max_new_tokens=model.cfg.max_seq_len))
+            sched.submit(Request(prompt=np.zeros(8, np.int32),
+                                 max_new_tokens=S))
+        with pytest.raises(ValueError, match="empty prompt"):
+            sched.submit(Request(prompt=np.zeros(0, np.int32),
+                                 max_new_tokens=1))
         with pytest.raises(ValueError, match="top_k"):
             sched.submit(Request(prompt=np.zeros(4, np.int32),
                                  max_new_tokens=2,
                                  temperature=1.0,
                                  top_k=model.cfg.vocab_size + 1))
+        assert sched.queue_depth == 0
 
     def test_queue_capacity_bounded(self, gpt):
-        model, params = gpt
-        engine = Engine(model, params, max_slots=1,
-                        prompt_buckets=(8,))
-        sched = Scheduler(engine, queue_capacity=2)
+        sched = Scheduler(_engine(gpt, max_slots=1), queue_capacity=2)
         for _ in range(2):
             sched.submit(Request(prompt=np.zeros(4, np.int32),
                                  max_new_tokens=1))
@@ -152,77 +104,21 @@ class TestEngineValidation:
             sched.submit(Request(prompt=np.zeros(4, np.int32),
                                  max_new_tokens=1))
 
-
-class TestGreedyParity:
-    # [the llama twin is slow-marked: ~17s of CPU compile for the same
-    # dense-engine property the gpt twin pins in tier-1; it still runs
-    # under -m slow and in the on-chip pass]
-    @pytest.mark.l0
-    @pytest.mark.parametrize("which", [
-        "gpt", pytest.param("llama", marks=pytest.mark.slow)])
-    def test_engine_matches_generate(self, which, request):
-        """Mixed-length greedy requests through 2 slots must reproduce
-        generate()'s token chains exactly — including requests that
-        queue behind the first wave (continuous refill)."""
-        model, params = request.getfixturevalue(which)
-        rng = np.random.default_rng(3)
-        prompts = _prompts(rng, model.cfg.vocab_size,
-                           (3, 5, 8, 4, 11))
-        budgets = [6, 3, 5, 7, 4]
-        engine = Engine(model, params, max_slots=2,
-                        prompt_buckets=(4, 8, 16))
-        sched = Scheduler(engine)
-        reqs = [sched.submit(Request(prompt=p, max_new_tokens=n))
-                for p, n in zip(prompts, budgets)]
-        sched.drain()
-        for p, n, r in zip(prompts, budgets, reqs):
-            ref = np.asarray(generate(
-                model, params, jnp.asarray(p[None]),
-                max_new_tokens=n))[0, len(p):]
-            np.testing.assert_array_equal(
-                np.asarray(r.tokens), ref,
-                err_msg=f"{which} prompt_len={len(p)} n={n}")
-
-    def test_chunked_prefill_engine_matches_generate(self, gpt):
-        """The engine's prefill rides the same chunked path as
-        generate(prefill_chunk=...): forcing small chunks must not
-        change the greedy token chain."""
-        model, params = gpt
-        rng = np.random.default_rng(19)
-        prompt = rng.integers(0, model.cfg.vocab_size,
-                              size=(11,)).astype(np.int32)
-        ref = np.asarray(generate(
-            model, params, jnp.asarray(prompt[None]),
-            max_new_tokens=4))[0, 11:]
-        engine = Engine(model, params, max_slots=1,
-                        prompt_buckets=(16,), prefill_chunk=4)
-        sched = Scheduler(engine)
-        req = sched.submit(Request(prompt=prompt, max_new_tokens=4))
-        sched.drain()
-        np.testing.assert_array_equal(np.asarray(req.tokens), ref)
-
-    def test_eos_stops_early_and_matches_generate(self, gpt):
-        model, params = gpt
-        rng = np.random.default_rng(5)
-        prompt = rng.integers(0, model.cfg.vocab_size,
-                              size=(5,)).astype(np.int32)
-        n = 8
-        ref = np.asarray(generate(
-            model, params, jnp.asarray(prompt[None]),
-            max_new_tokens=n))[0, 5:]
-        eos = int(ref[2])            # force a stop three tokens in
-        engine = Engine(model, params, max_slots=1,
-                        prompt_buckets=(8,))
-        sched = Scheduler(engine)
-        req = sched.submit(Request(prompt=prompt, max_new_tokens=n,
-                                   eos_id=eos))
-        sched.drain()
-        got = np.asarray(req.tokens)
-        # engine stops AT the produced eos; generate's chain up to the
-        # first eos must match token for token
-        first = int(np.argmax(ref == eos))
-        np.testing.assert_array_equal(got, ref[:first + 1])
-        assert got[-1] == eos and len(got) < n
+    def test_guard_raises_on_forced_retrace(self, gpt):
+        """The guard is live, not decorative: a second feed width
+        through the decode executable, beyond its budget of one trace,
+        must raise RetraceError (this is what a shape leak in
+        production would look like)."""
+        engine = _engine(gpt, max_slots=1)
+        engine.warmup()
+        ones = np.ones((1,), np.int32)
+        off = np.zeros((1,), bool)
+        with pytest.raises(RetraceError):
+            engine._decode(engine._variables, engine.cache,
+                           engine.state, engine._tables,
+                           engine._cursors, np.zeros((1, 2), np.int32),
+                           ones, off, off)
+        assert engine.trace_counts["decode_step"] == 1
 
 
 class TestTopPSampling:
@@ -234,15 +130,13 @@ class TestTopPSampling:
         """top_p=1.0 and top_p=None are the same program AND the same
         tokens (the disabled nucleus filter is an exact no-op in
         sample_dynamic, not an epsilon approximation)."""
-        model, params = gpt
+        model, _ = gpt
         rng = np.random.default_rng(23)
         prompt = rng.integers(0, model.cfg.vocab_size,
                               size=(6,)).astype(np.int32)
 
         def run(top_p):
-            engine = Engine(model, params, max_slots=1,
-                            prompt_buckets=(8,))
-            sched = Scheduler(engine)
+            sched = Scheduler(_engine(gpt, max_slots=1))
             req = sched.submit(Request(
                 prompt=prompt, max_new_tokens=6, temperature=0.9,
                 top_p=top_p, seed=5))
@@ -251,7 +145,7 @@ class TestTopPSampling:
 
         assert run(None) == run(1.0)
 
-    def test_dynamic_nucleus_restricts_tokens(self, gpt):
+    def test_dynamic_nucleus_restricts_tokens(self):
         """sample_dynamic with a per-slot top_p must only emit tokens
         from each row's nucleus; disabled rows are exact no-ops."""
         from apex_tpu.serving.engine import sample_dynamic
@@ -282,128 +176,49 @@ class TestTopPSampling:
         assert any(t not in nucleus for t in seen1)
 
     def test_top_p_validation_at_submit(self, gpt):
-        model, params = gpt
-        engine = Engine(model, params, max_slots=1,
-                        prompt_buckets=(8,))
-        sched = Scheduler(engine)
+        sched = Scheduler(_engine(gpt, max_slots=1))
         with pytest.raises(ValueError, match="top_p"):
             sched.submit(Request(prompt=np.zeros(4, np.int32),
                                  max_new_tokens=2, temperature=1.0,
                                  top_p=1.5))
 
 
-class TestSamplingDeterminism:
-    def test_tokens_independent_of_cotenants(self, gpt):
-        """A sampled request carries its own rng (seeded at admission):
-        running alone or beside other traffic must not change its
-        tokens."""
-        model, params = gpt
-        rng = np.random.default_rng(7)
-        prompt = rng.integers(0, model.cfg.vocab_size,
-                              size=(6,)).astype(np.int32)
-
-        def run(extra_traffic):
-            engine = Engine(model, params, max_slots=2,
-                            prompt_buckets=(8,))
-            sched = Scheduler(engine)
-            req = sched.submit(Request(
-                prompt=prompt, max_new_tokens=5, temperature=0.9,
-                top_k=20, seed=123))
-            if extra_traffic:
-                for i in range(3):
-                    sched.submit(Request(
-                        prompt=rng.integers(
-                            0, model.cfg.vocab_size,
-                            size=(4 + i,)).astype(np.int32),
-                        max_new_tokens=4, temperature=1.3, seed=i))
-            sched.drain()
-            return list(req.tokens)
-
-        assert run(False) == run(True)
-
-
-class TestSoakZeroRetraces:
-    def test_steady_state_soak(self, gpt):
-        """The acceptance soak: >= 3 prompt-length buckets, mixed
-        temperatures / top_k / top_p / eos / budgets, admissions and
-        evictions interleaving across 14 requests through 3 slots —
-        zero jaxpr traces after warmup.  The engine's retrace_guards
-        (budget: decode_step/admit/release = 1, prefill = #buckets)
-        raise RetraceError on any excess trace, and the process-wide
-        trace-event counter cross-checks the whole soak.  Nucleus
-        (top_p) traffic rides the same executable as everything else
-        (the ISSUE-3 plumbing contract: per-slot device-array
-        params, budgets unchanged)."""
-        model, params = gpt
-        engine = Engine(model, params, max_slots=3,
-                        prompt_buckets=(4, 8, 16))
-        sched = Scheduler(engine)
-        engine.warmup()
-        assert engine.trace_counts == {
-            "decode_step": 1, "prefill": 3, "admit": 1, "release": 1}
-
-        rng = np.random.default_rng(11)
-        before = tracecheck.trace_event_count()
-        cases = [
-            (3, 4, 0.0, None, None, None),
-            (7, 3, 0.8, 20, None, None),
-            (12, 5, 1.2, 5, None, 0.9), (2, 6, 0.0, None, 17, None),
-            (8, 2, 0.5, None, None, 0.5),
-            (16, 4, 0.0, None, None, None),
-            (5, 3, 1.0, 50, 3, 0.95), (4, 5, 0.0, None, None, None),
-            (9, 4, 0.7, 10, None, None), (1, 2, 0.0, None, None, None),
-            (13, 3, 1.5, 2, None, 1.0), (6, 6, 0.0, None, 900, None),
-            (11, 2, 0.9, None, None, 0.7),
-            (8, 4, 0.0, None, None, None),
-        ]
-        reqs = []
-        for i, (L, n, t, k, eos, p) in enumerate(cases):
-            reqs.append(sched.submit(Request(
-                prompt=rng.integers(0, model.cfg.vocab_size,
-                                    size=(L,)).astype(np.int32),
-                max_new_tokens=n, temperature=t, top_k=k, top_p=p,
-                eos_id=eos, seed=i)))
-        events = sched.drain()
-        assert tracecheck.trace_event_count() == before, (
-            "steady-state soak retraced after warmup")
-        assert engine.trace_counts == {
-            "decode_step": 1, "prefill": 3, "admit": 1, "release": 1}
-        # every request produced tokens and respected its budget
-        for (L, n, t, k, eos, p), r in zip(cases, reqs):
-            assert 1 <= len(r.tokens) <= n
-            if eos is None:
-                assert len(r.tokens) == n
-        assert len(events) == sum(len(r.tokens) for r in reqs)
-
-    def test_unbucketable_prompt_raises_not_retraces(self, gpt):
-        model, params = gpt
-        engine = Engine(model, params, max_slots=1,
-                        prompt_buckets=(4,))
-        with pytest.raises(ValueError, match="bucket"):
-            engine.admit(0, np.zeros(5, np.int32), max_new_tokens=1)
-
-    def test_guard_raises_on_forced_retrace(self, gpt):
-        """The guard is live, not decorative: bypassing the bucketer
-        with a second prefill shape beyond the budget must raise
-        RetraceError (this is what a shape leak in production would
-        look like)."""
-        model, params = gpt
-        engine = Engine(model, params, max_slots=1,
-                        prompt_buckets=(4,))
-        engine.warmup()
-        with pytest.raises(RetraceError):
-            engine._prefill(engine._variables,
-                            jnp.zeros((1, 6), jnp.int32), np.int32(6))
-
-
 class TestInferenceServer:
-    def test_streaming_and_metrics(self, gpt):
+    def test_default_server_is_paged_and_matches_generate(self, gpt):
+        """``InferenceServer(model, params)``, no keyword: the engine
+        the benchmark measures, serving generate()'s tokens."""
         model, params = gpt
+        rng = np.random.default_rng(29)
+        # longer than the default chunk and the default page
+        prompt = rng.integers(0, model.cfg.vocab_size,
+                              size=(37,)).astype(np.int32)
+        ref = np.asarray(generate(
+            model, params, jnp.asarray(prompt[None]),
+            max_new_tokens=5))[0, 37:]
+        with InferenceServer(model, params) as server:
+            assert type(server.engine) is PagedEngine
+            got = server.submit(
+                prompt, max_new_tokens=5).result(timeout=300)
+            assert server.engine.trace_counts == {
+                "decode_step": 1, "prefill_step": 1, "admit": 1,
+                "release": 1}
+        np.testing.assert_array_equal(np.asarray(got), ref)
+        assert server.health()["blocks_in_use"] == 0
+
+    def test_prefill_chunk_left_unset_is_the_engines_default(self, gpt):
+        model, params = gpt
+        engine = InferenceServer(model, params).engine
+        assert engine._chunk == PagedEngine(model, params)._chunk == 32
+        assert engine.pool_tokens == 4 * model.cfg.max_seq_len
+        with pytest.raises(ValueError, match="prefill_chunk"):
+            InferenceServer(model, params, prefill_chunk=0)
+
+    def test_streaming_and_metrics(self, gpt):
+        model, _ = gpt
         rows = []
         writer = MetricsWriter(sink=lambda s, m: rows.append((s, m)))
-        server = InferenceServer(
-            model, params, max_slots=2, prompt_buckets=(4, 8),
-            metrics=writer, metrics_interval=2)
+        server = _server(gpt, max_slots=2, metrics=writer,
+                         metrics_interval=2)
         rng = np.random.default_rng(13)
         with server:
             h1 = server.submit(
@@ -424,6 +239,57 @@ class TestInferenceServer:
                     "queue_depth"} <= set(m)
             assert 0.0 <= m["occupancy"] <= 1.0
 
+    def test_health_and_metrics_key_sets(self, gpt):
+        """What a dashboard, the fleet router and the benchmark's
+        per-layer metrics read by name — as literals, so a rename or a
+        key that goes missing fails here.  ``mesh_shape`` (tensor-
+        parallel replicas) and the three ``ssm_*`` (recurrent state)
+        are pinned in test_tp_serving.py / test_falcon_h1.py."""
+        rows = []
+        writer = MetricsWriter(sink=lambda s, m: rows.append(m))
+        server = _server(gpt, max_slots=2, spec_tokens=2,
+                         metrics=writer, metrics_interval=1)
+        with server:
+            server.submit(np.arange(1, 8, dtype=np.int32),
+                          max_new_tokens=3).result(timeout=300)
+            health = server.health()
+        assert set(health) == {
+            "status", "ready", "draining", "uptime_s", "steps",
+            "queue_depth", "occupancy", "tokens_emitted", "requeues",
+            "failed_requests", "deadline_expired", "drain_evicted",
+            "preempts", "spans", "admitted", "queue_wait_s",
+            "first_tokens", "prefill_s", "compiles", "error",
+            "chips_per_replica", "blocks_in_use", "blocks_total",
+            "live_tokens", "shared_blocks", "cow_forks",
+            "kv_pages_live", "kv_dtype", "kv_bits",
+            "spec_accept_rate"}         # the last: spec_tokens > 0 only
+        assert set(health["spans"]) == {
+            "apex/serve/step", "apex/serve/deliver",
+            "apex/sched/admit", "apex/sched/route",
+            "apex/engine/step_prefill", "apex/engine/step_decode",
+            "apex/engine/step_spec", "apex/engine/plan",
+            "apex/engine/dispatch", "apex/engine/fetch",
+            "apex/engine/commit"}
+        merged = {}
+        for row in rows:
+            merged.update(row)
+        assert set(merged) == {
+            "tokens_per_sec", "tokens_per_sec_per_chip",
+            "chips_per_replica", "occupancy", "queue_depth",
+            "tokens_total", "requeues", "failed_requests",
+            "deadline_expired", "preempts", "ttft_p50_s", "ttft_p99_s",
+            "queue_wait_p50_s", "queue_wait_p99_s", "step_ms_p50",
+            "step_ms_p99", "blocks_in_use", "blocks_total",
+            "live_tokens", "shared_blocks", "cow_forks", "kv_bits",
+            "spec_accept_rate"}
+        assert server.engine.trace_counts == {
+            "decode_step": 1, "prefill_step": 1, "spec_step": 1,
+            "admit": 1, "release": 1}
+        # without drafting the accept rate is absent, not 0.0
+        plain = _server(gpt, max_slots=1)
+        assert "spec_accept_rate" not in plain.health()
+        assert "spec_step" not in plain.engine.trace_counts
+
     def test_greedy_parity_through_server(self, gpt):
         model, params = gpt
         rng = np.random.default_rng(17)
@@ -432,18 +298,43 @@ class TestInferenceServer:
         ref = np.asarray(generate(
             model, params, jnp.asarray(prompt[None]),
             max_new_tokens=5))[0, 5:]
-        with InferenceServer(model, params, max_slots=2,
-                             prompt_buckets=(8,)) as server:
+        with _server(gpt, max_slots=2) as server:
             got = server.submit(
                 prompt, max_new_tokens=5).result(timeout=300)
         np.testing.assert_array_equal(np.asarray(got), ref)
 
+    def test_preempted_request_keeps_its_stream_and_its_chain(self, gpt):
+        """Two tenants overcommit a pool that cannot hold both: the
+        engine preempts the younger, the server requeues it, and both
+        clients still read generate()'s chain off their handles —
+        ``preempts`` in health() is what the benchmark's
+        ``preempts.serve`` reads."""
+        model, params = gpt
+        rng = np.random.default_rng(7)
+        prompts = [rng.integers(0, model.cfg.vocab_size,
+                                size=(n,)).astype(np.int32)
+                   for n in (20, 22)]
+        budgets = (30, 28)
+        server = _server(gpt, max_slots=2, pool_tokens=64,
+                         admit_headroom=0)
+        with server:
+            handles = [server.submit(p, max_new_tokens=n)
+                       for p, n in zip(prompts, budgets)]
+            got = [h.result(timeout=300) for h in handles]
+            health = server.health()
+        assert health["preempts"] >= 1
+        assert health["failed_requests"] == 0 == health["requeues"]
+        assert health["blocks_in_use"] == 0
+        for p, n, toks in zip(prompts, budgets, got):
+            ref = np.asarray(generate(
+                model, params, jnp.asarray(p[None]),
+                max_new_tokens=n))[0, len(p):]
+            np.testing.assert_array_equal(np.asarray(toks), ref)
+
     def test_shutdown_without_drain_cancels(self, gpt):
         from apex_tpu.serving import ServerClosed
 
-        model, params = gpt
-        server = InferenceServer(model, params, max_slots=1,
-                                 prompt_buckets=(4,))
+        server = _server(gpt, max_slots=1)
         server.start(warmup=False)
         h = server.submit(np.zeros(3, np.int32), max_new_tokens=200)
         server.shutdown(wait=False, timeout=60)
@@ -456,9 +347,7 @@ class TestInferenceServer:
         root cause is preserved on server.error."""
         from apex_tpu.serving import ServerClosed
 
-        model, params = gpt
-        server = InferenceServer(model, params, max_slots=1,
-                                 prompt_buckets=(4,))
+        server = _server(gpt, max_slots=1)
         boom = RuntimeError("engine exploded")
 
         def exploding_step():
@@ -477,9 +366,7 @@ class TestInferenceServer:
     def test_submit_after_shutdown_raises(self, gpt):
         from apex_tpu.serving import ServerClosed
 
-        model, params = gpt
-        server = InferenceServer(model, params, max_slots=1,
-                                 prompt_buckets=(4,))
+        server = _server(gpt, max_slots=1)
         server.start(warmup=False)
         server.shutdown()
         with pytest.raises(ServerClosed):
@@ -493,9 +380,7 @@ class TestHandleErrorContract:
     bare timeout."""
 
     def test_timeout_is_retryable_not_terminal(self, gpt):
-        model, params = gpt
-        server = InferenceServer(model, params, max_slots=1,
-                                 prompt_buckets=(4,))
+        server = _server(gpt, max_slots=1)
         server.start(warmup=False)      # first token needs a compile
         try:
             h = server.submit(np.zeros(3, np.int32), max_new_tokens=3)
@@ -511,9 +396,7 @@ class TestHandleErrorContract:
     def test_shutdown_surfaces_terminal_not_timeout(self, gpt):
         from apex_tpu.serving import ServerClosed
 
-        model, params = gpt
-        server = InferenceServer(model, params, max_slots=1,
-                                 prompt_buckets=(4,))
+        server = _server(gpt, max_slots=1)
         server.start(warmup=False)
         h = server.submit(np.zeros(3, np.int32), max_new_tokens=200)
         # wait=False cancels in-flight requests; after the worker has
@@ -530,9 +413,7 @@ class TestHandleErrorContract:
     def test_deadline_failure_is_request_failed(self, gpt):
         from apex_tpu.serving import RequestFailed
 
-        model, params = gpt
-        server = InferenceServer(model, params, max_slots=1,
-                                 prompt_buckets=(4,))
+        server = _server(gpt, max_slots=1)
         with server:
             h = server.submit(np.zeros(3, np.int32),
                               max_new_tokens=100, deadline=1e-4)
@@ -555,9 +436,7 @@ class TestDrainKillAndHealthFields:
     def test_drain_lifecycle_health_fields_and_eviction(self, gpt):
         from apex_tpu.serving import ReplicaDraining, ServerClosed
 
-        model, params = gpt
-        server = InferenceServer(model, params, max_slots=1,
-                                 prompt_buckets=(4,))
+        server = _server(gpt, max_slots=1)
         server.start(warmup=False)      # executables compile on demand
         h = server.submit(np.zeros(3, np.int32), max_new_tokens=200)
         for _ in h.stream(timeout=300):
@@ -579,6 +458,9 @@ class TestDrainKillAndHealthFields:
         assert health["status"] == "serving"
         assert health["ready"] is False
         assert health["drain_evicted"] == 1
+        # the drain released the slot: every page is back in the pool
+        assert health["blocks_in_use"] == 0
+        assert health["blocks_total"] == server.engine.blocks_total
         with pytest.raises(ServerClosed, match="draining"):
             server.submit(np.zeros(3, np.int32), max_new_tokens=1)
         server.shutdown(timeout=60)
@@ -586,9 +468,7 @@ class TestDrainKillAndHealthFields:
     def test_kill_cancels_clients_and_reports_failed(self, gpt):
         from apex_tpu.serving import ServerClosed
 
-        model, params = gpt
-        server = InferenceServer(model, params, max_slots=1,
-                                 prompt_buckets=(4,))
+        server = _server(gpt, max_slots=1)
         server.start(warmup=False)
         h = server.submit(np.zeros(3, np.int32), max_new_tokens=200)
         server.kill()
@@ -599,6 +479,28 @@ class TestDrainKillAndHealthFields:
         assert server.error is not None
         server.kill()                           # idempotent
         server.shutdown()                       # and shutdown-safe
+
+    def test_kill_mid_decode_abandons_the_pool(self, gpt):
+        """A kill takes the device memory with it: nothing is released,
+        so the dead replica's pool still counts its tenant's pages —
+        the router migrates from the streamed prefix, never from the
+        engine."""
+        from apex_tpu.serving import ServerClosed
+
+        server = _server(gpt, max_slots=1)
+        server.start(warmup=False)
+        h = server.submit(np.arange(1, 12, dtype=np.int32),
+                          max_new_tokens=200)
+        for _ in h.stream(timeout=300):
+            break                       # mid-decode: pages are held
+        server.kill()
+        with pytest.raises(ServerClosed):
+            h.result(timeout=300)
+        assert len(h.tokens_so_far) >= 1
+        health = server.health()
+        assert health["status"] == "failed"
+        assert health["blocks_in_use"] >= 2     # 11 + tokens > one page
+        assert health["live_tokens"] >= 11       # the whole prompt is in
 
 
 class TestLatencySummarySnapshotRace:

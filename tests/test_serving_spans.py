@@ -120,7 +120,7 @@ class TestServerSpans:
     def test_health_spans_add_up_after_a_drained_run(self, gpt):
         model, params = gpt
         server = InferenceServer(
-            model, params, max_slots=2, kv_cache="paged", block_size=8,
+            model, params, max_slots=2, block_size=8,
             prefill_chunk=4, pool_tokens=256)
         prompts = _prompts(model, (3, 9, 14, 6, 11))
         with server:
@@ -172,7 +172,7 @@ class TestServerSpans:
     def test_preempt_readmissions_are_counted(self, gpt):
         model, params = gpt
         server = InferenceServer(
-            model, params, max_slots=2, kv_cache="paged", block_size=8,
+            model, params, max_slots=2, block_size=8,
             prefill_chunk=4, pool_tokens=64, admit_headroom=0)
         p1, p2 = _prompts(model, (20, 22), seed=7)
         with server:
@@ -212,28 +212,10 @@ class TestServerSpans:
             == moved[engine_mod.PLAN] == moved[engine_mod.COMMIT]
         assert sched.admitted == 1 and sched.queue_wait_s >= 0
 
-    def test_dense_engine_has_step_decode_and_fetch_only(self, gpt):
-        model, params = gpt
-        server = InferenceServer(model, params, max_slots=2,
-                                 prompt_buckets=(8,))
-        with server:
-            before = server.health()
-            self._serve(server, _prompts(model, (3, 5)), budget=4)
-            after = server.health()
-        assert set(after["spans"]) == {
-            api.SERVE_STEP, api.DELIVER, scheduler.ADMIT,
-            scheduler.ROUTE, engine_mod.STEP_DECODE, engine_mod.FETCH}
-        steps = after["steps"] - before["steps"]
-        assert _moved(after, before, engine_mod.STEP_DECODE, "n") == steps
-        assert _moved(after, before, engine_mod.FETCH, "n") == steps
-        assert after["compiles"] == sum(
-            server.engine.trace_counts.values())
-        assert after["first_tokens"] - before["first_tokens"] == 2
-
     def test_health_from_another_thread_never_raises(self, gpt):
         model, params = gpt
         server = InferenceServer(
-            model, params, max_slots=2, kv_cache="paged", block_size=8,
+            model, params, max_slots=2, block_size=8,
             prefill_chunk=4, pool_tokens=256)
         stop = threading.Event()
         errors, reads = [], [0]

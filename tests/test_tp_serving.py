@@ -235,22 +235,14 @@ class TestConfigTimeValidation:
                                              "tensor-parallel"):
             PagedEngine(model, params, mesh=3)
 
-    def test_server_rejects_tp_on_dense(self, gpt):
-        model, params = gpt
-        with pytest.raises(ValueError, match="require "
-                                             "kv_cache='paged'"):
-            InferenceServer(model, params, tp=2)
-
     def test_server_rejects_tp_mesh_mismatch(self, gpt, mesh2):
         model, params = gpt
         with pytest.raises(ValueError, match="disagrees with mesh"):
-            InferenceServer(model, params, kv_cache="paged",
-                            tp=4, mesh=mesh2)
+            InferenceServer(model, params, tp=4, mesh=mesh2)
         # mesh may be the engine's int spelling: still the loud
         # mismatch error, never an AttributeError on .shape
         with pytest.raises(ValueError, match="disagrees with mesh"):
-            InferenceServer(model, params, kv_cache="paged",
-                            tp=4, mesh=2)
+            InferenceServer(model, params, tp=4, mesh=2)
 
     def test_tp_mesh_needs_enough_devices(self):
         with pytest.raises(ValueError, match="devices"):
@@ -458,8 +450,7 @@ class TestTPServer:
         rows = []
         writer = MetricsWriter(sink=lambda s, m: rows.append(m))
         server = InferenceServer(
-            model, params, max_slots=2, kv_cache="paged",
-            block_size=8, prefill_chunk=4, tp=2,
+            model, params, max_slots=2, block_size=8, prefill_chunk=4, tp=2,
             metrics=writer, metrics_interval=1)
         rng = np.random.default_rng(2)
         with server:
@@ -488,7 +479,7 @@ class TestTPServer:
     def test_single_chip_server_reports_one_chip(self, gpt):
         model, params = gpt
         server = InferenceServer(model, params, max_slots=1,
-                                 kv_cache="paged", block_size=8,
+                                 block_size=8,
                                  prefill_chunk=4)
         health = server.health()      # probe works unstarted
         assert health["chips_per_replica"] == 1
