@@ -35,6 +35,7 @@ from apex_tpu.ops.paged_attention import (
     kv_quant_spec,
     paged_attention,
     paged_decode_fused,
+    paged_write,
     quantize_kv,
     rope_rows as _rope_rows,
     tp_head_shards,
@@ -562,8 +563,8 @@ class ParallelAttention(nn.Module):
             # aliased, only the write page moves); elsewhere the
             # dispatch target is the historical unfused XLA sequence
             # verbatim, so this branch is bitwise the old path there.
-            # Chunked prefill and the speculative verify (s > 1) keep
-            # the one-pass XLA scatter below.
+            # Chunked prefill and the speculative verify (s > 1) write
+            # through paged_write below: the touched pages, in place.
             outs = paged_decode_fused(
                 q, k, v, pk.value, pv.value, bt.value, cur.value,
                 max_seq_len=S, cos_b=cos_b, sin_b=sin_b,
@@ -592,11 +593,18 @@ class ParallelAttention(nn.Module):
         # when a near-full tenant rides a wide mixed step
         phys = jnp.where(positions < S, phys, 0)
         off = positions % BS
-        kT = k.transpose(2, 0, 1, 3)             # (hk, b, s, d)
-        vT = v.transpose(2, 0, 1, 3)
+
+        def write(k_rows, v_rows, phys):
+            # the touched pages move, in place, on a TPU; elsewhere
+            # (and for a tensor-parallel pool) the one-pass scatter
+            kp_new, vp_new = paged_write(
+                k_rows, v_rows, pk.value, pv.value, phys, off,
+                mesh=cfg.kv_mesh, shard_axis=cfg.kv_shard_axis)
+            pk.value = pin(kp_new, 0)
+            pv.value = pin(vp_new, 0)
+
         if store_dt is None:
-            pk.value = pin(pk.value.at[:, phys, off].set(kT), 0)
-            pv.value = pin(pv.value.at[:, phys, off].set(vT), 0)
+            write(k, v, phys)
             return paged_attention(q, pk.value, pv.value, bt.value,
                                    cur.value, scale=d ** -0.5,
                                    mesh=cfg.kv_mesh,
@@ -631,6 +639,8 @@ class ParallelAttention(nn.Module):
         real = (jnp.arange(s, dtype=jnp.int32)[None, :]
                 < cl.value[:, None])                         # (b, s)
         phys = jnp.where(real, phys, 0)
+        kT = k.transpose(2, 0, 1, 3)             # (hk, b, s, d)
+        vT = v.transpose(2, 0, 1, 3)
         ka = jnp.max(jnp.abs(kT.astype(jnp.float32)), axis=-1)
         va = jnp.max(jnp.abs(vT.astype(jnp.float32)), axis=-1)
         ka = jnp.where(real[None], ka, 0.0)                  # (hk, b, s)
@@ -653,10 +663,10 @@ class ParallelAttention(nn.Module):
         vs_new = pin(
             vsc.value.at[:, fresh].set(0.0).at[:, phys].max(v_run), 0)
         ksc.value, vsc.value = ks_new, vs_new
-        pk.value = pin(pk.value.at[:, phys, off].set(
-            quantize_kv(kT, ks_new[:, phys], qmax, store_dt)), 0)
-        pv.value = pin(pv.value.at[:, phys, off].set(
-            quantize_kv(vT, vs_new[:, phys], qmax, store_dt)), 0)
+        write(quantize_kv(k, ks_new[:, phys].transpose(1, 2, 0), qmax,
+                          store_dt),
+              quantize_kv(v, vs_new[:, phys].transpose(1, 2, 0), qmax,
+                          store_dt), phys)
         return paged_attention(q, pk.value, pv.value, bt.value,
                                cur.value, scale=d ** -0.5,
                                k_scales=ks_new, v_scales=vs_new,

@@ -28,7 +28,9 @@ Layouts::
                   chunk; query i of row b sits at position lengths[b]+i
 
 The chunk's own K/V must already be written into the pool (the model's
-write-then-attend convention, ``models/transformer.py``); visibility is
+write-then-attend convention, ``models/transformer.py``: a step wider
+than one token writes through :func:`paged_write`, the width-1 step
+inside :func:`paged_decode_fused`); visibility is
 by absolute position — key position ``p`` is visible to query ``i``
 iff ``p <= lengths[b] + i`` — so garbage beyond the cursor (freed
 pages, pad-token writes) is never read.  Physical block 0 is the
@@ -98,6 +100,18 @@ conventions:
   einsum — bit-comparable to the dense cache's attention in
   ``generate()``.
 
+**The chunk write** (:func:`paged_write`, PERF.md §6, PR 36): a step
+wider than one token — a mixed step's prefill chunks, a speculative
+verify's draft runs — puts its ``(b, s, kv_heads, d)`` rows into the
+pool before the attend.  As an XLA scatter that write made the
+compiler transpose each pool into the scatter's layout and back, four
+pool-sized copies a layer whatever the rows touched; the Pallas kernel
+fetches the at most ``(s - 1) // block_size + 2`` pages a row touches,
+places the new lanes by a sublane rotate and a masked select and sends
+the pages home through outputs aliased to the pools — its cost is its
+rows', and the pool never moves.  Off a TPU the op IS the scatter
+(:func:`paged_write_reference`).
+
 The *block size itself* is the tunable (the analogue of the row-wise
 kernels' block-rows): sweep it offline with
 ``apex_tpu.ops.autotune.tune_paged_attention`` and the serving engine
@@ -138,7 +152,8 @@ from apex_tpu.ops._dispatch import resolve_impl
 
 __all__ = ["paged_attention", "paged_attention_reference",
            "paged_decode_fused", "paged_decode_fused_reference",
-           "rope_rows", "kv_quant_spec", "kv_store_bytes_per_token",
+           "paged_write", "paged_write_reference", "rope_rows",
+           "kv_quant_spec", "kv_store_bytes_per_token",
            "quantize_kv", "quantize_kv_pages", "tp_head_shards"]
 
 _NEG_INF = -1e30
@@ -701,7 +716,7 @@ def paged_decode_fused_reference(q, k_new, v_new, k_pages, v_pages,
     if s != 1:
         raise ValueError(
             f"paged_decode_fused is the WIDTH-1 decode fusion (chunk "
-            f"and verify steps keep the one-pass XLA scatter), got "
+            f"and verify steps write through paged_write), got "
             f"s={s}")
     hk, NB, BS, _ = k_pages.shape
     MB = block_tables.shape[1]
@@ -1077,9 +1092,9 @@ def paged_decode_fused(q, k_new, v_new, k_pages, v_pages, block_tables,
     Pallas kernel, so the row is rotated, coded and written
     in-register on its way into the attend (ISSUE 14's second fusion
     front).  Strictly the WIDTH-1 step: chunked prefill and the
-    speculative verify keep the one-pass XLA scatter (a chunk's rows
-    straddle pages, and only the decode row's write page is always
-    the sweep's last).
+    speculative verify write through :func:`paged_write` before their
+    attend (a chunk's rows straddle pages, and only the decode row's
+    write page is always the sweep's last).
 
     ``q`` (b, 1, h, d) and ``k_new``/``v_new`` (b, 1, hk, d) arrive
     UNROTATED; ``cos_b``/``sin_b`` (b, 1, 1, rot/2) are the per-row
@@ -1157,6 +1172,224 @@ def paged_decode_fused(q, k_new, v_new, k_pages, v_pages, block_tables,
         jnp.asarray(lengths, jnp.int32), int(max_seq_len), cos_b,
         sin_b, scale, impl == "pallas_interpret",
         k_scales=k_scales, v_scales=v_scales, chunk_lens=chunk_lens)
+
+
+# --------------------------------------------------------------------- #
+# chunk write — a width > 1 step's K/V rows into their pages, in place
+# --------------------------------------------------------------------- #
+def paged_write_reference(k, v, k_pages, v_pages, phys, off):
+    """The one-pass XLA scatter of a chunk's rows into the pool —
+    golden semantics of :func:`paged_write` and its CPU/GPU dispatch
+    target (``models/transformer.py``'s historical width > 1 write,
+    verbatim).  On a TPU XLA transposes each pool into the scatter's
+    layout and back around it, four pool-sized copies a layer
+    whatever the rows touch (PERF.md §6, PR 36): the reason the kernel
+    exists."""
+    kT = k.transpose(2, 0, 1, 3)                         # (hk, b, s, d)
+    vT = v.transpose(2, 0, 1, 3)
+    return (k_pages.at[:, phys, off].set(kT),
+            v_pages.at[:, phys, off].set(vT))
+
+
+#: fast memory the write kernel may hold (page buffers, the rotated
+#: rows, the double-buffered blocks of new rows); a wider chunk takes
+#: the scatter
+_WRITE_VMEM_BYTES = 8 * 1024 * 1024
+
+
+def _write_pages(s, bs):
+    """Pages a row's ``s`` consecutive positions can touch, the first
+    one anywhere in its page."""
+    return (s - 1) // bs + 2
+
+
+def _write_vmem_bytes(s, hk, bs, d, dtype):
+    rows = _write_pages(s, bs) * bs
+    return 2 * hk * d * (rows * (jnp.dtype(dtype).itemsize + 4)
+                         + 2 * s * jnp.dtype(dtype).itemsize)
+
+
+def _paged_write_kernel(pid_ref, shift_ref, put_ref, nk_ref, nv_ref,
+                        k_hbm, v_hbm, kp_out, vp_out, kbuf, vbuf, kmov,
+                        vmov, sems, *, bs, s):
+    """One row of the chunk write: each page its lanes touch — at most
+    :func:`_write_pages`, ``pid_ref[row, p]``, 0 where slot ``p`` has
+    nothing to write — comes in by one DMA a pool (all kv heads),
+    takes the new lanes by a masked select and goes home by one DMA a
+    pool through the aliased output.  Nothing else of the pool moves.
+
+    The new rows arrive ``(hk, s, d)``; rotated down by the first
+    lane's offset in its page (``shift_ref[row]``) inside a buffer of
+    all the slots' rows, lane ``i`` sits at buffer row ``shift + i``,
+    its place in the row's consecutive pages (a sublane rotate in 32
+    bits — exact for every pool dtype; the compiler has no dynamic
+    in-register slice).  ``put_ref`` marks the buffer rows that take a
+    lane.
+
+    The body is three short loops over the page slots (start the
+    fetches; wait, patch and send home; wait) and one rotate a pool:
+    what a server's start pays to lower it is a few tens of
+    milliseconds (PERF.md §6, PR 36), whatever ``s`` and the head
+    count."""
+    pages = kbuf.shape[0]
+    row = pl.program_id(0)
+
+    def each_page(fn):
+        def body(p, carry):
+            pid = pid_ref[row, p]
+            pl.when(pid != 0)(lambda: fn(p, pid))
+            return carry
+
+        jax.lax.fori_loop(0, pages, body, None)
+
+    def fetches(p, pid):
+        return (pltpu.make_async_copy(k_hbm.at[:, pid], kbuf.at[p],
+                                      sems.at[0, p]),
+                pltpu.make_async_copy(v_hbm.at[:, pid], vbuf.at[p],
+                                      sems.at[1, p]))
+
+    def homes(p, pid):
+        return (pltpu.make_async_copy(kbuf.at[p], kp_out.at[:, pid],
+                                      sems.at[0, p]),
+                pltpu.make_async_copy(vbuf.at[p], vp_out.at[:, pid],
+                                      sems.at[1, p]))
+
+    def start_fetch(p, pid):
+        for cp in fetches(p, pid):
+            cp.start()
+
+    def patch(p, pid):
+        for cp in fetches(p, pid):
+            cp.wait()
+        rows = pl.ds(pl.multiple_of(p * bs, bs), bs)
+        put = put_ref[0, rows] != 0                      # (bs, 1)
+        for buf, mov in ((kbuf, kmov), (vbuf, vmov)):
+            buf[p] = jnp.where(put, mov[:, rows],
+                               buf[p].astype(jnp.float32)
+                               ).astype(buf.dtype)
+        for cp in homes(p, pid):
+            cp.start()
+
+    def landed(p, pid):
+        for cp in homes(p, pid):
+            cp.wait()
+
+    each_page(start_fetch)
+    shift = shift_ref[row]
+    for new_ref, mov in ((nk_ref, kmov), (nv_ref, vmov)):
+        # rows past the chunk's width hold whatever the buffer held:
+        # put_ref never selects them
+        mov[:, :s] = new_ref[0].astype(jnp.float32)
+        mov[...] = pltpu.roll(mov[...], shift, 1)
+    each_page(patch)
+    each_page(landed)
+
+
+def _run_paged_write(k, v, k_pages, v_pages, phys, off, interpret):
+    b, s, hk, d = k.shape
+    bs = k_pages.shape[2]
+    pages = _write_pages(s, bs)
+    rows = pages * bs
+    # buffer row r of a row's consecutive pages takes lane r - shift;
+    # the page id it carries there, 0 where no lane lands or the lane
+    # is routed to the null page
+    shift = off[:, 0]
+    lane = jnp.arange(rows, dtype=jnp.int32)[None, :] - shift[:, None]
+    at = jnp.where(
+        (lane >= 0) & (lane < s),
+        jnp.take_along_axis(phys, jnp.clip(lane, 0, s - 1), axis=1), 0)
+    pid = at.reshape(b, pages, bs).max(axis=2)           # (b, pages)
+    put = (at != 0).astype(jnp.int32)[:, :, None]        # (b, rows, 1)
+    pool_spec = pl.BlockSpec(memory_space=pl.ANY)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b,),
+        in_specs=[_row_spec(rows, 1), _row_spec(hk, s, d),
+                  _row_spec(hk, s, d), pool_spec, pool_spec],
+        out_specs=[pool_spec, pool_spec],
+        scratch_shapes=[
+            pltpu.VMEM((pages, hk, bs, d), k_pages.dtype),
+            pltpu.VMEM((pages, hk, bs, d), v_pages.dtype),
+            pltpu.VMEM((hk, rows, d), jnp.float32),      # rotated K rows
+            pltpu.VMEM((hk, rows, d), jnp.float32),      # rotated V rows
+            pltpu.SemaphoreType.DMA((2, pages)),
+        ],
+    )
+    with jax.named_scope("paged_write"):
+        return pl.pallas_call(
+            functools.partial(_paged_write_kernel, bs=bs, s=s),
+            grid_spec=grid_spec,
+            out_shape=[
+                jax.ShapeDtypeStruct(k_pages.shape, k_pages.dtype),
+                jax.ShapeDtypeStruct(v_pages.shape, v_pages.dtype)],
+            # inputs count scalar prefetch first: 2 scalars, the mask
+            # (2), the new rows (3, 4), then the pools (5, 6), aliased
+            # to the outputs so that only the touched pages move
+            input_output_aliases={5: 0, 6: 1},
+            interpret=interpret,
+        )(pid, shift, put, k.transpose(0, 2, 1, 3),
+          v.transpose(0, 2, 1, 3), k_pages, v_pages)
+
+
+def paged_write(k, v, k_pages, v_pages, phys, off, *,
+                implementation: Optional[str] = None,
+                mesh=None, shard_axis: Optional[str] = None):
+    """Write a chunk's K/V rows into the paged pool, in place: lane
+    ``i`` of row ``r`` goes to page ``phys[r, i]``, offset ``off[r,
+    i]``.  The write of every step wider than one token — the mixed
+    step's prefill chunks and the speculative verify's draft runs (the
+    width-1 step writes inside :func:`paged_decode_fused`).
+
+    ``k``/``v`` are ``(b, s, kv_heads, d)``, already rotated and in
+    the pool's dtype (a coded pool's caller quantizes first: the op
+    moves bits).  ``phys``/``off`` are ``(b, s)`` int32 as the model
+    computes them: a row's lanes sit at CONSECUTIVE positions (``off[r,
+    i] == (off[r, 0] + i) % block_size``, the lanes of one page of the
+    row carrying one id), and a lane routed to the null page 0 — a
+    pad lane, a position past the cache — is dropped, the null page's
+    content being garbage by contract on every path.  Returns
+    ``(k_pages, v_pages)``: every live page bit for bit the scatter's,
+    every other page byte-preserved.
+
+    The Pallas kernel moves only the pages the rows touch, through
+    outputs aliased to the pools; the XLA composition is the one-pass
+    scatter (:func:`paged_write_reference`), which on a TPU costs four
+    pool-sized transposed copies a layer.  Dispatch per
+    :mod:`apex_tpu.ops._dispatch`; outside the kernel's envelope are
+    a block size or head width off the 8-row tile, rows not in the
+    pool's dtype, a chunk too wide for fast memory, and a
+    tensor-parallel pool (``mesh``/``shard_axis``, as
+    :func:`paged_attention` takes them), whose scatter the partitioner
+    keeps shard-local.
+    """
+    if k.shape != v.shape or k_pages.shape != v_pages.shape:
+        raise ValueError(
+            f"k/v shapes differ: new {k.shape} vs {v.shape}, pages "
+            f"{k_pages.shape} vs {v_pages.shape}")
+    b, s, hk, d = k.shape
+    hkp, _nb, bs, dp = k_pages.shape
+    if (hkp, dp) != (hk, d):
+        raise ValueError(
+            f"new rows {k.shape} do not match pages {k_pages.shape} "
+            f"in (kv_heads, head_dim)")
+    if phys.shape != (b, s) or off.shape != (b, s):
+        raise ValueError(
+            f"phys {phys.shape} / off {off.shape} are not (b, s) = "
+            f"{(b, s)}")
+    sharded = (shard_axis is not None and mesh is not None
+               and mesh.shape.get(shard_axis, 1) > 1)
+    pallas_ok = (not sharded and bs % 8 == 0 and d % 8 == 0
+                 and k.dtype == v.dtype == k_pages.dtype == v_pages.dtype
+                 and _write_vmem_bytes(s, hk, bs, d, k_pages.dtype)
+                 <= _WRITE_VMEM_BYTES)
+    impl = resolve_impl(implementation, pallas_ok=pallas_ok,
+                        op="paged_write")
+    if impl == "xla":
+        return paged_write_reference(k, v, k_pages, v_pages, phys, off)
+    return _run_paged_write(k, v, k_pages, v_pages,
+                            jnp.asarray(phys, jnp.int32),
+                            jnp.asarray(off, jnp.int32),
+                            impl == "pallas_interpret")
 
 
 # --------------------------------------------------------------------- #

@@ -786,6 +786,7 @@ class InferenceServer:
             "shared_blocks": engine.shared_blocks,
             "cow_forks": engine.cow_forks,
             "kv_pages_live": engine.kv_pages_live,
+            "kv_write_pages": engine.kv_write_pages,
             "kv_dtype": engine.kv_dtype,
             "kv_bits": engine.kv_bits,
         }

@@ -584,6 +584,11 @@ class PagedEngine:
         #: pages the attention kernels' sweeps had to visit, summed
         #: over steps and rows (an empty row sweeps the null page)
         self.kv_pages_live = 0
+        #: pages the chunk write of a step wider than one token
+        #: touches: the pages spanned by [cursor, cursor + n_tokens)
+        #: of every live row (a width-1 step writes inside its
+        #: attention kernel and counts none)
+        self.kv_write_pages = 0
         self._headroom = (2 * self.block_size if admit_headroom is None
                           else int(admit_headroom))
         self._variables = dict(params)
@@ -1148,6 +1153,14 @@ class PagedEngine:
                 self._install_admissions()
                 self.kv_pages_live += int(
                     (self._cursors // self.block_size + 1).sum())
+                if w > 1:
+                    live = [slot for slot, rec in enumerate(self._tenants)
+                            if rec is not None]
+                    first = self._cursors[live]
+                    last = first + n_tokens[live] - 1
+                    self.kv_write_pages += int(
+                        (last // self.block_size
+                         - first // self.block_size + 1).sum())
                 if any_spec:
                     self.cache, self.state, toks, n_emit, finished = \
                         self._spec(self._variables, self.cache,

@@ -29,7 +29,8 @@ from apex_tpu.ops import (fused_attention, fused_layer_norm,
                           fused_rms_norm)
 from apex_tpu.ops import fused_sampling as fs
 from apex_tpu.ops.paged_attention import (paged_attention,
-                                          paged_decode_fused)
+                                          paged_decode_fused,
+                                          paged_write)
 
 bf16 = jnp.bfloat16
 
@@ -167,6 +168,25 @@ def test_paged_decode_fused(compile_for_chip, rope, kv, pool_tokens):
     assert "tpu_custom_call" in compile_for_chip(fn, *shapes)
 
 
+def _write_shapes(b, hk, pool_tokens, s, dtype, bs=16):
+    nb = pool_tokens // bs + 1
+    return [((b, s, hk, D), dtype)] * 2 + [((hk, nb, bs, D), dtype)] * 2 \
+        + [((b, s), jnp.int32)] * 2
+
+
+def _write_in_place(k, v, kp, vp, phys, off):
+    return paged_write(k, v, kp, vp, phys, off, implementation="pallas")
+
+
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+@pytest.mark.parametrize("s", [32, 5])        # prefill chunk, verify
+def test_paged_write(compile_for_chip, s, kv):
+    text = compile_for_chip(_write_in_place, *_write_shapes(
+        B, HK, POOLS[-1], s, jnp.int8 if kv == "int8" else bf16))
+    assert "tpu_custom_call" in text
+    assert "paged_write" in text
+
+
 # ------------------- Falcon-H1-34B: 20 / 4 heads, the recurrent state
 FB, FH, FHK = 64, 20, 4               # slots; 5 query heads a KV head
 F_SEQ, F_POOL = 2048, 81920
@@ -186,6 +206,12 @@ def test_paged_attention_five_query_heads_a_kv_head(compile_for_chip, s):
 
     assert "tpu_custom_call" in compile_for_chip(
         fn, ((FB, s, FH, D), bf16), *_falcon_pool())
+
+
+def test_paged_write_at_the_falcon_cells_pool(compile_for_chip):
+    text = compile_for_chip(_write_in_place, *_write_shapes(
+        FB, FHK, F_POOL, 32, bf16))
+    assert "tpu_custom_call" in text
 
 
 def test_paged_decode_fused_five_query_heads_a_kv_head(compile_for_chip):
@@ -339,19 +365,18 @@ def test_serve_step_programs_copy_no_cache(topo, monkeypatch, family,
     moved = _moved(text, a_layer)
     if width == 1:
         assert not moved, moved
+        assert "paged_write" not in text
         cache_bytes = sum(a.size * a.dtype.itemsize
                           for a in jax.tree.leaves(engine.cache))
         temp = compiled.memory_analysis().temp_size_in_bytes
         # the scan's temporaries exceeded the cache they copied
         assert temp < cache_bytes / 2, (temp, cache_bytes)
     else:
-        # what is left to the next PR: the XLA scatter's two
-        # transposed copies a pool a layer (the write of width > 1)
-        copies = sum(n for (op, _), n in moved.items() if op == "copy")
-        assert copies <= 4 * layers, (
-            f"{copies} pool-shaped copies in a {layers}-layer mixed "
-            f"step (the scatter's: 4 a layer); all of pool size: "
-            f"{moved}")
+        # the chunk write moves the touched pages through its aliased
+        # pools (the XLA scatter it replaced transposed each pool and
+        # back, four pool-shaped copies a layer)
+        assert not moved, moved
+        assert text.count("paged_write") >= layers
 
 
 # --------------------------------------------------- fused sampling

@@ -26,7 +26,11 @@ Contracts under test:
 - the in-kernel page sweep (ISSUE 30): both kernels on cursors at
   every edge of the chunked sweep (page and chunk boundaries, a full
   table, at and past ``max_seq_len``), a result that does not depend
-  on the table's width, and a ``pallas_call`` grid with no page axis.
+  on the table's width, and a ``pallas_call`` grid with no page axis;
+- the chunk write (ISSUE 36): ``paged_write``'s kernel against the XLA
+  scatter it replaces, bit for bit on every live page, over pool
+  dtypes, widths, head counts, page-crossing and null-routed lanes,
+  in ONE aliased ``pallas_call`` with a body of a few dozen equations.
 """
 
 import numpy as np
@@ -795,6 +799,177 @@ class TestFusedDecodePrologue:
 # --------------------------------------------------------------------- #
 # the in-kernel page sweep (ISSUE 30) — chunk edges, table width, grid
 # --------------------------------------------------------------------- #
+_WRITE_DTYPES = [
+    "bfloat16", "float32", "int8",
+    pytest.param("float8_e4m3fn", marks=pytest.mark.skipif(
+        not hasattr(jnp, "float8_e4m3fn"),
+        reason="no float8_e4m3fn in this jax build")),
+]
+
+
+class TestChunkWrite:
+    """``paged_write``: a step wider than one token puts its K/V rows
+    into the pool by one Pallas call aliased to both pools.  Against
+    the XLA scatter it replaces (``paged_write_reference``, the
+    model's historical write verbatim) the interpret-mode kernel is
+    BITWISE equal on every page but the null page, whose content is
+    garbage by contract (the kernel drops the lanes routed there and
+    keeps its bytes); pages no lane touches keep theirs."""
+
+    @staticmethod
+    def _case(dtype, *, s, hk, cursors, n_tokens=None, BS=8, d=32,
+              S=64, seed=0):
+        """Pool, rows and the ``(phys, off)`` the model's branch
+        computes for rows at ``cursors`` (``n_tokens``: the coded
+        pool's real-lane counts, pad lanes routed to the null page);
+        each row owns the pages its real lanes need, the rest of its
+        table points at the null page."""
+        rng = np.random.default_rng(seed)
+        dtype = jnp.dtype(dtype)
+        cursors = np.asarray(cursors, np.int32)
+        b, MB = len(cursors), -(-S // BS)
+        NB = b * MB + 1
+
+        def vals(shape):
+            x = rng.normal(size=shape) * 20
+            if jnp.issubdtype(dtype, jnp.integer):
+                x = np.clip(np.round(x), -127, 127)
+            return jnp.asarray(x, dtype)
+
+        kp, vp = vals((hk, NB, BS, d)), vals((hk, NB, BS, d))
+        k, v = vals((b, s, hk, d)), vals((b, s, hk, d))
+        lens = (np.full((b,), s) if n_tokens is None
+                else np.asarray(n_tokens))
+        tables = np.zeros((b, MB), np.int32)
+        own = rng.permutation(np.arange(1, NB)).reshape(b, MB)
+        for r in range(b):
+            n = min(MB, -(-(int(cursors[r]) + int(lens[r])) // BS))
+            tables[r, :n] = own[r, :n]
+        pos = cursors[:, None] + np.arange(s)
+        phys = np.take_along_axis(
+            tables, np.minimum(pos // BS, MB - 1), axis=1)
+        phys = np.where(pos < S, phys, 0)
+        if n_tokens is not None:
+            phys = np.where(np.arange(s)[None] < lens[:, None], phys, 0)
+        return (k, v, kp, vp, jnp.asarray(phys, jnp.int32),
+                jnp.asarray(pos % BS, jnp.int32))
+
+    @staticmethod
+    def _bits(x):
+        x = np.asarray(x)
+        return x.view({1: np.uint8, 2: np.uint16,
+                       4: np.uint32}[x.dtype.itemsize])
+
+    def _check(self, args):
+        from apex_tpu.ops.paged_attention import (paged_write,
+                                                  paged_write_reference)
+
+        want = jax.jit(paged_write_reference)(*args)
+        got = jax.jit(lambda *a: paged_write(
+            *a, implementation="pallas_interpret"))(*args)
+        phys = np.asarray(args[4])
+        touched = np.unique(phys[phys > 0])
+        for g, w, old in zip(got, want, args[2:4]):
+            g, w, old = self._bits(g), self._bits(w), self._bits(old)
+            np.testing.assert_array_equal(g[:, 1:], w[:, 1:])
+            np.testing.assert_array_equal(g[:, 0], old[:, 0])
+            rest = np.setdiff1d(np.arange(1, g.shape[1]), touched)
+            np.testing.assert_array_equal(g[:, rest], old[:, rest])
+            if touched.size:
+                assert (g[:, touched] != old[:, touched]).any()
+
+    @pytest.mark.parametrize("hk", [8, 4])
+    @pytest.mark.parametrize("s", [32, 5])      # prefill chunk, verify
+    @pytest.mark.parametrize("dtype", _WRITE_DTYPES)
+    def test_kernel_is_the_scatter_bit_for_bit(self, dtype, s, hk):
+        """Rows at a page's start, mid-page, at a page's last offset,
+        and at the end of the cache; coded pools carry pad lanes."""
+        coded = jnp.dtype(dtype).itemsize == 1
+        self._check(self._case(
+            dtype, s=s, hk=hk, cursors=[0, 3, 15, 8, 30],
+            n_tokens=[s, 2, s - 1, 1, 3] if coded else None))
+
+    @pytest.mark.parametrize("case", [
+        # starts mid-page and crosses two page boundaries: 3 pages
+        dict(s=16, cursors=[5], BS=8),
+        dict(s=32, cursors=[11, 16, 1], BS=16, S=128),
+        # pad lanes (coded pool) and a cursor at max_seq_len - 1: the
+        # lanes past the cache go to the null page
+        dict(s=32, cursors=[63, 40], n_tokens=[1, 7]),
+        dict(s=5, cursors=[63, 62, 60]),
+        # a row with n_tokens = 0 writes nothing at all
+        dict(s=32, cursors=[9, 20, 0], n_tokens=[0, 32, 0]),
+        # an idle batch: no page moves
+        dict(s=5, cursors=[0, 0], n_tokens=[0, 0]),
+    ], ids=["two_boundaries", "two_boundaries_bs16", "pad_and_last",
+            "cursor_at_the_end", "empty_row", "all_empty"])
+    @pytest.mark.parametrize("dtype", ["bfloat16", "int8"])
+    def test_edges(self, dtype, case):
+        self._check(self._case(dtype, hk=4, **case))
+
+    def test_reference_is_the_models_scatter(self):
+        """The dispatch target off a TPU is the historical write,
+        verbatim: ``pool.at[:, phys, off].set(rows)``."""
+        from apex_tpu.ops.paged_attention import paged_write
+
+        k, v, kp, vp, phys, off = self._case(
+            "float32", s=5, hk=4, cursors=[0, 7, 62])
+        got = jax.jit(paged_write)(k, v, kp, vp, phys, off)  # auto: xla
+        np.testing.assert_array_equal(
+            np.asarray(got[0]),
+            np.asarray(kp.at[:, phys, off].set(k.transpose(2, 0, 1, 3))))
+        np.testing.assert_array_equal(
+            np.asarray(got[1]),
+            np.asarray(vp.at[:, phys, off].set(v.transpose(2, 0, 1, 3))))
+
+    def test_one_call_writes_both_pools_in_place(self):
+        """ONE ``pallas_call`` for K and V, the pools aliased to its
+        outputs, a grid step a row and no loop unrolled over lanes,
+        pages or heads (what a server's start pays to lower it)."""
+        from apex_tpu.ops.paged_attention import paged_write
+
+        args = self._case("bfloat16", s=32, hk=8, cursors=[3] * 6)
+        jaxpr = jax.make_jaxpr(lambda *a: paged_write(
+            *a, implementation="pallas_interpret"))(*args)
+        calls = [e for e in jaxpr.jaxpr.eqns
+                 if e.primitive.name == "pallas_call"]
+        assert len(calls) == 1
+        params = calls[0].params
+        assert params["grid_mapping"].grid == (6,)
+        assert dict(params["input_output_aliases"]) == {5: 0, 6: 1}
+        body = params["jaxpr"]
+        assert len(body.eqns) < 40, len(body.eqns)
+
+    def test_validation_and_envelope(self):
+        from apex_tpu.ops.paged_attention import paged_write
+
+        k, v, kp, vp, phys, off = self._case(
+            "bfloat16", s=5, hk=4, cursors=[0, 7])
+        with pytest.raises(ValueError, match="shapes differ"):
+            paged_write(k, v[:, :4], kp, vp, phys, off)
+        with pytest.raises(ValueError, match="do not match pages"):
+            paged_write(k[:, :, :2], v[:, :, :2], kp, vp, phys, off)
+        with pytest.raises(ValueError, match=r"not \(b, s\)"):
+            paged_write(k, v, kp, vp, phys[:, :3], off)
+        # rows not in the pool's dtype: outside the kernel's envelope
+        with pytest.raises(ValueError, match="outside its envelope"):
+            paged_write(k.astype(jnp.float32), v.astype(jnp.float32),
+                        kp, vp, phys, off,
+                        implementation="pallas_interpret")
+        # and so is a tensor-parallel pool: it keeps the scatter
+        mesh = jax.sharding.Mesh(np.array(jax.devices()[:2]),
+                                 ("tensor",))
+        with pytest.raises(ValueError, match="outside its envelope"):
+            paged_write(k, v, kp, vp, phys, off, mesh=mesh,
+                        shard_axis="tensor",
+                        implementation="pallas_interpret")
+        got = paged_write(k, v, kp, vp, phys, off, mesh=mesh,
+                          shard_axis="tensor")
+        np.testing.assert_array_equal(
+            self._bits(got[0]),
+            self._bits(kp.at[:, phys, off].set(k.transpose(2, 0, 1, 3))))
+
+
 def _chunk_positions(bs):
     from apex_tpu.ops.paged_attention import _chunk_pages
     return _chunk_pages(bs) * bs

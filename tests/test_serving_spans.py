@@ -14,6 +14,9 @@ Contracts under test:
   order;
 - ``health()`` stays safe from another thread while the worker steps;
 - a guarded program is named by its guard, a kernel by its scope;
+- ``kv_write_pages`` counts the pages a mixed step's chunk write
+  touches and nothing for a width-1 step, and warm-up traces the same
+  executables as before the chunk write was a kernel (ISSUE 36);
 - ``docs/serving.md`` and ``PERF.md`` spell every name as the code does.
 """
 
@@ -51,7 +54,7 @@ ENGINE_PARTS = (engine_mod.PLAN, engine_mod.DISPATCH, engine_mod.FETCH,
 SPANS = ((api.SERVE_STEP, api.DELIVER, scheduler.ADMIT, scheduler.ROUTE)
          + ENGINE_STEPS + ENGINE_PARTS)
 FIELDS = ("spans", "admitted", "queue_wait_s", "first_tokens",
-          "prefill_s", "compiles")
+          "prefill_s", "compiles", "kv_write_pages")
 
 
 @pytest.fixture(scope="module")
@@ -285,6 +288,54 @@ def test_trace_holds_the_engine_spans_nested_on_one_line(gpt, tmp_path):
     ids = {k: str(v) for k, v in admit[0][3].items()}
     assert ids.get("uid") == str(req.uid)
     assert ids.get("prompt_len") == "9"
+
+
+# ----------------------------------------------------- the chunk write
+def test_a_mixed_step_counts_the_pages_its_write_touches(gpt):
+    """``kv_write_pages``: pages spanned by ``[cursor, cursor +
+    n_tokens)`` of every live row of a step wider than one token; a
+    width-1 step writes inside its attention kernel and counts none."""
+    model, params = gpt
+    engine = PagedEngine(model, params, max_slots=3, block_size=8,
+                         prefill_chunk=16, pool_tokens=256)
+    engine.admit(0, np.arange(1, 14, dtype=np.int32), max_new_tokens=8)
+    engine.step()           # 13 lanes from position 0: pages 0 and 1
+    assert engine.kv_write_pages == 2
+    engine.step()           # width 1
+    engine.step()
+    assert engine.kv_write_pages == 2
+    # a second tenant's 21-token prompt rides two mixed steps beside
+    # the first one's decode lane (one page each step)
+    engine.admit(1, np.arange(1, 22, dtype=np.int32), max_new_tokens=2)
+    engine.step()           # row 0: 1 page; row 1: positions 0..15, 2
+    assert engine.kv_write_pages == 2 + 1 + 2
+    engine.step()           # row 0: 1 page; row 1: positions 16..20, 1
+    assert engine.kv_write_pages == 5 + 1 + 1
+    before = engine.kv_write_pages
+    engine.step()           # width 1 again
+    assert engine.kv_write_pages == before
+    assert engine.kv_pages_live > 0
+
+
+@pytest.mark.parametrize("spec_tokens", [0, 2])
+def test_warmup_traces_the_same_executables_as_ever(gpt, spec_tokens):
+    """The chunk write rides the mixed and the drafted step: warm-up
+    traces each guarded program once, and the engine holds no guarded
+    program beside the five."""
+    model, params = gpt
+    engine = PagedEngine(model, params, max_slots=2, block_size=8,
+                         prefill_chunk=4, pool_tokens=64,
+                         spec_tokens=spec_tokens)
+    engine.warmup()
+    want = {"decode_step": 1, "prefill_step": 1, "admit": 1,
+            "release": 1}
+    if spec_tokens:
+        want["spec_step"] = 1
+    assert engine.trace_counts == want
+    guarded = {name for name, held in vars(engine).items()
+               if hasattr(held, "trace_count")}
+    assert guarded == {"_decode", "_prefill", "_spec", "_admit",
+                       "_release"}
 
 
 # -------------------------------------------------------------- names
