@@ -17,6 +17,7 @@ from apex_tpu.models.gpt import (GPTConfig, GPTModel, gpt_loss_fn,
                                  moe_aux_loss)
 from apex_tpu.models.llama import LlamaConfig, LlamaModel
 from apex_tpu.models.falcon_h1 import FalconH1Config, FalconH1Model
+from apex_tpu.models.afmoe import AfmoeConfig, AfmoeModel
 from apex_tpu.models.bert import BertConfig, BertModel, bert_mlm_loss_fn
 from apex_tpu.models.resnet import ResNetConfig, ResNet, resnet50, resnet18
 from apex_tpu.models.vit import ViTConfig, ViTModel
@@ -37,6 +38,8 @@ __all__ = [
     "LlamaModel",
     "FalconH1Config",
     "FalconH1Model",
+    "AfmoeConfig",
+    "AfmoeModel",
     "BertConfig",
     "BertModel",
     "bert_mlm_loss_fn",

@@ -98,6 +98,17 @@ class TransformerConfig:
     # MLP.  1.0 leaves the classic recipes untouched.
     key_multiplier: float = 1.0
     mlp_gate_multiplier: float = 1.0
+    # what the afmoe family's attention adds (models/afmoe.py), stated
+    # like the fields above: a sigmoid gate on the heads' output,
+    # ``W_o (o * sigmoid(W_g x))`` with ``W_g`` hidden -> heads x
+    # head_dim and no bias, and an RMS norm over each query and key
+    # head's channels (one learned gain vector each, eps
+    # ``layernorm_eps``) before the rotation.  A stack whose layers
+    # differ in ``sliding_window`` / ``position_embedding`` gives each
+    # layer a config of its own (``dataclasses.replace``): neither
+    # field shapes a parameter.
+    attn_gate: bool = False
+    qk_norm: bool = False
     # Mixture-of-Experts FFN (Mixtral-style; beyond-reference — the
     # reference has no EP, SURVEY §2.6 checklist): replaces every
     # layer's dense MLP with `num_moe_experts` experts under top-k
@@ -204,6 +215,13 @@ class TransformerConfig:
     def ffn_size(self) -> int:
         return self.ffn_hidden_size or 4 * self.hidden_size
 
+    @property
+    def kv_window(self) -> Optional[int]:
+        """The width of the stack's window layers, for the serving
+        engine's page counters; ``None``: the stack has none (a family
+        whose layers differ overrides this)."""
+        return self.sliding_window
+
     def __post_init__(self):
         if self.kv_channels is not None:
             if self.kv_channels < 1:
@@ -244,11 +262,6 @@ class TransformerConfig:
             if not self.causal:
                 raise ValueError("kv_cache='paged' requires causal=True "
                                  "(it is a decode-cache layout)")
-            if self.sliding_window is not None:
-                raise ValueError(
-                    "kv_cache='paged' does not support sliding_window "
-                    "— the paged pool already bounds decode memory to "
-                    "live tokens; serve with sliding_window=None")
             if self.kv_block_size < 1:
                 raise ValueError(
                     f"kv_block_size must be >= 1, got "
@@ -572,7 +585,8 @@ class ParallelAttention(nn.Module):
                 k_scales=(ksc.value if store_dt is not None else None),
                 v_scales=(vsc.value if store_dt is not None else None),
                 chunk_lens=(cl.value if store_dt is not None else None),
-                mesh=cfg.kv_mesh, shard_axis=cfg.kv_shard_axis)
+                mesh=cfg.kv_mesh, shard_axis=cfg.kv_shard_axis,
+                window=cfg.sliding_window)
             if store_dt is None:
                 o, kp_new, vp_new = outs
             else:
@@ -608,7 +622,8 @@ class ParallelAttention(nn.Module):
             return paged_attention(q, pk.value, pv.value, bt.value,
                                    cur.value, scale=d ** -0.5,
                                    mesh=cfg.kv_mesh,
-                                   shard_axis=cfg.kv_shard_axis)
+                                   shard_axis=cfg.kv_shard_axis,
+                                   window=cfg.sliding_window)
         # quantize-on-write (chunked prefill and decode scatter are
         # this one path).  Scale discipline per (kv_head, page):
         # - RESET at a page's first write: pages always begin life at
@@ -671,7 +686,8 @@ class ParallelAttention(nn.Module):
                                cur.value, scale=d ** -0.5,
                                k_scales=ks_new, v_scales=vs_new,
                                mesh=cfg.kv_mesh,
-                               shard_axis=cfg.kv_shard_axis)
+                               shard_axis=cfg.kv_shard_axis,
+                               window=cfg.sliding_window)
 
     @nn.compact
     def __call__(self, x, *, mask_bias=None, deterministic: bool = True,
@@ -705,6 +721,16 @@ class ParallelAttention(nn.Module):
             v = qkv[..., (h + hk) * d:].reshape(b, s, hk, d)
         if cfg.key_multiplier != 1.0:
             k = k * jnp.asarray(cfg.key_multiplier, k.dtype)
+        if cfg.qk_norm:
+            q = self._head_norm("q_norm", q)
+            k = self._head_norm("k_norm", k)
+        gate = None
+        if cfg.attn_gate:
+            gate = ColumnParallelLinear(
+                features=h * d, use_bias=False,
+                sequence_parallel=cfg.sequence_parallel,
+                dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+                name="gate_proj")(x)
         rot = int(cfg.rotary_pct * d) // 2 * 2
         if decode:
             if not cfg.causal:
@@ -727,7 +753,8 @@ class ParallelAttention(nn.Module):
                     use_bias=cfg.add_bias_linear,
                     sequence_parallel=cfg.sequence_parallel,
                     dtype=cfg.dtype, param_dtype=cfg.param_dtype,
-                    name="out_proj")(o.reshape(b, s, h * d))
+                    name="out_proj")(
+                        self._gated(o.reshape(b, s, h * d), gate))
             S = cfg.max_seq_len
             # rolling ring-buffer cache (Mistral design): with a
             # sliding window only the last `window` keys are ever
@@ -867,12 +894,32 @@ class ParallelAttention(nn.Module):
         # kernel's own output/lse residuals — named inside the kernel's
         # fwd rule (ops/attention.py), not here: a second layer-level
         # tag with the same name would store the attention output twice
-        o = o.reshape(b, s, h * d)
+        o = self._gated(o.reshape(b, s, h * d), gate)
         return RowParallelLinear(
             features=cfg.hidden_size, use_bias=cfg.add_bias_linear,
             sequence_parallel=cfg.sequence_parallel,
             dtype=cfg.dtype, param_dtype=cfg.param_dtype,
             name="out_proj")(o)
+
+    def _head_norm(self, name, x):
+        """RMS norm of every head of ``x`` (b, s, heads, d) over its
+        ``d`` channels, one learned gain vector for all heads
+        (``cfg.qk_norm``)."""
+        cfg = self.cfg
+        w = self.param(name, nn.initializers.ones_init(),
+                       (cfg.head_dim,), cfg.param_dtype)
+        xf = x.astype(jnp.float32)
+        xf = xf * jax.lax.rsqrt(
+            jnp.mean(xf * xf, axis=-1, keepdims=True) + cfg.layernorm_eps)
+        return (xf * w.astype(jnp.float32)).astype(x.dtype)
+
+    @staticmethod
+    def _gated(o, gate):
+        """The heads' output under the sigmoid gate (``cfg.attn_gate``);
+        ``gate`` None leaves it as it is."""
+        if gate is None:
+            return o
+        return o * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(o.dtype)
 
 
 class ParallelMLP(nn.Module):
@@ -988,8 +1035,9 @@ def parameters_only(scanned):
                             trans_out_fn=lambda _: {})
 
 
-def decode_layers(stack: nn.Module, layer: nn.Module, num_layers: int,
-                  x, **kwargs):
+def decode_layers(stack: nn.Module, layer, num_layers: int, x, *,
+                  scope: str = "layers", first: int = 0,
+                  decode: bool = True, layer_args=None, **kwargs):
     """The layers of a scanned stack under ``decode=True``, WITHOUT a
     scan over the cache.
 
@@ -1013,32 +1061,60 @@ def decode_layers(stack: nn.Module, layer: nn.Module, num_layers: int,
     annotations see one tree whatever ``decode`` is); their static
     slices fuse into the GEMMs that read them.  What grows is the
     program: a decode step compiles in time linear in depth.
+
+    A model whose layers are of more than one kind calls this once a
+    parameter stack (``scope`` names the stack's child; ``first`` is
+    the index of its first layer in the model, which names the cache
+    subtrees ``layer_{first + i}``) and may hand ``layer`` as a
+    sequence, one unbound module a layer: modules that differ in
+    static attributes only (a window, a positional scheme) slice the
+    same stack, and layers that share a module share its trace.  Such
+    a model has no scan to fall back on for its full-sequence forward
+    and walks the same loop with ``decode=False``: no cache is read or
+    kept.  ``layer_args``: one tuple of further positional arguments a
+    layer, traced — what a layer reads that is NOT sliced from the
+    stack (a bank of weights that a kernel takes whole: a slice that
+    feeds a ``pallas_call`` is a copy, a slice that feeds a GEMM fuses
+    into it).
     """
-    stacked = stack.get_variable("params", "layers")["layer"]
+    stacked = stack.get_variable("params", scope)["layer"]
+    layers = (list(layer) if isinstance(layer, (list, tuple))
+              else [layer] * num_layers)
     key = stack.make_rng("dropout") if stack.has_rng("dropout") else None
 
     # one trace and one lowered function for all the layers: their
     # shapes are the same, and XLA inlines the calls
-    @jax.jit
-    def apply_layer(variables, x, key):
-        return layer.apply(
-            variables, x, decode=True, mutable=["cache"],
-            rngs=None if key is None else {"dropout": key}, **kwargs)
+    def applier(module):
+        @jax.jit
+        def apply_layer(variables, x, key, args):
+            rngs = None if key is None else {"dropout": key}
+            if not decode:
+                return module.apply(variables, x, *args, rngs=rngs,
+                                    **kwargs), None
+            return module.apply(variables, x, *args, decode=True,
+                                mutable=["cache"], rngs=rngs, **kwargs)
+        return apply_layer
 
+    appliers = {}
     for i in range(num_layers):
+        if id(layers[i]) not in appliers:
+            appliers[id(layers[i])] = applier(layers[i])
+        apply_layer = appliers[id(layers[i])]
         # the slice keeps a Partitioned box; its names drop the layer
         # axis, as nn.scan's metadata_params does on the way in
         params = nn.meta.remove_axis(
             jax.tree.map(lambda a: a[i], stacked), 0,
             {nn.PARTITION_NAME: None})
-        name = f"layer_{i}"
+        name = f"layer_{first + i}"
         variables = {"params": params}
-        if stack.has_variable("cache", name):
+        if decode and stack.has_variable("cache", name):
             variables["cache"] = stack.get_variable("cache", name)
         x, updated = apply_layer(
             variables, x,
-            None if key is None else jax.random.fold_in(key, i))
-        stack.put_variable("cache", name, updated["cache"])
+            None if key is None else jax.random.fold_in(key, i),
+            () if layer_args is None else layer_args[i])
+        if decode:
+            stack.put_variable("cache", name, updated["cache"])
     return x
 
 

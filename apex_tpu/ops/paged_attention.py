@@ -37,6 +37,17 @@ pages, pad-token writes) is never read.  Physical block 0 is the
 engine's **null page** (pad writes land there); the mask makes its
 contents unreachable, so the op needs no special case for it.
 
+**Sliding window (``window=``)**: a windowed layer's query at position
+``p`` sees keys ``p - window < j <= p`` only.  Every read of the pool
+takes ``window=None | int``: the references mask the positions before
+a query's window, and the kernels' sweep STARTS at the chunk that holds
+position ``max(0, lengths[b] - window + 1)`` — the window's start of the
+row's first query lane — so a windowed row costs what its window holds,
+not what its context holds; later lanes mask the positions before
+their own start.  The pages behind the window stay allocated (the
+engine's to release, ROADMAP M1); they are no longer read.
+``window=None`` is the program it always was.
+
 **Multi-query verify (speculative decoding)**: the same ``s > 1``
 chunk path scores a draft run ``[current, d_1..d_k]`` in one
 application — query ``i`` sits at ``lengths[b] + i`` and sees exactly
@@ -304,7 +315,8 @@ def tp_head_shards(num_heads: int, kv_heads: int, tp: int):
 
 
 def _run_sharded(q, k_pages, v_pages, tables, lengths, scale,
-                 implementation, k_scales, v_scales, mesh, axis):
+                 implementation, k_scales, v_scales, mesh, axis,
+                 window=None):
     """shard_map wrapper: each chip runs the unsharded op on its
     kv-head slice (pool + scales sharded on axis 0, q on its head
     axis, tables/lengths replicated — no collective in here)."""
@@ -325,10 +337,15 @@ def _run_sharded(q, k_pages, v_pages, tables, lengths, scale,
         ks, vs = scales if scales else (None, None)
         return paged_attention(q, kp, vp, bt, ln, scale=scale,
                                implementation=implementation,
-                               k_scales=ks, v_scales=vs)
+                               k_scales=ks, v_scales=vs, window=window)
 
     return jax.shard_map(local, mesh=mesh, in_specs=tuple(in_specs),
                          out_specs=q_spec, check_vma=False)(*args)
+
+
+def _check_window(window) -> None:
+    if window is not None and int(window) < 1:
+        raise ValueError(f"window must be >= 1 or None, got {window}")
 
 
 def _is_quantized_pool(dtype) -> bool:
@@ -349,7 +366,8 @@ def _qmax_for_pool(dtype) -> float:
 # --------------------------------------------------------------------- #
 def paged_attention_reference(q, k_pages, v_pages, block_tables,
                               lengths, *, scale: Optional[float] = None,
-                              k_scales=None, v_scales=None):
+                              k_scales=None, v_scales=None,
+                              window: Optional[int] = None):
     """Gather-then-attend reference: softmax(q·K_gatheredᵀ·scale)·V.
 
     Shapes as in the module docstring.  The gather materializes each
@@ -357,7 +375,8 @@ def paged_attention_reference(q, k_pages, v_pages, block_tables,
     the Pallas kernel never does); masking is by absolute position, so
     pool garbage beyond ``lengths[b] + i`` is unreachable.  fp32
     softmax, output in ``q.dtype`` — the same numerics contract as the
-    dense cache's attention in ``generate()``.
+    dense cache's attention in ``generate()``.  With ``window`` a
+    query at position ``p`` sees keys ``p - window < j <= p`` only.
 
     With quantized pages (``k_scales``/``v_scales`` given, one fp32
     amax per (kv_head, pool block)), the GATHERED pages are dequantized
@@ -391,6 +410,8 @@ def paged_attention_reference(q, k_pages, v_pages, block_tables,
     pos_q = lengths[:, None] + jnp.arange(s, dtype=jnp.int32)  # (b, s)
     k_pos = jnp.arange(mb * bs, dtype=jnp.int32)
     visible = k_pos[None, None, :] <= pos_q[:, :, None]        # (b, s, K)
+    if window is not None:
+        visible &= k_pos[None, None, :] > pos_q[:, :, None] - window
     scores = jnp.where(visible[:, :, None, None, :], scores, _NEG_INF)
     p = jax.nn.softmax(scores, axis=-1)
     o = jnp.einsum("bsgrk,bkgd->bsgrd", p, vals.astype(jnp.float32))
@@ -461,7 +482,7 @@ def _sweep_scratch(hk, bs, d, lanes, pool_dtype):
 
 def _sweep_row(tables_ref, row, length, k_hbm, v_hbm, scratch, o_ref, *,
                s, bs, rep, mb, q_tile, qmax=None, page_scales=None,
-               on_last_chunk=None):
+               on_last_chunk=None, window=None):
     """One row's online-softmax sweep over its LIVE pages — the one
     body behind both kernels (the masking/softmax algebra must never
     fork).
@@ -497,6 +518,16 @@ def _sweep_row(tables_ref, row, length, k_hbm, v_hbm, scratch, o_ref, *,
     ``on_last_chunk(slot)`` runs once, after the last chunk has landed
     in ``slot`` and before it is attended — the fused kernel's
     prologue hook.
+
+    With ``window`` the sweep starts at the chunk that holds position
+    ``max(0, length - window + 1)``, the window's start of the row's
+    FIRST query lane (no lane's window starts earlier), and every lane
+    masks the positions before its own start.  A later lane may find
+    no live key in the first chunk or two: its statistics stay at the
+    floor there and what it summed meanwhile is zeroed — explicitly,
+    the floor minus the floor being 0 and not minus infinity.
+    ``window=None`` is the sweep it always was, instruction for
+    instruction.
     """
     kbuf, vbuf, sems, m_ref, l_ref, acc_ref = scratch
     _slots, hk, t, _d = kbuf.shape
@@ -504,6 +535,10 @@ def _sweep_row(tables_ref, row, length, k_hbm, v_hbm, scratch, o_ref, *,
     lanes = rep * s
     live = _live_pages(length, s, bs, mb)
     n_chunks = (live + c_pages - 1) // c_pages
+    # the first chunk a query of the row can see into: a static 0
+    # without a window
+    first = 0 if window is None else jnp.minimum(
+        jnp.maximum(length - window + 1, 0) // t, n_chunks - 1)
 
     def copies(c, slot):
         out = []
@@ -518,7 +553,7 @@ def _sweep_row(tables_ref, row, length, k_hbm, v_hbm, scratch, o_ref, *,
                 sems.at[1, slot]))
         return out
 
-    for cp in copies(0, 0):
+    for cp in copies(first, first % 2):
         cp.start()
     m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
     l_ref[...] = jnp.zeros_like(l_ref)
@@ -545,6 +580,8 @@ def _sweep_row(tables_ref, row, length, k_hbm, v_hbm, scratch, o_ref, *,
         # table (the last chunk's spare slots re-fetch the last live
         # page: a cursor at or past the table's end must not see them)
         dead = k_pos > jnp.minimum(length + q_off, mb * bs - 1)
+        if window is not None:
+            dead |= k_pos <= length + q_off - window
         for head in range(hk):
             qs = q_tile(head)
             kt = kbuf[slot, head]
@@ -572,6 +609,10 @@ def _sweep_row(tables_ref, row, length, k_hbm, v_hbm, scratch, o_ref, *,
             # positions — no explicit dead-row zeroing needed (see
             # ops/attention.py)
             p = jnp.exp2(sc - m_new)
+            if window is not None:
+                # a lane whose window starts in a later chunk has seen
+                # no live key yet: m is still the floor and exp2(0) = 1
+                p = jnp.where(dead, 0.0, p)
             alpha = jnp.exp2(m_prev - m_new)
             l_ref[head] = l_ref[head] * alpha + jnp.sum(
                 p, axis=0, keepdims=True)
@@ -586,7 +627,7 @@ def _sweep_row(tables_ref, row, length, k_hbm, v_hbm, scratch, o_ref, *,
             m_ref[head] = m_new
         return carry
 
-    jax.lax.fori_loop(0, n_chunks, chunk, None)
+    jax.lax.fori_loop(first, n_chunks, chunk, None)
 
     for head in range(hk):
         l = l_ref[head]
@@ -596,7 +637,7 @@ def _sweep_row(tables_ref, row, length, k_hbm, v_hbm, scratch, o_ref, *,
 
 
 def _paged_kernel(tables_ref, lens_ref, q_ref, k_hbm, v_hbm, *refs,
-                  bs, s, rep, scale, mb, qmax=None):
+                  bs, s, rep, scale, mb, qmax=None, window=None):
     """One row of the chunk / verify / plain-decode attend: the shared
     sweep (:func:`_sweep_row`) over the row's live pages, all kv heads
     in one grid step.  With ``qmax`` set two extra refs —
@@ -619,11 +660,11 @@ def _paged_kernel(tables_ref, lens_ref, q_ref, k_hbm, v_hbm, *refs,
 
     _sweep_row(tables_ref, row, lens_ref[row], k_hbm, v_hbm, scratch,
                o_ref, s=s, bs=bs, rep=rep, mb=mb, q_tile=q_tile,
-               qmax=qmax, page_scales=page_scales)
+               qmax=qmax, page_scales=page_scales, window=window)
 
 
 def _run_paged(q4, k_pages, v_pages, tables, lengths, scale, interpret,
-               k_scales=None, v_scales=None):
+               k_scales=None, v_scales=None, window=None):
     b, s, h, d = q4.shape
     hk, _nb_pool, bs, _ = k_pages.shape
     rep = h // hk
@@ -641,7 +682,8 @@ def _run_paged(q4, k_pages, v_pages, tables, lengths, scale, interpret,
                  _row_page_scales(v_scales, tables)]
     kernel = functools.partial(
         _paged_kernel, bs=bs, s=s, rep=rep, scale=scale, mb=mb,
-        qmax=_qmax_for_pool(k_pages.dtype) if quantized else None)
+        qmax=_qmax_for_pool(k_pages.dtype) if quantized else None,
+        window=window)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b,),
@@ -692,7 +734,8 @@ def paged_decode_fused_reference(q, k_new, v_new, k_pages, v_pages,
                                  cos_b=None, sin_b=None,
                                  scale: Optional[float] = None,
                                  k_scales=None, v_scales=None,
-                                 chunk_lens=None):
+                                 chunk_lens=None,
+                                 window: Optional[int] = None):
     """The unfused decode-step prologue + attend, verbatim — golden
     semantics of :func:`paged_decode_fused` and its CPU/GPU dispatch
     target.
@@ -736,7 +779,7 @@ def paged_decode_fused_reference(q, k_new, v_new, k_pages, v_pages,
         kp = k_pages.at[:, phys, off].set(kT)
         vp = v_pages.at[:, phys, off].set(vT)
         o = paged_attention_reference(q, kp, vp, block_tables,
-                                      lengths, scale=scale)
+                                      lengths, scale=scale, window=window)
         return o, kp, vp
     qmax = _qmax_for_pool(k_pages.dtype)
     store_dt = k_pages.dtype
@@ -770,13 +813,14 @@ def paged_decode_fused_reference(q, k_new, v_new, k_pages, v_pages,
         quantize_kv(vT, vs_new[:, phys], qmax, store_dt))
     o = paged_attention_reference(q, kp, vp, block_tables, lengths,
                                   scale=scale, k_scales=ks_new,
-                                  v_scales=vs_new)
+                                  v_scales=vs_new, window=window)
     return o, kp, vp, ks_new, vs_new
 
 
 def _paged_fused_kernel(tables_ref, lens_ref, wphys_ref, woff_ref,
                         real_ref, q_ref, k_hbm, v_hbm, nk_ref, nv_ref,
-                        *refs, bs, rep, scale, mb, S, half, qmax=None):
+                        *refs, bs, rep, scale, mb, S, half, qmax=None,
+                        window=None):
     """The decode sweep of :func:`_paged_kernel` (s = 1) with the
     step's PROLOGUE folded in.  The row's write page IS the last live
     page of its sweep, so once the last chunk has landed each head
@@ -917,7 +961,8 @@ def _paged_fused_kernel(tables_ref, lens_ref, wphys_ref, woff_ref,
 
     _sweep_row(tables_ref, row, length, k_hbm, v_hbm, scratch, o_ref,
                s=1, bs=bs, rep=rep, mb=mb, q_tile=q_tile, qmax=qmax,
-               page_scales=page_scales, on_last_chunk=_prologue)
+               page_scales=page_scales, on_last_chunk=_prologue,
+               window=window)
 
     @pl.when(write_ok)
     def _landed():
@@ -927,7 +972,8 @@ def _paged_fused_kernel(tables_ref, lens_ref, wphys_ref, woff_ref,
 
 def _run_decode_fused(q4, k_new, v_new, k_pages, v_pages, tables,
                       lengths, S, cos_b, sin_b, scale, interpret,
-                      k_scales=None, v_scales=None, chunk_lens=None):
+                      k_scales=None, v_scales=None, chunk_lens=None,
+                      window=None):
     b, s, h, d = q4.shape
     hk, _nb_pool, bs, _ = k_pages.shape
     rep = h // hk
@@ -998,7 +1044,8 @@ def _run_decode_fused(q4, k_new, v_new, k_pages, v_pages, tables,
     kernel = functools.partial(
         _paged_fused_kernel, bs=bs, rep=rep, scale=scale, mb=mb,
         S=S, half=half,
-        qmax=_qmax_for_pool(k_pages.dtype) if quantized else None)
+        qmax=_qmax_for_pool(k_pages.dtype) if quantized else None,
+        window=window)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
         grid=(b,),
@@ -1034,7 +1081,7 @@ def _run_decode_fused(q4, k_new, v_new, k_pages, v_pages, tables,
 def _run_decode_fused_sharded(q, k_new, v_new, k_pages, v_pages,
                               tables, lengths, S, cos_b, sin_b, scale,
                               implementation, k_scales, v_scales,
-                              chunk_lens, mesh, axis):
+                              chunk_lens, mesh, axis, window=None):
     """shard_map wrapper for the fused decode step: pool, scales and
     the new K/V rows shard on their kv_heads axes, q on its head axis,
     everything host-authoritative replicated — the write is
@@ -1071,7 +1118,7 @@ def _run_decode_fused_sharded(q, k_new, v_new, k_pages, v_pages,
     def local(q, nk, nv, kp, vp, bt, ln, opt):
         return paged_decode_fused(
             q, nk, nv, kp, vp, bt, ln, max_seq_len=S, scale=scale,
-            implementation=implementation, **opt)
+            implementation=implementation, window=window, **opt)
 
     return jax.shard_map(local, mesh=mesh, in_specs=in_specs,
                          out_specs=out_specs, check_vma=False)(
@@ -1083,7 +1130,8 @@ def paged_decode_fused(q, k_new, v_new, k_pages, v_pages, block_tables,
                        sin_b=None, scale: Optional[float] = None,
                        implementation: Optional[str] = None,
                        k_scales=None, v_scales=None, chunk_lens=None,
-                       mesh=None, shard_axis: Optional[str] = None):
+                       mesh=None, shard_axis: Optional[str] = None,
+                       window: Optional[int] = None):
     """One fused decode step over the paged pool: per-row RoPE of
     ``q``/``k_new``, (quantized) write of the new K/V row into its
     page, and the block-table-gathered attend — the attention
@@ -1103,6 +1151,8 @@ def paged_decode_fused(q, k_new, v_new, k_pages, v_pages, block_tables,
     ``lengths[b]`` is both the mask horizon and the write position.
     Quantized pools add ``k_scales``/``v_scales`` (updated copies are
     returned) and ``chunk_lens`` (the engine's pad-lane routing leaf).
+    ``window`` is a windowed layer's width (module docstring): the row
+    is written as ever and the attend starts at the window's start.
     Returns ``(o, k_pages, v_pages[, k_scales, v_scales])`` — the
     pool leaves updated with the written row, everything else
     byte-preserved (the kernel aliases the pool, so only the write
@@ -1147,12 +1197,14 @@ def paged_decode_fused(q, k_new, v_new, k_pages, v_pages, block_tables,
             "k_scales/v_scales/chunk_lens only apply to quantized "
             f"pools; pages are {k_pages.dtype}")
     scale = (d ** -0.5) if scale is None else float(scale)
+    _check_window(window)
     if shard_axis is not None and mesh is not None \
             and mesh.shape.get(shard_axis, 1) > 1:
         return _run_decode_fused_sharded(
             q, k_new, v_new, k_pages, v_pages, block_tables, lengths,
             int(max_seq_len), cos_b, sin_b, scale, implementation,
-            k_scales, v_scales, chunk_lens, mesh, shard_axis)
+            k_scales, v_scales, chunk_lens, mesh, shard_axis,
+            window=window)
     half = 0 if cos_b is None else int(cos_b.shape[-1])
     pallas_ok = (bs % 8 == 0 and d % 8 == 0
                  and (half == 0 or half % 8 == 0)
@@ -1165,13 +1217,14 @@ def paged_decode_fused(q, k_new, v_new, k_pages, v_pages, block_tables,
             q, k_new, v_new, k_pages, v_pages, block_tables, lengths,
             max_seq_len=int(max_seq_len), cos_b=cos_b, sin_b=sin_b,
             scale=scale, k_scales=k_scales, v_scales=v_scales,
-            chunk_lens=chunk_lens)
+            chunk_lens=chunk_lens, window=window)
     return _run_decode_fused(
         q, k_new, v_new, k_pages, v_pages,
         jnp.asarray(block_tables, jnp.int32),
         jnp.asarray(lengths, jnp.int32), int(max_seq_len), cos_b,
         sin_b, scale, impl == "pallas_interpret",
-        k_scales=k_scales, v_scales=v_scales, chunk_lens=chunk_lens)
+        k_scales=k_scales, v_scales=v_scales, chunk_lens=chunk_lens,
+        window=window)
 
 
 # --------------------------------------------------------------------- #
@@ -1399,9 +1452,12 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
                     scale: Optional[float] = None,
                     implementation: Optional[str] = None,
                     k_scales=None, v_scales=None,
-                    mesh=None, shard_axis: Optional[str] = None):
+                    mesh=None, shard_axis: Optional[str] = None,
+                    window: Optional[int] = None):
     """Attention of chunk queries over a paged KV pool (shapes in the
-    module docstring).
+    module docstring).  ``window``: a windowed layer's width — a query
+    at position ``p`` sees keys ``p - window < j <= p`` and the sweep
+    starts where the first lane's window does.
 
     With ``mesh`` and ``shard_axis`` both set (and the axis larger
     than 1), the op runs tensor-parallel through ``jax.shard_map``:
@@ -1466,11 +1522,13 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
             f"k_scales/v_scales only apply to quantized pools; pages "
             f"are {k_pages.dtype}")
     scale = (d ** -0.5) if scale is None else float(scale)
+    _check_window(window)
     if shard_axis is not None and mesh is not None \
             and mesh.shape.get(shard_axis, 1) > 1:
         return _run_sharded(q, k_pages, v_pages, block_tables,
                             lengths, scale, implementation,
-                            k_scales, v_scales, mesh, shard_axis)
+                            k_scales, v_scales, mesh, shard_axis,
+                            window=window)
     pallas_ok = (bs % 8 == 0 and d % 8 == 0
                  and (quantized
                       or q.dtype == k_pages.dtype == v_pages.dtype))
@@ -1479,9 +1537,9 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
     if impl == "xla":
         return paged_attention_reference(
             q, k_pages, v_pages, block_tables, lengths, scale=scale,
-            k_scales=k_scales, v_scales=v_scales)
+            k_scales=k_scales, v_scales=v_scales, window=window)
     return _run_paged(q, k_pages, v_pages,
                       jnp.asarray(block_tables, jnp.int32),
                       jnp.asarray(lengths, jnp.int32), scale,
                       impl == "pallas_interpret",
-                      k_scales=k_scales, v_scales=v_scales)
+                      k_scales=k_scales, v_scales=v_scales, window=window)

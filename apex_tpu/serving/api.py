@@ -795,6 +795,15 @@ class InferenceServer:
             out["mesh_shape"] = mesh_shape
         if engine.spec_tokens:
             out["spec_accept_rate"] = engine.spec_accept_rate
+        if engine.window is not None:
+            # pages one window layer's sweeps visited (docs/serving.md)
+            out["kv_window_pages"] = engine.kv_window_pages
+        if engine.expert_layers:
+            # the expert share's load, over steps and expert layers
+            out["expert_assignments"] = engine.expert_assignments
+            out["expert_load_max"] = engine.expert_load_max
+            out["experts_active"] = engine.experts_active
+            out["expert_layer_steps"] = engine.expert_layer_steps
         if engine.ssm_state_bytes:
             # recurrent state beside the pages (docs/serving.md)
             out["ssm_state_bytes"] = engine.ssm_state_bytes

@@ -24,6 +24,7 @@ leaves shard on ``kv_heads`` and everything else stays replicated
 from __future__ import annotations
 
 import hashlib
+import re
 from typing import Any, Dict, List, NamedTuple
 
 import jax
@@ -169,6 +170,10 @@ _CHUNK_LENS_LEAF = "chunk_lens"
 #: model owns the values (zeroed for a row at cursor 0, advanced over
 #: ``chunk_lens`` real lanes), the engine counts the bytes
 _RECURRENT_LEAVES = ("ssm_state", "conv_state")
+#: an expert layer's per-step report: the assignments each held expert
+#: got (``models/afmoe.py``); the model overwrites it every step and the
+#: engine reads every layer's back beside the tokens
+_EXPERT_COUNTS_LEAF = "expert_counts"
 
 
 class BlockExhausted(RuntimeError):
@@ -469,7 +474,7 @@ def constrain_paged_cache(cache: Any, mesh, axis: str) -> Any:
                         paged_pool_shardings(cache, mesh, axis))
 
 
-__all__ += ["recurrent_state_bytes"]
+__all__ += ["recurrent_state_bytes", "expert_count_leaves", "expert_counts"]
 __all__ += ["paged_pool_shardings", "shard_paged_cache",
             "constrain_paged_cache"]
 
@@ -482,6 +487,24 @@ def recurrent_state_bytes(cache: Any) -> int:
         int(np.prod(leaf.shape)) * jnp.dtype(leaf.dtype).itemsize
         for path, leaf in jax.tree_util.tree_flatten_with_path(cache)[0]
         if _leaf_name(path) in _RECURRENT_LEAVES)
+
+
+def expert_count_leaves(cache: Any) -> list:
+    """The ``expert_counts`` leaves of a cache tree (arrays or
+    ShapeDtypeStructs), one an expert layer, in layer order."""
+    found = {
+        int(re.search(r"layer_(\d+)", jax.tree_util.keystr(path)).group(1)):
+        leaf for path, leaf
+        in jax.tree_util.tree_flatten_with_path(cache)[0]
+        if _leaf_name(path) == _EXPERT_COUNTS_LEAF}
+    return [found[i] for i in sorted(found)]
+
+
+def expert_counts(cache: Any):
+    """Every expert layer's ``expert_counts``, stacked ``(expert
+    layers, experts held)`` — or None for a model that has none."""
+    leaves = expert_count_leaves(cache)
+    return jnp.stack(leaves) if leaves else None
 
 
 def set_paged_leaves(cache: Any, tables, cursors,

@@ -332,6 +332,24 @@ class PagedEngine:
     snapshot or a state sharding that does not exist and raise for
     such a model.
 
+    A model with **window layers** (``cfg.kv_window`` states their
+    width, whichever of its layers they are) is served as any other: its window layers' reads of the pool start at the
+    window's first page (:mod:`apex_tpu.ops.paged_attention`,
+    ``window=``) and its pages stay allocated behind the window
+    (ROADMAP M1).  ``share_prefixes`` and ``spec_tokens`` hold under a
+    window — a page's K/V are a function of the prefix whatever reads
+    them, and a verify chunk is a chunk.  ``kv_window_pages`` counts
+    what one window layer sweeps beside ``kv_pages_live``.
+
+    A model with an **expert share** (an expert layer that holds some
+    of its layer's experts: :class:`~apex_tpu.models.afmoe.AfmoeModel`)
+    reports, a layer and a step, the assignments each held expert got;
+    they come back in the step's one fetch (every step program returns
+    ONE packed array: tokens, accepted counts, finished flags, these
+    counts) and feed
+    ``expert_assignments``, ``expert_load_max``, ``experts_active`` and
+    ``expert_layer_steps``.  ``mesh`` raises for such a model.
+
     Block exhaustion preempts the YOUNGEST tenant (its blocks are
     freed, its slot state cleared) and reports it in
     ``StepOutput.preempted``; the scheduler requeues it to continue
@@ -455,11 +473,6 @@ class PagedEngine:
         if not getattr(cfg, "causal", True):
             raise ValueError("PagedEngine requires a causal model "
                              "(decode=True contract)")
-        if getattr(cfg, "sliding_window", None) is not None:
-            raise ValueError(
-                "PagedEngine does not support sliding-window models — "
-                "the paged pool already bounds decode memory to live "
-                "tokens; serve with sliding_window=None")
         if max_slots < 1:
             raise ValueError(f"max_slots must be >= 1, got {max_slots}")
         if prefill_chunk < 1:
@@ -589,6 +602,12 @@ class PagedEngine:
         #: of every live row (a width-1 step writes inside its
         #: attention kernel and counts none)
         self.kv_write_pages = 0
+        #: the width of the model's window layers (``cfg.kv_window``;
+        #: None: it has none) and the pages ONE such layer's sweeps had
+        #: to visit, the same sum as ``kv_pages_live`` from the window's
+        #: first page on: what the window saves is the difference
+        self.window = getattr(cfg, "kv_window", None)
+        self.kv_window_pages = 0
         self._headroom = (2 * self.block_size if admit_headroom is None
                           else int(admit_headroom))
         self._variables = dict(params)
@@ -632,6 +651,27 @@ class PagedEngine:
                         f"model with recurrent state — {why}; serve "
                         "it with share_prefixes=False, spec_tokens=0, "
                         "mesh=None")
+        # an expert layer reports the assignments its held experts got
+        # a step (``expert_counts``, a layer); the chip's share of the
+        # experts has no sharding over the tensor axis
+        counts = slot_cache.expert_count_leaves(shapes)
+        self.expert_layers = len(counts)
+        self._expert_cells = sum(int(np.prod(c.shape)) for c in counts)
+        if self.expert_layers and self.mesh is not None:
+            raise ValueError(
+                "PagedEngine: mesh= is not supported for a model with "
+                "an expert share — the experts held here are one chip's "
+                "part of an EXPERT-parallel layer, and no sharding of "
+                "them over the tensor axis exists; serve it with "
+                "mesh=None")
+        #: over steps and expert layers (lifetime; 0 without experts):
+        #: assignments that landed on held experts, the fullest held
+        #: expert's count, the held experts that got any, and the
+        #: layer-steps summed over
+        self.expert_assignments = 0
+        self.expert_load_max = 0
+        self.experts_active = 0
+        self.expert_layer_steps = 0
         #: rows started from zero state (admissions, and re-admissions
         #: after a preemption) and real lanes advanced through the
         #: recurrence, summed over steps and rows, a step counted once
@@ -685,8 +725,37 @@ class PagedEngine:
                         cache, mesh, TENSOR_AXIS),
                     _pin_replicated(state, mesh))
 
-        def step_fn(variables, cache, state, tables, cursors, feed,
-                    n_tokens, is_prefill, emit):
+        def fetched(cache, *columns):
+            # ONE int32 array for the step's one fetch — every array
+            # the host reads back is a round trip of its own: the
+            # per-slot columns, slot-major, then every expert layer's
+            # counts (a model without experts: nothing)
+            columns = [c.reshape(c.shape[0], -1).astype(jnp.int32)
+                       for c in columns]
+            experts = slot_cache.expert_counts(cache)
+            tail = ([] if experts is None
+                    else [experts.reshape(-1).astype(jnp.int32)])
+            return jnp.concatenate(
+                [jnp.concatenate(columns, axis=1).reshape(-1)] + tail)
+
+        slots, pages = self._tables.shape
+
+        def fed(packed, n_flags):
+            # ``_packed``'s array taken apart: block tables, cursors,
+            # the feed — its width is what is left —, n_tokens, then
+            # ``n_flags`` rows of flags
+            w = packed.shape[0] // slots - pages - 2 - n_flags
+            sizes = (slots * pages, slots, slots * w, slots) \
+                + (slots,) * (n_flags - 1)
+            tables, cursors, feed, n_tokens, *flags = jnp.split(
+                packed, np.cumsum(sizes))
+            return (tables.reshape(slots, pages), cursors,
+                    feed.reshape(slots, w), n_tokens,
+                    *[f != 0 for f in flags])
+
+        def step_fn(variables, cache, state, packed):
+            tables, cursors, feed, n_tokens, is_prefill, emit = fed(
+                packed, 2)
             # the host-authoritative block tables / cursors overwrite
             # their cache leaves (the model never advances them);
             # n_tokens doubles as the quantized pool's chunk_lens so
@@ -724,12 +793,12 @@ class PagedEngine:
                 active=state.active & ~finished,
                 rng=jnp.where(emit[:, None], split[:, 1], state.rng))
             cache, state = pin_out(cache, state)
-            return cache, state, nxt, finished
+            return cache, state, fetched(cache, nxt, finished)
 
         spec_w = 1 + self.spec_tokens
 
-        def spec_step_fn(variables, cache, state, tables, cursors,
-                         feed, n_tokens, emit):
+        def spec_step_fn(variables, cache, state, packed):
+            tables, cursors, feed, n_tokens, emit = fed(packed, 1)
             # the draft/verify step: every active row decodes — feed
             # row i is [current_tok, d_1..d_k, pad] with n_tokens[i] =
             # 1 + k real tokens.  ONE model application scores all
@@ -796,7 +865,7 @@ class PagedEngine:
                 active=state.active & ~finished,
                 rng=new_rng)
             cache, state = pin_out(cache, state)
-            return cache, state, sampled, n_emit, finished
+            return cache, state, fetched(cache, sampled, n_emit, finished)
 
         def admit(state, ints, floats):
             state = slot_cache.admit_slots(state, ints, floats)
@@ -1086,6 +1155,17 @@ class PagedEngine:
                 drafts[slot] = proposal[:cap]
         return drafts
 
+    def _packed(self, feed, n_tokens, *flags) -> np.ndarray:
+        """ONE int32 array of everything the host hands a step — the
+        block tables, the cursors, ``feed``, ``n_tokens`` and the flag
+        rows, in the order the step takes them apart again: a step's
+        arguments are transferred one by one, and each transfer costs
+        the host the same quarter of a millisecond whatever its size
+        (PERF.md section 6, PR 37)."""
+        return np.concatenate(
+            [self._tables.reshape(-1), self._cursors, feed.reshape(-1),
+             n_tokens] + [f.astype(np.int32) for f in flags])
+
     def step(self) -> StepOutput:  # graftlint: hot-step
         """One fused mixed prefill+decode step over every slot.
 
@@ -1151,8 +1231,14 @@ class PagedEngine:
                         (self._cursors[live] == 0).sum())
             with span(self.spans, DISPATCH):
                 self._install_admissions()
-                self.kv_pages_live += int(
-                    (self._cursors // self.block_size + 1).sum())
+                last_page = self._cursors // self.block_size
+                self.kv_pages_live += int((last_page + 1).sum())
+                if self.window is not None:
+                    first_page = np.maximum(
+                        self._cursors - self.window + 1, 0) \
+                        // self.block_size
+                    self.kv_window_pages += int(
+                        (last_page - first_page + 1).sum())
                 if w > 1:
                     live = [slot for slot, rec in enumerate(self._tenants)
                             if rec is not None]
@@ -1162,28 +1248,33 @@ class PagedEngine:
                         (last // self.block_size
                          - first // self.block_size + 1).sum())
                 if any_spec:
-                    self.cache, self.state, toks, n_emit, finished = \
-                        self._spec(self._variables, self.cache,
-                                   self.state, self._tables,
-                                   self._cursors, feed, n_tokens, emit)
+                    self.cache, self.state, out = self._spec(
+                        self._variables, self.cache, self.state,
+                        self._packed(feed, n_tokens, emit))
                 else:
                     runner = self._prefill if any_prefill else self._decode
-                    self.cache, self.state, toks, finished = runner(
+                    self.cache, self.state, out = runner(
                         self._variables, self.cache, self.state,
-                        self._tables, self._cursors, feed, n_tokens,
-                        is_prefill, emit)
+                        self._packed(feed, n_tokens, is_prefill, emit))
             with span(self.spans, FETCH):
-                # graftlint: unsharded(the paged engine's single per-step host sync — emitted tokens feed the host tenant table, verified drafts steer host-side cursors)
-                tokens = np.asarray(toks)
-                # graftlint: unsharded(same fetch — finished flags; the caller releases finished slots)
-                finished = np.asarray(finished)
+                # graftlint: unsharded(the paged engine's single per-step host sync — emitted tokens feed the host tenant table, finished flags release slots, verified drafts' accepted-prefix lengths steer host-side cursors, an expert model's counts feed health())
+                out = np.asarray(out)
+                # per slot: the step's tokens, (a drafted step: how
+                # many of them were kept,) the finished flag
+                rows = out[:out.size - self._expert_cells].reshape(
+                    self.max_slots, -1)
+                finished = rows[:, -1].astype(bool)
                 if any_spec:
-                    # graftlint: unsharded(same fetch — accepted-prefix lengths roll the cursors back over rejected tails)
-                    counts = np.asarray(n_emit)
+                    tokens, counts = rows[:, :-2], rows[:, -2]
                 else:
-                    tokens = tokens[:, None]
-                    counts = emit.astype(np.int32)
+                    tokens, counts = rows[:, :-1], emit.astype(np.int32)
             with span(self.spans, COMMIT):
+                if self.expert_layers:
+                    experts = out[rows.size:].reshape(self.expert_layers, -1)
+                    self.expert_assignments += int(experts.sum())
+                    self.expert_load_max += int(experts.max(axis=1).sum())
+                    self.experts_active += int((experts > 0).sum())
+                    self.expert_layer_steps += experts.shape[0]
                 for slot in range(self.max_slots):
                     rec = self._tenants[slot]
                     if rec is None:
@@ -1277,8 +1368,8 @@ class PagedEngine:
         ones = np.ones((self.max_slots,), np.int32)
         off = np.zeros((self.max_slots,), bool)
         return runner.lower(
-            self._variables, self.cache, self.state, self._tables,
-            self._cursors, feed, ones, off, off).compile().as_text()
+            self._variables, self.cache, self.state,
+            self._packed(feed, ones, off, off)).compile().as_text()
 
     # ------------------------------------------------------------ gauges
     @property

@@ -239,9 +239,11 @@ def llama_config(rehearse):
     full = LlamaConfig.mistral_7b()
     # depth: 8 of 32 layers is ~4 GB of bf16 weights, which leaves a KV
     # pool (and the reference's programs) beside them on 16 GB.
-    # context: PagedEngine refuses windowed configs (ROADMAP R1); at
-    # max_seq_len <= the 4096-token window the window never binds, so
-    # the served function is the published one.
+    # context: as the benchmark's Mistral configuration, which dates
+    # from when PagedEngine refused windowed configs (it serves them
+    # since PR 37; ROADMAP M1): at max_seq_len <= the 4096-token window
+    # the window never binds, so the served function is the published
+    # one.
     cfg = LlamaConfig.mistral_7b(
         num_layers=8, max_seq_len=4096, sliding_window=None,
         dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)

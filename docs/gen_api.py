@@ -28,6 +28,7 @@ PAGES = {
              "apex_tpu.core.train_state", "apex_tpu.core.mesh"],
     "ops": ["apex_tpu.ops.attention", "apex_tpu.ops.paged_attention",
             "apex_tpu.ops.fused_sampling", "apex_tpu.ops.ssm",
+            "apex_tpu.ops.expert_gmm",
             "apex_tpu.ops.multihead_attn",
             "apex_tpu.ops.layer_norm", "apex_tpu.ops.softmax",
             "apex_tpu.ops.rope", "apex_tpu.ops.mlp",
@@ -69,7 +70,7 @@ PAGES = {
     "models": ["apex_tpu.models.bert", "apex_tpu.models.gpt",
                "apex_tpu.models.vit", "apex_tpu.models.resnet",
                "apex_tpu.models.transformer",
-               "apex_tpu.models.falcon_h1",
+               "apex_tpu.models.falcon_h1", "apex_tpu.models.afmoe",
                "apex_tpu.models.generate",
                "apex_tpu.models.torch_import"],
     "serving": ["apex_tpu.serving.api", "apex_tpu.serving.engine",

@@ -22,6 +22,7 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -226,6 +227,75 @@ def test_paged_decode_fused_five_query_heads_a_kv_head(compile_for_chip):
         *[((FB, 1, 1, D // 2), jnp.float32)] * 2)
 
 
+# ------- Trinity-Large: 48 / 8 heads, window and full layers, experts
+TB, TH, THK = 48, 48, 8               # slots; 6 query heads a KV head
+T_SEQ, T_POOL, T_WINDOW = 8192, 122880, 4096
+
+
+def _trinity_pool(dtype=bf16, bs=16):
+    nb = T_POOL // bs + 1
+    return (((THK, nb, bs, D), dtype), ((THK, nb, bs, D), dtype),
+            ((TB, T_SEQ // bs), jnp.int32), ((TB,), jnp.int32)), nb
+
+
+@pytest.mark.parametrize("s", [1, 32])
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+@pytest.mark.parametrize("window", [None, T_WINDOW])
+def test_paged_attention_window_and_full_layers(compile_for_chip, s, kv,
+                                                window):
+    quant = kv == "int8"
+    pool, nb = _trinity_pool(jnp.int8 if quant else bf16)
+    shapes = [((TB, s, TH, D), bf16), *pool]
+    if quant:
+        shapes += [((THK, nb), jnp.float32)] * 2
+
+    def fn(q, kp, vp, bt, ln, *scales):
+        ks, vs = scales if scales else (None, None)
+        return paged_attention(q, kp, vp, bt, ln, k_scales=ks,
+                               v_scales=vs, window=window,
+                               implementation="pallas")
+
+    assert "tpu_custom_call" in compile_for_chip(fn, *shapes)
+
+
+@pytest.mark.parametrize("kind", ["window", "full"])
+def test_paged_decode_fused_window_and_full_layers(compile_for_chip,
+                                                   kind):
+    """A window layer rotates and reads from its window's start; a
+    full layer does neither."""
+    pool, _ = _trinity_pool()
+    shapes = [((TB, 1, TH, D), bf16), ((TB, 1, THK, D), bf16),
+              ((TB, 1, THK, D), bf16), *pool]
+    if kind == "window":
+        shapes += [((TB, 1, 1, D // 2), jnp.float32)] * 2
+
+    def fn(q, nk, nv, kp, vp, bt, ln, *rope):
+        kw = dict(cos_b=rope[0], sin_b=rope[1], window=T_WINDOW) \
+            if rope else {}
+        return paged_decode_fused(q, nk, nv, kp, vp, bt, ln,
+                                  max_seq_len=T_SEQ,
+                                  implementation="pallas", **kw)
+
+    assert "tpu_custom_call" in compile_for_chip(fn, *shapes)
+
+
+@pytest.mark.parametrize("rows", [256, 4224])  # decode, mixed step
+def test_expert_products_at_the_cells_shapes(compile_for_chip, rows):
+    """32 held experts of 3072 x 3072: the gate/up product and the
+    down product over ``rows`` sorted assignments."""
+    from apex_tpu.ops.expert_gmm import expert_gmm
+
+    def fn(x, w_in, w_down, counts):
+        y = expert_gmm(x, w_in, counts, implementation="pallas")
+        return expert_gmm(y[:, :3072], w_down, counts,
+                          implementation="pallas")
+
+    text = compile_for_chip(
+        fn, ((rows, 3072), bf16), ((32, 3072, 6144), bf16),
+        ((32, 3072, 3072), bf16), ((32,), jnp.int32))
+    assert text.count("tpu_custom_call") >= 2 and "expert_gmm" in text
+
+
 @pytest.mark.parametrize("s", [1, 32])        # decode update, mixed step
 def test_ssm_kernels_at_the_cells_shapes(compile_for_chip, s):
     from apex_tpu.ops import ssm
@@ -260,6 +330,9 @@ STEP_PROGRAMS = [
     ("mistral", "mistral_7b_l8", 8, 1), ("mistral", "mistral_7b_l8", 2, 32),
     ("falcon_h1", "falcon_h1_34b_l4", 4, 1),
     ("falcon_h1", "falcon_h1_34b_l4", 2, 32),
+    # every kind of layer: dense window, expert window x 3, expert full
+    ("afmoe", "trinity_large_l5_e32", 5, 1),
+    ("afmoe", "trinity_large_l5_e32", 5, 32),
 ]
 #: what may hold a whole pool or state: the program's arguments and
 #: results, and the Pallas kernels, which alias theirs
@@ -275,7 +348,8 @@ def _cell_engine(family, config, layers):
     import json
     import pathlib
 
-    from apex_tpu.models import (FalconH1Config, FalconH1Model,
+    from apex_tpu.models import (AfmoeConfig, AfmoeModel,
+                                 FalconH1Config, FalconH1Model,
                                  LlamaConfig, LlamaModel)
     from apex_tpu.serving import PagedEngine
 
@@ -286,6 +360,8 @@ def _cell_engine(family, config, layers):
     kw = dict(dtype=bf16, param_dtype=bf16)
     if family == "falcon_h1":
         model = FalconH1Model(FalconH1Config.from_hf(c, **kw))
+    elif family == "afmoe":
+        model = AfmoeModel(AfmoeConfig.from_hf(c, **kw))
     else:
         model = LlamaModel(LlamaConfig(
             vocab_size=c["vocab_size"], hidden_size=c["hidden_size"],
@@ -344,13 +420,12 @@ def test_serve_step_programs_copy_no_cache(topo, monkeypatch, family,
             a.shape, a.dtype, sharding=one_chip), tree)
 
     slots = engine.max_slots
-    rows = jax.ShapeDtypeStruct((slots,), jnp.int32)
-    flags = jax.ShapeDtypeStruct((slots,), jnp.bool_)
     step = engine._decode if width == 1 else engine._prefill
+    off = np.zeros((slots,), bool)
     compiled = step.lower(*on_chip((
-        params, engine.cache, engine.state, engine._tables, rows,
-        jax.ShapeDtypeStruct((slots, width), jnp.int32), rows, flags,
-        flags))).compile()
+        params, engine.cache, engine.state, engine._packed(
+            np.zeros((slots, width), np.int32),
+            np.ones((slots,), np.int32), off, off)))).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
 

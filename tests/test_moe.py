@@ -237,3 +237,247 @@ class TestMoEInModelZoo:
         assert cfg.add_bias_linear is False
         assert "b1" not in moe and "b2" not in moe, sorted(moe)
         assert "wg" in moe                      # gated (SwiGLU) experts
+
+
+# --------------------------------------------------------------------- #
+# the serving layer (ISSUE 37): drop-free, one chip's share
+# --------------------------------------------------------------------- #
+from apex_tpu.ops.expert_gmm import (ROW_TILE, expert_gmm,     # noqa: E402
+                                     expert_gmm_reference)
+from apex_tpu.transformer.moe import (ExpertShareConfig,       # noqa: E402
+                                      ExpertShareMLP, route_top_k)
+
+
+def _share(**kw):
+    base = dict(num_experts=16, top_k=4, route_scale=2.448,
+                hidden_size=32, ffn_hidden_size=128)
+    base.update(kw)
+    return ExpertShareConfig(**base)
+
+
+def _layer(cfg):
+    """The layer with the zoo's SwiGLU as its shared expert."""
+    from apex_tpu.models.transformer import ParallelMLP, TransformerConfig
+
+    return ExpertShareMLP(cfg, ParallelMLP(TransformerConfig(
+        hidden_size=cfg.hidden_size, num_heads=1,
+        ffn_hidden_size=cfg.ffn_hidden_size, activation="silu",
+        gated_mlp=True, add_bias_linear=False)))
+
+
+def _apply(cfg, params, x, **kw):
+    """The layer on ``x``, its own matrices (``w_in``, ``w_down`` of
+    ``params``) as the bank."""
+    params = dict(params)
+    bank = params.pop("w_in"), params.pop("w_down")
+    return _layer(cfg).apply({"params": params}, x, bank, **kw)
+
+
+def _layer_params(cfg, key, **over):
+    x = jnp.zeros((1, 4, cfg.hidden_size), jnp.float32)
+    h, f = cfg.hidden_size, cfg.ffn_hidden_size
+    params = _layer(cfg).init(key, x, (
+        jnp.zeros((cfg.held, h, 2 * f)), jnp.zeros((cfg.held, f, h))))[
+            "params"]
+    ks = jax.random.split(key, 4)
+    params = dict(
+        params,
+        router=jax.random.normal(ks[0], (h, cfg.num_experts)) * 0.3,
+        expert_bias=jax.random.normal(ks[1], (cfg.num_experts,)) * 0.1,
+        w_in=jax.random.normal(ks[2], (cfg.held, h, 2 * f)) / h ** 0.5,
+        w_down=jax.random.normal(ks[3], (cfg.held, f, h)) / f ** 0.5)
+    params.update(over)
+    return params
+
+
+def _by_hand(cfg, params, x):
+    """The layer in numpy, token by token, expert by expert."""
+    val = lambda v: np.asarray(getattr(v, "value", v), np.float64)
+    x = np.asarray(x, np.float64).reshape(-1, cfg.hidden_size)
+    silu = lambda a: a / (1.0 + np.exp(-a))
+    scores = 1.0 / (1.0 + np.exp(-(x @ val(params["router"]))))
+    picked = np.argsort(-(scores + val(params["expert_bias"])),
+                        axis=-1, kind="stable")[:, : cfg.top_k]
+    mlp = params["shared_expert"]
+    out = (silu(x @ val(mlp["dense_h_to_4h_gate"]["kernel"]))
+           * (x @ val(mlp["dense_h_to_4h"]["kernel"]))) \
+        @ val(mlp["dense_4h_to_h"]["kernel"])
+    f = cfg.ffn_hidden_size
+    counts = np.zeros(cfg.held, np.int64)
+    for t in range(x.shape[0]):
+        denom = scores[t, picked[t]].sum() + 1e-20
+        for e in picked[t]:
+            local = e - cfg.expert_offset
+            if not 0 <= local < cfg.held:
+                continue
+            counts[local] += 1
+            y = x[t] @ val(params["w_in"])[local]
+            y = (silu(y[:f]) * y[f:]) @ val(params["w_down"])[local]
+            out[t] += cfg.route_scale * scores[t, e] / denom * y
+    return out, counts
+
+
+class TestRouter:
+    def test_selects_by_score_plus_bias_and_weights_by_score(self, rng):
+        cfg = _share()
+        x = jnp.asarray(rng.normal(size=(24, 32)), jnp.float32)
+        w = jnp.asarray(rng.normal(size=(32, 16)) * 0.3, jnp.float32)
+        b = jnp.asarray(rng.normal(size=(16,)) * 0.2, jnp.float32)
+        ids, weights = route_top_k(cfg, x, w, b)
+        scores = 1 / (1 + np.exp(-np.asarray(x, np.float64)
+                                 @ np.asarray(w, np.float64)))
+        want = np.argsort(-(scores + np.asarray(b)), axis=-1)[:, :4]
+        np.testing.assert_array_equal(np.sort(np.asarray(ids), -1),
+                                      np.sort(want, -1))
+        s = np.take_along_axis(scores, np.asarray(ids), -1)
+        np.testing.assert_allclose(
+            np.asarray(weights),
+            2.448 * s / (s.sum(-1, keepdims=True) + 1e-20), rtol=1e-5)
+        # normalised over all four selected, then scaled
+        np.testing.assert_allclose(np.asarray(weights).sum(-1), 2.448,
+                                   rtol=1e-5)
+
+    def test_a_large_bias_changes_the_selection_and_not_the_weights(
+            self, rng):
+        cfg = _share()
+        x = jnp.asarray(rng.normal(size=(24, 32)), jnp.float32)
+        w = jnp.asarray(rng.normal(size=(32, 16)) * 0.3, jnp.float32)
+        bias = jnp.zeros((16,)).at[5].set(10.0)
+        ids, weights = route_top_k(cfg, x, w, bias)
+        assert bool(jnp.all(jnp.any(ids == 5, axis=-1)))   # always chosen
+        free, _ = route_top_k(cfg, x, w, jnp.zeros((16,)))
+        assert not bool(jnp.all(jnp.any(free == 5, axis=-1)))
+        # expert 5's weight is its SCORE's share, never its bias's
+        scores = np.asarray(jax.nn.sigmoid(x @ w))
+        s = np.take_along_axis(scores, np.asarray(ids), -1)
+        np.testing.assert_allclose(
+            np.asarray(weights), 2.448 * s / s.sum(-1, keepdims=True),
+            rtol=1e-5)
+        assert float(jnp.max(weights)) < 2.448
+
+    @pytest.mark.parametrize("scale,top_k", [(1.0, 4), (0.5, 4),
+                                             (2.448, 1)])
+    def test_weights_of_the_selected_add_up_to_the_scale(self, rng, scale,
+                                                         top_k):
+        cfg = _share(route_scale=scale, top_k=top_k)
+        x = jnp.asarray(rng.normal(size=(8, 32)), jnp.float32)
+        w = jnp.asarray(rng.normal(size=(32, 16)) * 0.3, jnp.float32)
+        ids, weights = route_top_k(cfg, x, w, jnp.zeros((16,)))
+        assert ids.shape == weights.shape == (8, top_k)
+        np.testing.assert_allclose(np.asarray(weights).sum(-1), scale,
+                                   rtol=1e-5)
+
+
+class TestExpertShare:
+    @pytest.mark.parametrize("held,offset", [(None, 0), (4, 0), (4, 8),
+                                             (6, 10)])
+    def test_layer_is_the_hand_computation(self, rng, held, offset):
+        cfg = _share(experts_held=held, expert_offset=offset)
+        params = _layer_params(cfg, jax.random.PRNGKey(held or 0))
+        x = jnp.asarray(rng.normal(size=(2, 9, 32)), jnp.float32)
+        with jax.default_matmul_precision("highest"):
+            out, counts = _apply(cfg, params, x)
+        want, want_counts = _by_hand(cfg, params, x)
+        np.testing.assert_allclose(np.asarray(out).reshape(-1, 32), want,
+                                   atol=2e-5, rtol=2e-5)
+        np.testing.assert_array_equal(np.asarray(counts), want_counts)
+
+    def test_no_token_is_dropped_when_every_token_picks_one_expert(
+            self, rng):
+        """A GShard layer at capacity 1.25 would drop most of these;
+        here expert 2 multiplies every token, and says so."""
+        cfg = _share(experts_held=4)
+        params = _layer_params(
+            cfg, jax.random.PRNGKey(1),
+            expert_bias=jnp.zeros((16,)).at[2].set(10.0))
+        x = jnp.asarray(rng.normal(size=(3, 40, 32)), jnp.float32)
+        with jax.default_matmul_precision("highest"):
+            out, counts = _apply(cfg, params, x)
+        want, want_counts = _by_hand(cfg, params, x)
+        assert int(counts[2]) == 3 * 40 == int(want_counts[2])
+        assert int(jnp.max(counts)) == 120      # what expert_load_max sums
+        np.testing.assert_allclose(np.asarray(out).reshape(-1, 32), want,
+                                   atol=2e-5, rtol=2e-5)
+
+    def test_pad_lanes_are_routed_nowhere(self, rng):
+        cfg = _share(experts_held=None)
+        params = _layer_params(cfg, jax.random.PRNGKey(2))
+        x = jnp.asarray(rng.normal(size=(2, 8, 32)), jnp.float32)
+        valid = jnp.arange(8)[None] < jnp.asarray([3, 8])[:, None]
+        with jax.default_matmul_precision("highest"):
+            out, counts = _apply(cfg, params, x, valid=valid)
+            every, _ = _apply(cfg, params, x)
+            shared = _apply(cfg, dict(
+                params, w_down=params["w_down"] * 0), x)[0]
+        assert int(counts.sum()) == (3 + 8) * cfg.top_k
+        np.testing.assert_allclose(np.asarray(out[0, :3]),
+                                   np.asarray(every[0, :3]), atol=1e-6)
+        np.testing.assert_allclose(np.asarray(out[1]), np.asarray(every[1]),
+                                   atol=1e-6)
+        # a pad lane keeps the shared expert's part alone
+        np.testing.assert_allclose(np.asarray(out[0, 3:]),
+                                   np.asarray(shared[0, 3:]), atol=1e-6)
+
+    def test_a_bank_of_several_layers_experts(self, rng):
+        """Handed a bank and its first group, the layer multiplies its
+        own experts' rows only: the result is the layer's that owns
+        the same matrices."""
+        cfg = _share(experts_held=4, expert_offset=4)
+        params = _layer_params(cfg, jax.random.PRNGKey(3))
+        x = jnp.asarray(rng.normal(size=(2, 9, 32)), jnp.float32)
+        own = _apply(cfg, params, x)
+        # other layers' matrices: loud, so that a wrong group shows
+        junk = lambda like: jnp.asarray(
+            100.0 * rng.normal(size=like.shape), jnp.float32)
+        bank = tuple(
+            jnp.concatenate([junk(params[k]), params[k], junk(params[k])])
+            for k in ("w_in", "w_down"))
+        rest = {k: v for k, v in params.items()
+                if k not in ("w_in", "w_down")}
+        got = _layer(cfg).apply({"params": rest}, x, bank, jnp.int32(4))
+        for a, b in zip(got, own):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       atol=1e-6)
+
+    def test_share_validation(self):
+        for kw, match in ((dict(top_k=0), "top_k"),
+                          (dict(experts_held=0), "experts"),
+                          (dict(experts_held=8, expert_offset=12),
+                           "experts")):
+            with pytest.raises(ValueError, match=match):
+                _share(**kw)
+
+
+class TestExpertGmm:
+    @pytest.mark.parametrize("sizes", [
+        [16, 0, 40, 7, 0, 1],           # empty groups, a tail behind
+        [0, 0, 0, 0, 0, 128],           # everything on the last expert
+        [128, 0, 0, 0, 0, 0],           # ... on the first
+        [0, 0, 0, 0, 0, 0],             # nothing at all
+        [30, 30, 30, 30, 30, 106]])     # groups that straddle row tiles
+    def test_kernel_is_the_ragged_dot(self, rng, sizes):
+        m = 2 * ROW_TILE
+        lhs = jnp.asarray(rng.normal(size=(m, 128)), jnp.float32)
+        rhs = jnp.asarray(rng.normal(size=(6, 128, 256)), jnp.float32)
+        sizes = jnp.asarray(sizes, jnp.int32)
+        want = expert_gmm_reference(lhs, rhs, sizes)
+        got = expert_gmm(lhs, rhs, sizes,
+                         implementation="pallas_interpret")
+        live = int(sizes.sum())
+        # rows behind the last group are undefined in the kernel
+        np.testing.assert_allclose(np.asarray(got)[:live],
+                                   np.asarray(want)[:live],
+                                   atol=2e-4, rtol=2e-4)
+
+    def test_off_a_tpu_auto_is_the_reference(self, rng):
+        lhs = jnp.asarray(rng.normal(size=(24, 16)), jnp.float32)
+        rhs = jnp.asarray(rng.normal(size=(3, 16, 8)), jnp.float32)
+        sizes = jnp.asarray([5, 0, 11], jnp.int32)
+        np.testing.assert_array_equal(
+            np.asarray(expert_gmm(lhs, rhs, sizes)),
+            np.asarray(expert_gmm_reference(lhs, rhs, sizes)))
+        # outside the kernel's envelope an explicit kernel raises
+        with pytest.raises(ValueError, match="envelope"):
+            expert_gmm(lhs, rhs, sizes, implementation="pallas")
+        with pytest.raises(ValueError, match="do not fit"):
+            expert_gmm(lhs, rhs[:, :8], sizes)

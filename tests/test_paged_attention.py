@@ -1126,3 +1126,179 @@ class TestSweepFollowsLiveTokensNotTheTable:
                  if e.primitive.name == "pallas_call"]
         assert len(calls) == 1
         assert tuple(calls[0].params["grid_mapping"].grid) == (3,)
+
+
+# --------------------------------------------------------------------- #
+# sliding window (ISSUE 37): every read of the pool takes ``window=``
+# --------------------------------------------------------------------- #
+def _brute_force(q, kp, vp, tables, lengths, window):
+    """Row by row, query by query, in numpy: the keys a query at
+    position ``p`` sees are ``max(0, p - window + 1) .. p``."""
+    q, kp, vp = (np.asarray(a, np.float64) for a in (q, kp, vp))
+    tables = np.asarray(tables)
+    b, s, h, d = q.shape
+    hk, _nb, bs, _ = kp.shape
+    rep = h // hk
+    out = np.zeros((b, s, h, d))
+    for r in range(b):
+        keys = kp[:, tables[r]].reshape(hk, -1, d)
+        vals = vp[:, tables[r]].reshape(hk, -1, d)
+        for i in range(s):
+            p = min(int(lengths[r]) + i, keys.shape[1] - 1)
+            lo = 0 if window is None else max(0, p - window + 1)
+            for head in range(h):
+                k, v = keys[head // rep, lo:p + 1], vals[head // rep, lo:p + 1]
+                sc = k @ q[r, i, head] * d ** -0.5
+                w = np.exp(sc - sc.max())
+                out[r, i, head] = (w / w.sum()) @ v
+    return out
+
+
+class TestWindow:
+    """``window=`` in both kernels and both references: the window
+    shorter than a page, one past a page boundary, as wide as a sweep
+    chunk, as wide as the context; rows on every edge of the sweep; a
+    wide chunk whose lanes' windows start in different chunks."""
+
+    BS, MB, HK, H, D = 8, 40, 2, 4, 32
+
+    def _rows(self, rng, lengths, s, dtype, kv_dtype=None):
+        kp, vp, tables, live = _edge_pool(
+            rng, lengths=list(lengths), s=s, hk=self.HK, d=self.D,
+            BS=self.BS, MB=self.MB, dtype=dtype)
+        scales = {}
+        if kv_dtype is not None:
+            kp, vp, ks, vs = quantize_kv_pages(kp, vp, kv_dtype)
+            scales = dict(k_scales=ks, v_scales=vs)
+        q = jnp.asarray(rng.normal(size=(len(lengths), s, self.H, self.D)),
+                        dtype)
+        return q, kp, vp, tables, jnp.asarray(lengths, jnp.int32), \
+            scales, live
+
+    @pytest.mark.parametrize("window", [5, 9, 128, 320])
+    @pytest.mark.parametrize("s", [1, 4])
+    def test_reference_is_the_brute_force(self, s, window):
+        rng = np.random.default_rng(40)
+        S = self.BS * self.MB
+        lengths = [0, 3, 8, 127, 128, 129, 200, S - s]
+        q, kp, vp, tables, lens, _, _ = self._rows(rng, lengths, s,
+                                                   jnp.float32)
+        ref = paged_attention_reference(q, kp, vp, tables, lens,
+                                        window=window)
+        np.testing.assert_allclose(
+            np.asarray(ref), _brute_force(q, kp, vp, tables, lengths,
+                                          window), atol=2e-5, rtol=2e-5)
+
+    _FP8 = pytest.mark.skipif(not hasattr(jnp, "float8_e4m3fn"),
+                              reason="no float8_e4m3fn in this jax build")
+
+    @pytest.mark.parametrize("s,window,dtype,kv_dtype", [
+        (1, 5, jnp.float32, None), (1, 9, jnp.float32, "int8"),
+        (1, 128, jnp.float32, None), (1, 130, jnp.bfloat16, None),
+        (4, 5, jnp.float32, None), (4, 9, jnp.bfloat16, None),
+        (4, 128, jnp.float32, "int8"),
+        pytest.param(4, 130, jnp.float32, "fp8", marks=_FP8),
+        (32, 5, jnp.float32, None), (32, 9, jnp.float32, "int8"),
+        (32, 128, jnp.bfloat16, None), (32, 130, jnp.float32, None)])
+    def test_chunk_kernel(self, s, window, dtype, kv_dtype):
+        rng = np.random.default_rng(41)
+        S = self.BS * self.MB
+        # every edge of the sweep, and cursors that put the first
+        # lane's window start and the last lane's in different chunks
+        lengths = _edge_lengths(self.BS, S, s) + [
+            window + 120, window + 127, window + 128, 2 * 128 + window - 3]
+        # (a lane farther past the table's end than the window is
+        # wide sees no key at all: out of the engine's contract, and
+        # garbage of another kind in kernel and reference)
+        lengths = [n for n in lengths if n <= S - s]
+        q, kp, vp, tables, lens, scales, _ = self._rows(
+            rng, lengths, s, dtype, kv_dtype)
+        ref = paged_attention_reference(q, kp, vp, tables, lens,
+                                        window=window, **scales)
+        out = paged_attention(q, kp, vp, tables, lens, window=window,
+                              implementation="pallas_interpret", **scales)
+        tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
+        np.testing.assert_allclose(
+            np.asarray(out, np.float32), np.asarray(ref, np.float32),
+            atol=tol, rtol=tol)
+
+    @pytest.mark.parametrize("window,dtype,kv_dtype", [
+        (5, jnp.float32, None), (9, jnp.bfloat16, None),
+        (128, jnp.float32, None), (5, jnp.float32, "int8"),
+        pytest.param(128, jnp.float32, "fp8", marks=_FP8)])
+    def test_fused_kernel(self, window, dtype, kv_dtype):
+        """The windowed attend within tolerance; the write is the
+        window's business not at all: pages, codes and scales bitwise
+        the reference's on live pages."""
+        from apex_tpu.ops.paged_attention import paged_decode_fused
+        from apex_tpu.ops.rope import rope_cos_sin
+
+        rng = np.random.default_rng(42)
+        S = self.BS * self.MB
+        lengths = np.asarray(
+            [n for n in _edge_lengths(self.BS, S) if n <= S]
+            + [window + 127, window + 128, 300], np.int32)
+        b = len(lengths)
+        q, kp, vp, tables, lens, scales, live = self._rows(
+            rng, lengths, 1, dtype, kv_dtype)
+        kw = dict(scales)
+        if kv_dtype is not None:
+            kw["chunk_lens"] = jnp.ones((b,), jnp.int32)
+        nk = jnp.asarray(rng.normal(size=(b, 1, self.HK, self.D)), dtype)
+        nv = jnp.asarray(rng.normal(size=(b, 1, self.HK, self.D)), dtype)
+        cos, sin = rope_cos_sin(S, self.D)
+        pc = np.minimum(lengths[:, None], S - 1)
+        kw.update(cos_b=jnp.asarray(cos[pc][:, :, None, :]),
+                  sin_b=jnp.asarray(sin[pc][:, :, None, :]))
+        run = lambda impl: jax.jit(lambda *a: paged_decode_fused(
+            *a, max_seq_len=S, window=window, implementation=impl,
+            **kw))(q, nk, nv, kp, vp, tables, lens)
+        ref, out = run("xla"), run("pallas_interpret")
+        tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
+        np.testing.assert_allclose(
+            np.asarray(out[0], np.float32), np.asarray(ref[0], np.float32),
+            atol=tol, rtol=tol)
+        for got, want in zip(out[1:3], ref[1:3]):
+            np.testing.assert_array_equal(
+                np.asarray(got[:, live], np.float32),
+                np.asarray(want[:, live], np.float32))
+
+    @pytest.mark.parametrize("impl", ["xla", "pallas_interpret"])
+    @pytest.mark.parametrize("s", [1, 4])
+    def test_no_window_and_a_window_as_wide_as_the_context(self, impl, s):
+        """``window=None`` is bit for bit the call without the
+        argument, and a window that holds the whole context masks
+        nothing."""
+        rng = np.random.default_rng(43)
+        S = self.BS * self.MB
+        lengths = _edge_lengths(self.BS, S, s)
+        q, kp, vp, tables, lens, _, _ = self._rows(rng, lengths, s,
+                                                   jnp.float32)
+        plain = paged_attention(q, kp, vp, tables, lens,
+                                implementation=impl)
+        for window in (None, S + self.BS + 3 + s):
+            got = paged_attention(q, kp, vp, tables, lens, window=window,
+                                  implementation=impl)
+            np.testing.assert_array_equal(np.asarray(got),
+                                          np.asarray(plain))
+
+    def test_window_none_traces_the_kernel_it_always_was(self):
+        """No equation of the kernel's body knows of a window when
+        there is none: the jaxprs with and without the argument are
+        the same text."""
+        rng = np.random.default_rng(44)
+        q, kp, vp, tables, lens, _, _ = self._rows(rng, [9, 130], 4,
+                                                   jnp.float32)
+        text = lambda **kw: str(jax.make_jaxpr(
+            lambda *a: paged_attention(
+                *a, implementation="pallas_interpret", **kw))(
+            q, kp, vp, tables, lens))
+        assert text() == text(window=None)
+        assert text() != text(window=9)
+
+    def test_a_window_below_one_raises(self):
+        rng = np.random.default_rng(45)
+        q, kp, vp, tables, lens, _, _ = self._rows(rng, [9], 1,
+                                                   jnp.float32)
+        with pytest.raises(ValueError, match="window"):
+            paged_attention(q, kp, vp, tables, lens, window=0)

@@ -503,6 +503,60 @@ class TestBatchedAdmission:
                              np.asarray(want)[0, prompt.size:]]
 
 
+class TestPackedDispatch:
+    """Every host array handed to a step is a transfer of its own, so a
+    step of any kind is handed ONE: block tables, cursors, feed,
+    ``n_tokens`` and the flag rows in one int32 array that the step
+    takes apart again."""
+
+    @pytest.mark.parametrize("spec", [0, 2])
+    def test_a_step_is_handed_one_host_array(self, gpt, spec):
+        model, params = gpt
+        engine = PagedEngine(model, params, max_slots=3, block_size=8,
+                             prefill_chunk=4, pool_tokens=128,
+                             spec_tokens=spec)
+        seen = []
+        for name in ("_decode", "_prefill", "_spec"):
+            inner = getattr(engine, name)
+
+            def recorded(*a, _inner=inner, _name=name):
+                seen.append((_name, a[3:]))
+                return _inner(*a)
+
+            setattr(engine, name, recorded)
+        prompt = np.tile(np.arange(1, 4, dtype=np.int32), 3)
+        engine.admit(1, prompt, max_new_tokens=6)
+        chain = []
+        while True:
+            out = engine.step()
+            chain.extend(int(t) for t in out.tokens[1, :out.counts[1]])
+            if out.finished[1]:
+                break
+        want = generate(model, params, jnp.asarray(prompt[None]),
+                        max_new_tokens=6)
+        assert chain == [int(t) for t in np.asarray(want)[0, prompt.size:]]
+        slots, pages = engine._tables.shape
+        widths = {"_decode": (1, 2), "_prefill": (4, 2),
+                  "_spec": (1 + spec, 1)}
+        assert {name for name, _ in seen} >= {"_decode", "_prefill"}
+        for name, host in seen:
+            (packed,) = host
+            w, flags = widths[name]
+            assert isinstance(packed, np.ndarray)
+            assert packed.dtype == np.int32
+            assert packed.shape == (slots * (pages + 2 + w + flags),)
+        # the layout: tables, cursors, feed, n_tokens, then the flags
+        feed = np.arange(slots * 4, dtype=np.int32).reshape(slots, 4)
+        n = np.array([4, 2, 1], np.int32)
+        on, off = np.ones(slots, bool), np.zeros(slots, bool)
+        packed = engine._packed(feed, n, on, off)
+        parts = np.split(packed, np.cumsum(
+            [slots * pages, slots, slots * 4, slots, slots]))
+        for got, want in zip(parts, (engine._tables, engine._cursors,
+                                     feed, n, on, off)):
+            np.testing.assert_array_equal(got, want.reshape(-1))
+
+
 class TestPagedServer:
     def test_streaming_parity_metrics_and_gauges(self, gpt):
         model, params = gpt
@@ -1388,3 +1442,90 @@ class TestQuantizedAccuracySlow:
         assert agree / total >= 0.95, (
             f"int8 KV greedy agreement {agree}/{total} "
             f"= {agree / total:.3f} < 0.95")
+
+
+class TestSlidingWindow:
+    """A windowed model through the engine (ISSUE 37): the paged reads
+    take the model's window, and greedy chains are ``generate()``'s —
+    whose dense path keeps a ring buffer of the window, another
+    implementation of the same mask."""
+
+    @staticmethod
+    def _model(window):
+        cfg = GPTConfig.tiny(position_embedding="learned",
+                             scan_layers=True, sliding_window=window)
+        model = GPTModel(cfg)
+        params = model.init(jax.random.PRNGKey(0),
+                            jnp.zeros((1, 4), jnp.int32))
+        return model, {"params": params["params"]}
+
+    @staticmethod
+    def _check(model, params, prompts, reqs, n):
+        for p, r in zip(prompts, reqs):
+            ref = np.asarray(generate(
+                model, params, jnp.asarray(p[None]),
+                max_new_tokens=n))[0, len(p):]
+            np.testing.assert_array_equal(
+                np.asarray(r.tokens), ref, err_msg=f"prompt_len={len(p)}")
+
+    @pytest.mark.parametrize("window", [6, 12])   # under a page, over one
+    def test_engine_matches_generate_past_the_window(self, window):
+        model, params = self._model(window)
+        rng = np.random.default_rng(61)
+        prompts = [rng.integers(0, model.cfg.vocab_size, size=(L,))
+                   .astype(np.int32) for L in (3, 8, 13, 27, 40)]
+        engine = PagedEngine(model, params, max_slots=2, block_size=8,
+                             prefill_chunk=4)
+        assert engine.window == window
+        sched = Scheduler(engine)
+        reqs = [sched.submit(Request(prompt=p, max_new_tokens=9))
+                for p in prompts]
+        sched.drain()
+        self._check(model, params, prompts, reqs, 9)
+        assert engine.blocks_in_use == 0
+        # what a window layer swept is less than what a full one would
+        assert 0 < engine.kv_window_pages < engine.kv_pages_live
+
+    def test_a_shared_prefix_under_a_window(self):
+        """A page's K/V are a function of the prefix whatever reads
+        them: tenants that map a shared prompt prefix still reproduce
+        generate()."""
+        model, params = self._model(6)
+        rng = np.random.default_rng(62)
+        system = rng.integers(0, model.cfg.vocab_size, size=(24,)) \
+            .astype(np.int32)
+        prompts = [np.concatenate([system, rng.integers(
+            0, model.cfg.vocab_size, size=(L,)).astype(np.int32)])
+            for L in (3, 9, 1)]
+        engine = PagedEngine(model, params, max_slots=3, block_size=8,
+                             prefill_chunk=4, share_prefixes=True)
+        sched = Scheduler(engine)
+        reqs = [sched.submit(Request(prompt=prompts[0], max_new_tokens=7))]
+        for _ in range(8):               # the first past its prefill
+            sched.run_step()
+        assert engine.trie_blocks == 3
+        reqs += [sched.submit(Request(prompt=p, max_new_tokens=7))
+                 for p in prompts[1:]]
+        sched.run_step()
+        assert engine.shared_blocks == 3     # mapped, not recomputed
+        sched.drain()
+        self._check(model, params, prompts, reqs, 7)
+        assert engine.blocks_in_use == 0
+
+    def test_a_draft_under_a_window(self):
+        """A verify chunk is a chunk: each draft position masks the
+        keys before its own window."""
+        model, params = self._model(6)
+        rng = np.random.default_rng(63)
+        prompts = [np.tile(rng.integers(
+            0, model.cfg.vocab_size, size=(4,)).astype(np.int32), 5),
+            rng.integers(0, model.cfg.vocab_size, size=(17,))
+            .astype(np.int32)]
+        engine = PagedEngine(model, params, max_slots=2, block_size=8,
+                             prefill_chunk=4, spec_tokens=3)
+        sched = Scheduler(engine)
+        reqs = [sched.submit(Request(prompt=p, max_new_tokens=8))
+                for p in prompts]
+        sched.drain()
+        self._check(model, params, prompts, reqs, 8)
+        assert engine.spec_proposed > 0

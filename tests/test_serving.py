@@ -5,7 +5,8 @@
 zero-retrace soak, the allocator, preemption, sharing, drafting,
 quantized pages).  Here:
 
-- what the engine and the scheduler refuse: a sliding-window model, a
+- what the engine and the scheduler refuse (a sliding-window model no
+  longer), a
   request that can never fit, a full queue, bad sampling parameters, a
   second shape through a guarded executable;
 - the threaded ``InferenceServer``: the default construction builds
@@ -65,14 +66,18 @@ def _server(gpt, **kw):
 
 
 class TestEngineValidation:
-    def test_sliding_window_cache_rejected(self):
+    def test_sliding_window_model_is_admitted(self):
+        """The engine refused windowed models until the paged reads
+        took a window (ISSUE 37); tests/test_paged_serving.py serves
+        one against generate()."""
         cfg = LlamaConfig.tiny(sliding_window=5, scan_layers=False)
         model = LlamaModel(cfg)
         params = model.init(jax.random.PRNGKey(0),
                             jnp.zeros((1, 4), jnp.int32))
-        with pytest.raises(ValueError, match="sliding-window"):
-            PagedEngine(model, {"params": params["params"]},
-                        max_slots=2, block_size=8)
+        engine = PagedEngine(model, {"params": params["params"]},
+                             max_slots=2, block_size=8)
+        assert engine.window == 5
+        assert engine._paged_model.cfg.sliding_window == 5
 
     def test_oversized_request_rejected_at_submit(self, gpt):
         model, _ = gpt
@@ -115,9 +120,8 @@ class TestEngineValidation:
         off = np.zeros((1,), bool)
         with pytest.raises(RetraceError):
             engine._decode(engine._variables, engine.cache,
-                           engine.state, engine._tables,
-                           engine._cursors, np.zeros((1, 2), np.int32),
-                           ones, off, off)
+                           engine.state, engine._packed(
+                               np.zeros((1, 2), np.int32), ones, off, off))
         assert engine.trace_counts["decode_step"] == 1
 
 
