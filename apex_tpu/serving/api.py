@@ -490,15 +490,23 @@ class InferenceServer:
                 handle = self._handles.pop(id(req), None)
                 if handle is not None:
                     handle._cancel()
+            # the steps still in flight served the handles just
+            # cancelled: nobody is left to hand their tokens to
+            self.scheduler.discard_in_flight()
             if self.metrics is not None \
                     and self._steps != self._last_emit_step:
                 self._emit_metrics(time.monotonic())
 
     def _serve_step(self) -> Optional[float]:
         """One iteration with work (worker thread): expire deadlines,
-        run the scheduler's step, deliver its tokens.  Returns the
-        step's time, or ``None`` where no step completed (everything
-        expired, or a transient fault was recovered from)."""
+        run the scheduler's step, deliver its tokens.  The scheduler
+        leaves the NEXT step dispatched where it can, so the chip works
+        through the delivery, the wait for a follow-up and the next
+        admission; an expiry or a fault recovery between two steps
+        evicts under that step, which then emits for nobody.  Returns
+        the step's time, or ``None`` where no step completed
+        (everything expired, or a transient fault was recovered
+        from)."""
         self._expire_deadlines()
         if not self.scheduler.has_work():
             return None                 # everything just expired
@@ -787,6 +795,8 @@ class InferenceServer:
             "cow_forks": engine.cow_forks,
             "kv_pages_live": engine.kv_pages_live,
             "kv_write_pages": engine.kv_write_pages,
+            # steps dispatched while their predecessor was unfetched
+            "steps_ahead": engine.steps_ahead,
             "kv_dtype": engine.kv_dtype,
             "kv_bits": engine.kv_bits,
         }

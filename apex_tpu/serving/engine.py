@@ -38,18 +38,24 @@ prefill and paged attention change the schedule and the cache layout,
 not the function), followed by the same fp32 argmax; sampled chains
 are a function of the request's seed alone.
 
-The step boundary is the only device→host sync: ``step()`` returns the
-per-slot tokens, counts and finished flags as numpy
-(:class:`StepOutput`) so the scheduler can evict and refill.  Inactive
-slots still compute (static shapes — no dynamic batch) into the null
-page; their outputs are ignored on the host.
+The step boundary is the only device→host sync: ``collect()`` returns
+a step's per-slot tokens, counts and finished flags as numpy
+(:class:`StepOutput`) so the scheduler can evict and refill.  A step
+has two halves: ``dispatch()`` plans it and enqueues its program,
+``collect()`` fetches what it made — and the next step may be
+dispatched before the last one is collected, so the chip runs step
+n + 1 while the host fetches, routes and delivers step n (``step()``
+is the two halves back to back).  Inactive slots still compute (static
+shapes — no dynamic batch) into the null page; their outputs are
+ignored on the host.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import time
-from typing import Dict, List, NamedTuple, Optional, Tuple
+from collections import deque
+from typing import Any, Deque, Dict, List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -70,10 +76,13 @@ __all__ = ["PagedEngine", "StepOutput", "sample_dynamic",
            "prompt_lookup_draft", "tp_mesh"]
 
 #: the engine's spans (``apex_tpu.utils.profiler.span``): one engine
-#: step, named by the program it runs, and its four parts — ``plan``
-#: (drafts, feed, page allocation), ``dispatch`` (the jitted call:
-#: enqueue only), ``fetch`` (the host's wait for the device and the
-#: copy back) and ``commit`` (host mirrors of the step).
+#: step, named by the program it runs — one count a device step, its
+#: seconds those of its two halves — and the halves' parts: ``plan``
+#: (may the step run ahead, drafts, feed, page allocation) and
+#: ``dispatch`` (the jitted call: enqueue only; then the mirrors the
+#: next plan reads), ``fetch`` (the host's wait for the oldest step in
+#: flight and the copy back) and ``commit`` (the host mirrors that
+#: need the fetched values).
 STEP_PREFILL = "apex/engine/step_prefill"
 STEP_DECODE = "apex/engine/step_decode"
 STEP_SPEC = "apex/engine/step_spec"
@@ -274,11 +283,30 @@ class _Tenant:
     blocks: List[int] = dataclasses.field(default_factory=list)
     seq: int = 0                # admission order (LIFO preemption key)
     budget: int = 0             # max_new_tokens (host mirror)
-    emitted: int = 0            # tokens emitted so far (host mirror)
+    planned: int = 0            # emissions dispatched so far
+    emitted: int = 0            # tokens fetched so far (host mirror)
     gen: List[int] = dataclasses.field(default_factory=list)
     #: chain digests of the prompt's full blocks (prefix sharing)
     digests: List[bytes] = dataclasses.field(default_factory=list)
     registered: int = 0         # prompt blocks offered to the trie
+
+
+@dataclasses.dataclass
+class _Flight:
+    """One dispatched step whose output the host has not fetched."""
+
+    kind: str                   # the step's span, named by its program
+    out: Any                    # the step's packed output, on the device
+    #: each slot's tenant when the step was planned: a row of the
+    #: output is theirs, whoever holds the slot when it is fetched
+    recs: List[Optional[_Tenant]]
+    n_tokens: np.ndarray
+    preempted: Tuple[int, ...]
+    #: the next step has to wait for this one's fetch: a drafted step
+    #: (its cursors are the device's to decide), or one in which a
+    #: row's dispatched emissions reach its budget (a slot comes free)
+    fence: bool
+    host_s: float               # seconds of the dispatch half
 
 
 class PagedEngine:
@@ -349,6 +377,30 @@ class PagedEngine:
     counts) and feed
     ``expert_assignments``, ``expert_load_max``, ``experts_active`` and
     ``expert_layer_steps``.  ``mesh`` raises for such a model.
+
+    **A step in flight.**  A step is two calls: :meth:`dispatch`
+    builds the feed, extends pages, installs admissions and enqueues
+    the step's program (JAX returns futures; the cache and the slot
+    state chain from step to step on the device), :meth:`collect` makes
+    the step's ONE fetch and updates the host mirrors that need it
+    (the tokens, the expert counts).  Everything the next plan needs —
+    cursors, ``fed``, pages, the trie — advances at dispatch by
+    ``n_tokens``, which the host chose, so step n + 1 can be dispatched
+    while step n is unfetched and the chip never waits for the host's
+    round trip.  At most one step runs ahead, and none where the code
+    can see that the step in flight must be collected first
+    (:meth:`dispatch` then returns ``False``): it frees a slot (a
+    row's dispatched emissions reach its budget — the next prompt
+    would wait a whole extra program), the next step drafts (drafts
+    are looked up in the tokens of the step in flight), or the plan
+    has to preempt (the victim's streamed prefix must hold the token
+    in flight).  The device gates a row's emission on its own active
+    bit, and the fetched emission mask, not the host's plan, says what
+    a row produced: a row that finished by EOS under a step in flight
+    emits nothing there.  A row of a step's output belongs to the
+    tenant that held the slot when the step was planned.
+    :meth:`step` is ``dispatch(); collect()``, the lock-step form;
+    ``steps_ahead`` counts the steps dispatched over an unfetched one.
 
     Block exhaustion preempts the YOUNGEST tenant (its blocks are
     freed, its slot state cleared) and reports it in
@@ -704,6 +756,11 @@ class PagedEngine:
         #: floats) columns of ``admit_slots``: the next step installs
         #: them all in one call (64 arrivals at once cost one, not 64)
         self._pending: Dict[int, Tuple[tuple, tuple]] = {}
+        #: steps dispatched and not yet collected, oldest first (two at
+        #: most: the one about to be fetched and one behind it)
+        self._flights: Deque[_Flight] = deque()
+        #: steps dispatched while their predecessor was unfetched
+        self.steps_ahead = 0
         self.spans = SpanTotals((STEP_PREFILL, STEP_DECODE, STEP_SPEC,
                                  PLAN, DISPATCH, FETCH, COMMIT))
         self._build()
@@ -782,6 +839,9 @@ class PagedEngine:
             # computes but emits nothing, and its rng does NOT advance
             # — the k-th produced token always uses the k-th split, so
             # sampled chains are invariant to chunking
+            # — and on the device's own active bit: a row that finished
+            # under a step in flight was planned once more and emits
+            # nothing; the host reads the mask back with the tokens
             emit = emit & state.active
             produced = state.produced + emit.astype(jnp.int32)
             hit_budget = produced >= state.budget
@@ -793,7 +853,7 @@ class PagedEngine:
                 active=state.active & ~finished,
                 rng=jnp.where(emit[:, None], split[:, 1], state.rng))
             cache, state = pin_out(cache, state)
-            return cache, state, fetched(cache, nxt, finished)
+            return cache, state, fetched(cache, nxt, emit, finished)
 
         spec_w = 1 + self.spec_tokens
 
@@ -1058,6 +1118,15 @@ class PagedEngine:
         return (self._alloc.refcount(page) > 1
                 or self._trie.holds_block(page))
 
+    def _growth(self, rec: _Tenant, n: int) -> int:
+        """Pages ``rec``'s table lacks for its next ``n`` tokens —
+        capped at the table width: a finished-but-unreleased tenant
+        stepped past max_seq_len (possible in raw engine drivers; the
+        scheduler releases at the finish boundary) wraps within its
+        last page instead of growing the table."""
+        return min(slot_cache.blocks_for(rec.cursor + n, self.block_size),
+                   self._tables.shape[1]) - len(rec.blocks)
+
     def _extend(self, slot: int, n: int,
                 preempted: List[int]) -> None:
         """Grow ``slot``'s block table to cover its next ``n`` real
@@ -1096,13 +1165,7 @@ class PagedEngine:
             counters.inc("serving.cow_fork")
             break
         while rec is not None:
-            # capped at the table width: a finished-but-unreleased
-            # tenant stepped past max_seq_len (possible in raw engine
-            # drivers; the scheduler releases at the finish boundary)
-            # wraps within its last page instead of growing the table
-            need = min(slot_cache.blocks_for(rec.cursor + n,
-                                             self.block_size),
-                       self._tables.shape[1]) - len(rec.blocks)
+            need = self._growth(rec, n)
             if need <= 0:
                 return
             try:
@@ -1166,8 +1229,38 @@ class PagedEngine:
             [self._tables.reshape(-1), self._cursors, feed.reshape(-1),
              n_tokens] + [f.astype(np.int32) for f in flags])
 
-    def step(self) -> StepOutput:  # graftlint: hot-step
-        """One fused mixed prefill+decode step over every slot.
+    def _pages_short(self, w: int) -> bool:
+        """Would a step of width ``w`` have to preempt?  The pages the
+        live rows' next tokens need (:meth:`_extend`'s two demands: a
+        private page in place of a read-only one at a block boundary,
+        then the table's growth) against the free ones."""
+        free = self._alloc.blocks_free
+        # no row can ask for more than this: the usual case, no loop
+        if free >= self.max_slots * (
+                slot_cache.blocks_for(w, self.block_size) + 2):
+            return False
+        need = 0
+        for rec in self._tenants:
+            if rec is None:
+                continue
+            n = (min(w, rec.prompt.size - rec.fed)
+                 if rec.fed < rec.prompt.size else 1)
+            wb = rec.cursor // self.block_size
+            if self.share_prefixes and rec.cursor % self.block_size == 0 \
+                    and wb < len(rec.blocks) \
+                    and self._read_only(rec.blocks[wb]):
+                need += 1
+            need += max(self._growth(rec, n), 0)
+        return need > free
+
+    @property
+    def in_flight(self) -> int:
+        """Steps dispatched and not yet collected (0, 1 or 2)."""
+        return len(self._flights)
+
+    def dispatch(self) -> bool:  # graftlint: hot-step
+        """Plan one fused mixed prefill+decode step over every slot
+        and enqueue its program; :meth:`collect` fetches what it made.
 
         Prefilling tenants consume their next prompt chunk (emitting a
         token only on the final chunk — that token IS the first
@@ -1176,24 +1269,38 @@ class PagedEngine:
         (``spec_tokens > 0``, no prefill pending, at least one lookup
         hit), verify their draft run and emit the accepted prefix plus
         one bonus token.  Inactive slots compute garbage into the null
-        page.  The single per-step host sync lives here.
+        page.  The host mirrors the next plan reads (cursors, ``fed``,
+        the trie) advance here, by what the host fed; no host sync.
+
+        With a step in flight this one runs AHEAD of its fetch.  That
+        is refused — ``False``, nothing done — where the step in flight
+        has to be collected first: it frees a slot or was drafted (its
+        ``fence``), this one would be drafted, this one would have to
+        preempt, or one step already runs ahead.
         """
         t_top = time.perf_counter()
         any_prefill = any(rec is not None
                           and rec.fed < rec.prompt.size
                           for rec in self._tenants)
+        drafting = not any_prefill and self.spec_tokens > 0
+        w = self._chunk if any_prefill else 1
+        if self._flights and (
+                len(self._flights) > 1 or self._flights[-1].fence
+                or drafting or self._pages_short(w)):
+            return False
         drafts: List[Optional[np.ndarray]] = [None] * self.max_slots
-        if not any_prefill and self.spec_tokens > 0:
+        if drafting:
             drafts = self._plan_drafts()
         any_spec = any(d is not None for d in drafts)
+        if any_spec:
+            w = 1 + self.spec_tokens
         # the step's span is named by the program it runs, which the
-        # drafts decide: its seconds (and plan's) count from the top
+        # drafts decide: plan's seconds count from the top, the step's
+        # are this half's and the collect half's, counted there
         kind = (STEP_SPEC if any_spec
                 else STEP_PREFILL if any_prefill else STEP_DECODE)
-        with span(self.spans, kind, since=t_top):
+        with jax.profiler.TraceAnnotation(kind):
             with span(self.spans, PLAN, since=t_top):
-                w = (self._chunk if any_prefill
-                     else 1 + self.spec_tokens if any_spec else 1)
                 feed = np.zeros((self.max_slots, w), np.int32)
                 n_tokens = np.ones((self.max_slots,), np.int32)
                 is_prefill = np.zeros((self.max_slots,), bool)
@@ -1221,7 +1328,6 @@ class PagedEngine:
                     n_tokens[slot] = 1
                     is_prefill[slot] = False
                     emit[slot] = False
-                    drafts[slot] = None
                 if self.ssm_state_bytes:
                     live = np.fromiter(
                         (rec is not None for rec in self._tenants),
@@ -1256,18 +1362,54 @@ class PagedEngine:
                     self.cache, self.state, out = runner(
                         self._variables, self.cache, self.state,
                         self._packed(feed, n_tokens, is_prefill, emit))
+                # the program is queued: now the mirrors the NEXT plan
+                # reads.  A plain step advances every row by what the
+                # host fed; a drafted step's cursors wait for the
+                # accepted counts (collect), and so does the next plan
+                fence = any_spec
+                if not any_spec:
+                    for slot, rec in enumerate(self._tenants):
+                        if rec is None:
+                            continue
+                        n = int(n_tokens[slot])
+                        if is_prefill[slot]:
+                            rec.fed += n
+                            if self.share_prefixes:
+                                self._register_blocks(rec)
+                        rec.cursor += n
+                        self._cursors[slot] = rec.cursor
+                        if emit[slot]:
+                            rec.planned += 1
+                            fence |= rec.planned >= rec.budget
+        if self._flights:
+            self.steps_ahead += 1
+        self._flights.append(_Flight(
+            kind, out, list(self._tenants), n_tokens, tuple(preempted),
+            fence, time.perf_counter() - t_top))
+        return True
+
+    def collect(self) -> StepOutput:  # graftlint: hot-step
+        """Fetch the oldest step in flight: its tokens, counts and
+        finished flags for the scheduler, and the host mirrors that
+        need them.  The single per-step host sync lives here; while the
+        host waits in it the chip may already run the step dispatched
+        behind this one."""
+        if not self._flights:
+            raise RuntimeError("collect(): no step is in flight")
+        flight = self._flights[0]
+        recs, n_tokens = flight.recs, flight.n_tokens
+        # one count a device step; its seconds are both halves'
+        with span(self.spans, flight.kind,
+                  since=time.perf_counter() - flight.host_s):
             with span(self.spans, FETCH):
                 # graftlint: unsharded(the paged engine's single per-step host sync — emitted tokens feed the host tenant table, finished flags release slots, verified drafts' accepted-prefix lengths steer host-side cursors, an expert model's counts feed health())
-                out = np.asarray(out)
-                # per slot: the step's tokens, (a drafted step: how
-                # many of them were kept,) the finished flag
+                out = np.asarray(flight.out)
+                # per slot: the step's tokens, how many of them the
+                # device emitted, the finished flag
                 rows = out[:out.size - self._expert_cells].reshape(
                     self.max_slots, -1)
+                tokens, counts = rows[:, :-2], rows[:, -2]
                 finished = rows[:, -1].astype(bool)
-                if any_spec:
-                    tokens, counts = rows[:, :-2], rows[:, -2]
-                else:
-                    tokens, counts = rows[:, :-1], emit.astype(np.int32)
             with span(self.spans, COMMIT):
                 if self.expert_layers:
                     experts = out[rows.size:].reshape(self.expert_layers, -1)
@@ -1275,37 +1417,51 @@ class PagedEngine:
                     self.expert_load_max += int(experts.max(axis=1).sum())
                     self.experts_active += int((experts > 0).sum())
                     self.expert_layer_steps += experts.shape[0]
-                for slot in range(self.max_slots):
-                    rec = self._tenants[slot]
+                for slot, rec in enumerate(recs):
                     if rec is None:
                         continue
-                    if any_spec:
+                    kept = int(counts[slot])
+                    if flight.kind == STEP_SPEC:
                         # keep only the verified prefix: the cursor
                         # rolls back over rejected draft tails, whose
                         # pool writes are position-masked garbage the
                         # next step overwrites
-                        kept = int(counts[slot])
                         rec.cursor += kept
+                        rec.planned += kept
+                        if self._tenants[slot] is rec:
+                            self._cursors[slot] = rec.cursor
                         proposed = int(n_tokens[slot]) - 1
                         if proposed > 0:
                             self.spec_proposed += proposed
                             self.spec_accepted += max(kept - 1, 0)
-                    else:
-                        n = int(n_tokens[slot])
-                        if is_prefill[slot]:
-                            rec.fed += n
-                            if self.share_prefixes:
-                                self._register_blocks(rec)
-                        rec.cursor += n
                     # host mirrors of the emission (the drafter's
                     # context and budget cap)
-                    kept = int(counts[slot])
                     if kept:
                         rec.emitted += kept
                         rec.gen.extend(int(t) for t in tokens[slot, :kept])
-                    self._cursors[slot] = rec.cursor
+            self._flights.popleft()
             return StepOutput(tokens, finished, counts > 0,
-                              tuple(preempted), counts)
+                              flight.preempted, counts)
+
+    def step(self) -> StepOutput:
+        """One step in lock-step: :meth:`dispatch`, then
+        :meth:`collect` — for direct callers that want a step's output
+        before they plan the next (warm-up, tests, batch scripts).
+        Needs an empty pipeline: with a step in flight the output
+        would be an older step's."""
+        if self._flights:
+            raise RuntimeError(
+                "step() is dispatch() + collect() of ONE step: "
+                f"{len(self._flights)} dispatched step(s) are still "
+                "uncollected — collect() (or discard()) them first")
+        self.dispatch()
+        return self.collect()
+
+    def discard(self) -> None:
+        """Knowingly drop every step in flight unfetched — for a caller
+        that has released every tenant those steps served (a drain, a
+        shutdown): nobody is left to hand their tokens to."""
+        self._flights.clear()
 
     def release(self, slot: int) -> None:
         """Free ``slot``: pages back to the pool (refcount-decremented

@@ -4,9 +4,13 @@ The scheduler owns the host-side view the device never needs: which
 request occupies which slot, what has been emitted, and who is waiting.
 At every step boundary it (1) refills free slots from the queue in FIFO
 order — as far as the engine's page pool can take the head request —
-then (2) runs one engine step (prompt chunks and decoding tenants in
-the same batch) and routes each produced token to its request,
-evicting tenants that finished (eos or budget).  Requests never wait
+then (2) dispatches engine steps (prompt chunks and decoding tenants in
+the same batch) until one runs ahead of the one about to be fetched,
+where the engine allows it, and (3) collects the oldest and routes
+each token it produced to the request that held the slot when that
+step was planned, evicting tenants that finished (eos or budget): the
+chip runs step n + 1 while the host routes and delivers step n.
+Requests never wait
 for each other's completion: a 512-token generation and a 3-token one
 share the batch, and the short one's slot is re-used the step after it
 finishes — the continuous-batching property that fixed-batch
@@ -37,7 +41,7 @@ __all__ = ["Request", "Scheduler", "QueueFull", "StepEvent"]
 #: the scheduler's spans: ``admit`` covers one request's admission
 #: (from leaving the queue to owning its slot, ``engine.admit``
 #: included; ids ``uid``, ``prompt_len``), ``route`` what follows
-#: ``engine.step()`` (preempt requeues, token routing, releases)
+#: ``engine.collect()`` (preempt requeues, token routing, releases)
 ADMIT = "apex/sched/admit"
 ROUTE = "apex/sched/route"
 
@@ -117,6 +121,15 @@ class Scheduler:
         # atomically and cannot raise or tear
         # graftlint: unguarded(fixed-size list, item writes by the engine-owning worker only; iteration safe)
         self._slots: List[Optional[Request]] = [None] * engine.max_slots
+        # beside each step the engine has in flight, oldest first: the
+        # slots' requests when it was planned.  A row of a step's
+        # output is routed through THIS table — never through _slots,
+        # which an admission, an expiry or an eviction may have changed
+        # since — and a slot that is vacated is voided in it.  Only the
+        # serving worker appends and pops; a monitor thread's
+        # has_work() reads its truth value, one atomic load
+        # graftlint: unguarded(appended, popped and cleared by the engine-owning worker only; other threads read its truth value)
+        self._flights: Deque[List[Optional[Request]]] = deque()
         self._admit_failures: List[Tuple[Request, BaseException]] = []
         #: block-exhaustion preemptions requeued so far
         self.preempts = 0
@@ -210,7 +223,9 @@ class Scheduler:
         return self.active_count / self.engine.max_slots
 
     def has_work(self) -> bool:
-        return self.active_count > 0 or self.queue_depth > 0
+        """A tenant, a queued request, or a step still to collect."""
+        return (self.active_count > 0 or self.queue_depth > 0
+                or bool(self._flights))
 
     # ------------------------------------------------------------- steps
     def _admit_from_queue(self) -> int:
@@ -300,8 +315,17 @@ class Scheduler:
         if req is None:
             return None
         self.engine.release(slot)
-        self._slots[slot] = None
+        self._vacate(slot)
         return req
+
+    def _vacate(self, slot: int) -> None:
+        """``slot`` has lost its tenant: whatever the steps in flight
+        still produce for it is nobody's (knowingly discarded — the
+        token was never handed over, and a requeued continuation makes
+        it again), and the slot's next tenant never sees it."""
+        self._slots[slot] = None
+        for tenants in self._flights:
+            tenants[slot] = None
 
     # graftlint: thread-entry(serving-worker)
     def evict_all(self) -> List[Request]:
@@ -310,20 +334,38 @@ class Scheduler:
         Engine rows are released through the same compiled ``release``
         as normal completion, so the pool gets all its pages back
         (``blocks_in_use`` returns to 0 once the queue is also
-        cancelled).  Call from the engine-owning thread only."""
+        cancelled).  The steps in flight served nobody else and are
+        dropped unfetched.  Call from the engine-owning thread only."""
         evicted: List[Request] = []
         for slot in range(len(self._slots)):
             req = self.evict(slot)
             if req is not None:
                 evicted.append(req)
+        self.discard_in_flight()
         return evicted
 
     # graftlint: thread-entry(serving-worker)
-    def run_step(self) -> List[StepEvent]:
-        """One step boundary: admit → decode → route/evict.
+    def discard_in_flight(self) -> None:
+        """Drop the steps in flight unfetched: every slot is vacated
+        (a drain, a shutdown), so nobody is left to route them to."""
+        self._flights.clear()
+        self.engine.discard()
 
-        Returns the tokens produced this step (empty when idle).  Call
-        from the engine-owning thread only.
+    # graftlint: thread-entry(serving-worker)
+    def run_step(self) -> List[StepEvent]:
+        """One step boundary: admit → dispatch → collect → route/evict.
+
+        Returns the tokens of the step COLLECTED here, the oldest in
+        flight (empty when idle); one more step may stay in flight
+        behind it, so that the chip works while the caller delivers.
+        Call from the engine-owning thread only.
+
+        The chip is kept fed with at most two dispatched steps — the
+        one about to be fetched and one ahead of it; the engine refuses
+        the one ahead where the step in flight has to be collected
+        first (it frees a slot, so that the next request rides the very
+        next step; a drafted step; a plan that would preempt —
+        :meth:`PagedEngine.dispatch`).
 
         The engine returns a :class:`~apex_tpu.serving.engine.
         StepOutput`: only ``emitted`` slots route a token (mid-prefill
@@ -335,21 +377,27 @@ class Scheduler:
         preemption is scheduling, not failure).
         """
         self._admit_from_queue()
-        if self.active_count == 0:
+        while len(self._flights) < 2 and self.active_count \
+                and self.engine.dispatch():
+            self._flights.append(list(self._slots))
+        if not self._flights:
             return []
-        out = self.engine.step()
+        out = self.engine.collect()
         with span(self.spans, ROUTE):
-            return self._route(out)
+            events = self._route(out, self._flights[0])
+            self._flights.popleft()
+            return events
 
-    def _route(self, out) -> List[StepEvent]:
+    def _route(self, out, tenants) -> List[StepEvent]:
         """Requeue the step's preempted tenants, route its tokens to
-        their requests, release the slots that finished."""
+        their requests — ``tenants``, the slots' requests when the step
+        was planned —, release the slots that finished."""
         tokens, finished, _emitted, preempted, counts = out
         for slot in preempted:
-            req = self._slots[slot]
+            req = tenants[slot]
             if req is None:
                 continue
-            self._slots[slot] = None    # engine already freed the slot
+            self._vacate(slot)          # engine already freed the slot
             self.preempts += 1
             counters.inc("serving.preempt")
             try:
@@ -357,7 +405,7 @@ class Scheduler:
             except ValueError as exc:   # unresumable continuation
                 self._admit_failures.append((req, exc))
         events: List[StepEvent] = []
-        for slot, req in enumerate(self._slots):
+        for slot, req in enumerate(tenants):
             if req is None:
                 continue
             # a drafted (speculative) step can emit SEVERAL tokens for
@@ -373,7 +421,7 @@ class Scheduler:
                 events.append(StepEvent(req, tok, fin))
                 if fin:
                     self.engine.release(slot)
-                    self._slots[slot] = None
+                    self._vacate(slot)
         return events
 
     # graftlint: single-threaded(synchronous convenience for tests/batch scripts; no server thread runs beside it)

@@ -265,7 +265,8 @@ class TestInferenceServer:
             "first_tokens", "prefill_s", "compiles", "error",
             "chips_per_replica", "blocks_in_use", "blocks_total",
             "live_tokens", "shared_blocks", "cow_forks",
-            "kv_pages_live", "kv_write_pages", "kv_dtype", "kv_bits",
+            "kv_pages_live", "kv_write_pages", "steps_ahead", "kv_dtype",
+            "kv_bits",
             "spec_accept_rate"}         # the last: spec_tokens > 0 only
         assert set(health["spans"]) == {
             "apex/serve/step", "apex/serve/deliver",

@@ -14,6 +14,9 @@ Contracts under test:
   order;
 - ``health()`` stays safe from another thread while the worker steps;
 - a guarded program is named by its guard, a kernel by its scope;
+- ``steps_ahead`` counts the steps dispatched over an unfetched one,
+  never more than the steps taken, and none where every step frees a
+  slot (ISSUE 38);
 - ``kv_write_pages`` counts the pages a mixed step's chunk write
   touches and nothing for a width-1 step, and warm-up traces the same
   executables as before the chunk write was a kernel (ISSUE 36);
@@ -54,7 +57,7 @@ ENGINE_PARTS = (engine_mod.PLAN, engine_mod.DISPATCH, engine_mod.FETCH,
 SPANS = ((api.SERVE_STEP, api.DELIVER, scheduler.ADMIT, scheduler.ROUTE)
          + ENGINE_STEPS + ENGINE_PARTS)
 FIELDS = ("spans", "admitted", "queue_wait_s", "first_tokens",
-          "prefill_s", "compiles", "kv_write_pages")
+          "prefill_s", "compiles", "kv_write_pages", "steps_ahead")
 
 
 @pytest.fixture(scope="module")
@@ -149,6 +152,8 @@ class TestServerSpans:
         assert moved[engine_mod.STEP_SPEC] == 0       # drafting is off
         for part in ENGINE_PARTS:
             assert moved[part] == steps
+        # some of those steps were dispatched over an unfetched one
+        assert 0 < after["steps_ahead"] - before["steps_ahead"] < steps
         assert moved[scheduler.ADMIT] == len(prompts)
         # a child's seconds never exceed its parent's
         engine_s = sum(secs[n] for n in ENGINE_STEPS)
@@ -171,6 +176,27 @@ class TestServerSpans:
         assert {"queue_wait_p50_s", "queue_wait_p99_s"} <= set(summary)
         assert 0 <= summary["queue_wait_p50_s"] \
             <= summary["queue_wait_p99_s"] <= summary["ttft_p99_s"]
+
+    def test_no_step_runs_ahead_where_every_step_frees_a_slot(self, gpt):
+        """Budget 1, one-chunk prompts: each step is some request's
+        last, so each is collected before the next is planned — and
+        the spans still count every device step once."""
+        model, params = gpt
+        server = InferenceServer(
+            model, params, max_slots=2, block_size=8,
+            prefill_chunk=16, pool_tokens=256)
+        with server:
+            before = server.health()
+            self._serve(server, _prompts(model, (3, 9, 14, 6, 11)),
+                        budget=1)
+            after = server.health()
+        steps = after["steps"] - before["steps"]
+        assert steps >= 3
+        assert after["steps_ahead"] == before["steps_ahead"] == 0
+        assert sum(_moved(after, before, n, "n")
+                   for n in ENGINE_STEPS) == steps
+        for part in ENGINE_PARTS:
+            assert _moved(after, before, part, "n") == steps
 
     def test_preempt_readmissions_are_counted(self, gpt):
         model, params = gpt
@@ -275,14 +301,32 @@ def test_trace_holds_the_engine_spans_nested_on_one_line(gpt, tmp_path):
     assert names >= set(ENGINE_PARTS) | {
         engine_mod.STEP_PREFILL, engine_mod.STEP_DECODE,
         scheduler.ADMIT, scheduler.ROUTE}
-    steps = [e for e in evs if e[2] in ENGINE_STEPS]
+    # a step is two events of its name, one a half: the dispatch half
+    # (plan, dispatch) and, once the step before it is collected, the
+    # collect half (fetch, commit)
+    halves = [e for e in evs if e[2] in ENGINE_STEPS]
     parts = [e for e in evs if e[2] in ENGINE_PARTS]
-    assert len(parts) == 4 * len(steps)
+    assert len(parts) == 2 * len(halves)
     for s, t, name, _ in parts:
-        assert sum(a <= s and t <= b for a, b, _, _ in steps) == 1, name
-    for a, b, _, _ in steps:
-        mine = [e[2] for e in sorted(parts) if a <= e[0] and e[1] <= b]
-        assert mine == list(ENGINE_PARTS)        # in order, once each
+        assert sum(a <= s and t <= b for a, b, _, _ in halves) == 1, name
+    held = []
+    for a, b, name, _ in sorted(halves):
+        mine = tuple(e[2] for e in sorted(parts)
+                     if a <= e[0] and e[1] <= b)
+        assert mine in (ENGINE_PARTS[:2], ENGINE_PARTS[2:])
+        held.append((name, mine == ENGINE_PARTS[:2]))
+    # every step is dispatched once and collected once, in that order
+    # and under one name, and at most two are in flight
+    queue = []
+    for name, dispatched in held:
+        if dispatched:
+            queue.append(name)
+            assert len(queue) <= 2
+        else:
+            assert queue.pop(0) == name
+    assert queue == []
+    assert any(a and b for (_, a), (_, b) in zip(held, held[1:])), \
+        "no step was dispatched ahead of the one before it"
     admit = [e for e in evs if e[2] == scheduler.ADMIT]
     assert len(admit) == 1
     ids = {k: str(v) for k, v in admit[0][3].items()}
