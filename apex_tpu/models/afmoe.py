@@ -60,6 +60,7 @@ import flax.linen as nn
 from apex_tpu.core.mesh import TENSOR_AXIS
 from apex_tpu.models.llama import LlamaConfig
 from apex_tpu.models.transformer import (
+    Lanes,
     ParallelAttention,
     ParallelMLP,
     _norm,
@@ -215,15 +216,19 @@ class AfmoeBlock(nn.Module):
 
     @nn.compact
     def __call__(self, h, bank=None, first_group=None, *,
-                 decode: bool = False):
+                 decode: bool = False, lanes: Optional[Lanes] = None):
         cfg = self.cfg
         a = ParallelAttention(cfg, name="attention")(
-            _norm(cfg, "input_norm")(h), decode=decode)
+            _norm(cfg, "input_norm")(h), decode=decode, lanes=lanes)
         h = h + _norm(cfg, "post_attention_norm")(a).astype(h.dtype)
         x = _norm(cfg, "pre_mlp_norm")(h)
         if self.experts:
             valid = None
-            if decode:
+            if lanes is not None:
+                # a compact mixed step: every entry of the list that
+                # holds a lane is a token
+                valid = lanes.valid
+            elif decode:
                 # engine-owned, like the attention's cursors: how many
                 # of each row's lanes hold a token this step (default
                 # for other callers: every lane)
@@ -268,9 +273,13 @@ class AfmoeModel(nn.Module):
 
     @nn.compact
     def __call__(self, input_ids, *, deterministic: bool = True,
-                 decode: bool = False):
+                 decode: bool = False, lanes: Optional[Lanes] = None):
+        """``lanes``: as :class:`~apex_tpu.models.gpt.GPTModel`'s — the
+        real lanes of a mixed serving step, ``(rows, 1, vocab)`` back."""
         del deterministic                   # no dropout in this family
         cfg = self.cfg
+        if lanes is not None:
+            input_ids = lanes.take(input_ids)
         x = VocabParallelEmbedding(
             num_embeddings=cfg.vocab_size, features=cfg.hidden_size,
             dtype=cfg.dtype, param_dtype=cfg.param_dtype,
@@ -317,7 +326,10 @@ class AfmoeModel(nn.Module):
             layers = [kinds[kind]
                       for kind in cfg.layer_types[first:first + count]]
             x = decode_layers(self, layers, count, x, scope=scope,
-                              first=first, decode=decode, layer_args=args)
+                              first=first, decode=decode, layer_args=args,
+                              lanes=lanes)
+        if lanes is not None:
+            x = lanes.last_lanes(x)
         x = _norm(cfg, "final_norm")(x).astype(cfg.dtype)
         logits = ColumnParallelLinear(
             features=cfg.vocab_size, use_bias=False,

@@ -44,7 +44,7 @@ under tensor parallelism — ROADMAP M6.
 from __future__ import annotations
 
 import dataclasses
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -53,6 +53,7 @@ import flax.linen as nn
 from apex_tpu.core.mesh import TENSOR_AXIS
 from apex_tpu.models.llama import LlamaConfig
 from apex_tpu.models.transformer import (
+    Lanes,
     ParallelAttention,
     ParallelMLP,
     _norm,
@@ -182,7 +183,8 @@ class FalconH1Mixer(nn.Module):
     cfg: FalconH1Config
 
     @nn.compact
-    def __call__(self, x, *, decode: bool = False):
+    def __call__(self, x, *, decode: bool = False,
+                 lanes: Optional[Lanes] = None):
         cfg = self.cfg
         b, s, _ = x.shape
         heads, p = cfg.mamba_n_heads, cfg.mamba_d_head
@@ -202,8 +204,13 @@ class FalconH1Mixer(nn.Module):
                 jnp.full((heads,), mdt)]).astype(proj.dtype)
             proj = proj * mu
         z = proj[..., :d_ssm]
-        u = proj[..., d_ssm:d_ssm + cc]
-        dt = proj[..., d_ssm + cc:]
+        udt = proj[..., d_ssm:]
+        if lanes is not None:
+            # a compact mixed step: the convolution and the scan run
+            # over rows, the projections and the gate on the list
+            udt = lanes.to_rows(udt)
+            b, s = udt.shape[:2]
+        u, dt = udt[..., :cc], udt[..., cc:]
 
         conv_w = self.param("conv_weight", nn.initializers.normal(0.5),
                             (k, cc), cfg.param_dtype)
@@ -237,7 +244,9 @@ class FalconH1Mixer(nn.Module):
             cl = self.variable("cache", "chunk_lens", jnp.full,
                                (b,), cfg.max_seq_len, jnp.int32)
             lens = jnp.minimum(cl.value, s)
-            reset = cur.value == 0
+            # a row with no real lane (one the step leaves waiting)
+            # keeps its state whatever its cursor
+            reset = (cur.value == 0) & (lens > 0)
             state, window = st.value, win.value
         else:
             lens = jnp.full((b,), s, jnp.int32)
@@ -267,13 +276,17 @@ class FalconH1Mixer(nn.Module):
         if decode:
             st.value, win.value = state, window
 
-        y = y + d_skip[:, None] * xs.astype(f32)
-        y = y.reshape(b, s, d_ssm) * jax.nn.silu(z.astype(f32))
+        y = (y + d_skip[:, None] * xs.astype(f32)).reshape(b, s, d_ssm)
+        if lanes is not None:
+            y = lanes.take(y)
+        lead = z.shape[:2]
+        y = y * jax.nn.silu(z.astype(f32))
         # gate first, then an RMS norm over each group's columns
-        yg = y.reshape(b, s, g, d_ssm // g).astype(f32)
+        yg = y.reshape(lead + (g, d_ssm // g)).astype(f32)
         yg = yg * jax.lax.rsqrt(
             jnp.mean(yg * yg, axis=-1, keepdims=True) + cfg.layernorm_eps)
-        y = (yg.reshape(b, s, d_ssm) * norm_w.astype(f32)).astype(cfg.dtype)
+        y = (yg.reshape(lead + (d_ssm,))
+             * norm_w.astype(f32)).astype(cfg.dtype)
         out = RowParallelLinear(
             features=cfg.hidden_size, use_bias=False,
             dtype=cfg.dtype, param_dtype=cfg.param_dtype,
@@ -312,12 +325,14 @@ class FalconH1Block(nn.Module):
     cfg: FalconH1Config
 
     @nn.compact
-    def __call__(self, h, *, decode: bool = False):
+    def __call__(self, h, *, decode: bool = False,
+                 lanes: Optional[Lanes] = None):
         cfg = self.cfg
         x = _norm(cfg, "input_norm")(h)
-        m = FalconH1Mixer(cfg, name="mamba")(x, decode=decode)
+        m = FalconH1Mixer(cfg, name="mamba")(x, decode=decode, lanes=lanes)
         a = ParallelAttention(cfg, name="attention")(
-            _scaled(x, cfg.attention_in_multiplier), decode=decode)
+            _scaled(x, cfg.attention_in_multiplier), decode=decode,
+            lanes=lanes)
         a = _scaled(a, cfg.attention_out_multiplier)
         h = h + m.astype(h.dtype) + a.astype(h.dtype)
         y = _norm(cfg, "pre_ff_norm")(h)
@@ -343,9 +358,13 @@ class FalconH1Model(nn.Module):
 
     @nn.compact
     def __call__(self, input_ids, *, deterministic: bool = True,
-                 decode: bool = False):
+                 decode: bool = False, lanes: Optional[Lanes] = None):
+        """``lanes``: as :class:`~apex_tpu.models.gpt.GPTModel`'s — the
+        real lanes of a mixed serving step, ``(rows, 1, vocab)`` back."""
         del deterministic                   # no dropout in this family
         cfg = self.cfg
+        if lanes is not None:
+            input_ids = lanes.take(input_ids)
         x = VocabParallelEmbedding(
             num_embeddings=cfg.vocab_size, features=cfg.hidden_size,
             dtype=cfg.dtype, param_dtype=cfg.param_dtype,
@@ -367,7 +386,10 @@ class FalconH1Model(nn.Module):
                 stack = parameters_only(stack)
             y, _ = stack(cfg, decode, name="layers")(x, None)
         x = (decode_layers(self, FalconH1Block(cfg, parent=None),
-                           cfg.num_layers, x) if decode else y)
+                           cfg.num_layers, x, lanes=lanes)
+             if decode else y)
+        if lanes is not None:
+            x = lanes.last_lanes(x)
         x = _norm(cfg, "final_norm")(x).astype(cfg.dtype)
         logits = ColumnParallelLinear(
             features=cfg.vocab_size, use_bias=False,

@@ -166,16 +166,19 @@ def init_cache(model, batch_size: int, *, prompt_len: int = 1,
     return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), shapes)
 
 
-def apply_decode(model, variables, cache, ids):
+def apply_decode(model, variables, cache, ids, lanes=None):
     """One ``decode=True`` model application over ``cache``.
 
     Returns ``(logits, new_cache)``.  ``variables`` is the param dict
     WITHOUT a ``"cache"`` entry; the cache rides separately so callers
-    can thread it functionally (scan carries, slot pools).
+    can thread it functionally (scan carries, slot pools).  ``lanes``
+    (the paged engine's mixed step): the model computes those lanes of
+    ``ids`` only and returns each row's last one's logits.
     """
+    extra = {} if lanes is None else {"lanes": lanes}
     logits, upd = model.apply(
         {**variables, "cache": cache}, ids,
-        deterministic=True, decode=True, mutable=["cache"])
+        deterministic=True, decode=True, mutable=["cache"], **extra)
     return logits, upd["cache"]
 
 

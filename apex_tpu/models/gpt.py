@@ -21,6 +21,7 @@ import flax.linen as nn
 
 from apex_tpu.core.mesh import TENSOR_AXIS
 from apex_tpu.models.transformer import (
+    Lanes,
     ParallelTransformer,
     TransformerConfig,
     _norm,
@@ -71,8 +72,15 @@ class GPTModel(nn.Module):
 
     @nn.compact
     def __call__(self, input_ids, *, deterministic: bool = True,
-                 decode: bool = False):
+                 decode: bool = False, lanes: Optional[Lanes] = None):
+        """``lanes`` (paged serving's mixed step, :class:`~apex_tpu.
+        models.transformer.Lanes`): the model runs on the step's real
+        lanes only and returns ``(rows, 1, vocab)``, the logits of each
+        row's last real lane."""
         cfg = self.cfg
+        width = input_ids.shape[1]
+        if lanes is not None:
+            input_ids = lanes.take(input_ids)
         emb = VocabParallelEmbedding(
             num_embeddings=cfg.vocab_size, features=cfg.hidden_size,
             dtype=cfg.dtype, param_dtype=cfg.param_dtype,
@@ -93,8 +101,10 @@ class GPTModel(nn.Module):
                     lambda: jnp.zeros((x.shape[0],), jnp.int32))
                 positions = jnp.minimum(
                     pi.value[:, None]
-                    + jnp.arange(x.shape[1], dtype=jnp.int32),
+                    + jnp.arange(width, dtype=jnp.int32),
                     cfg.max_seq_len - 1)
+                if lanes is not None:
+                    positions = lanes.take(positions)
                 x = x + pos_table[positions].astype(x.dtype)
             elif decode:
                 # incremental decoding: positions continue from the
@@ -110,7 +120,9 @@ class GPTModel(nn.Module):
                 x = x + pos_table[None, : x.shape[1]].astype(x.dtype)
         x = x.astype(cfg.dtype)
         x = ParallelTransformer(cfg, name="transformer")(
-            x, deterministic=deterministic, decode=decode)
+            x, deterministic=deterministic, decode=decode, lanes=lanes)
+        if lanes is not None:
+            x = lanes.last_lanes(x)
         x = _norm(cfg, "final_norm")(x).astype(cfg.dtype)
         if cfg.tie_embeddings:
             logits = emb.attend(x)

@@ -50,7 +50,7 @@ from apex_tpu.transformer.layers import (
 
 __all__ = ["TransformerConfig", "ParallelTransformerLayer",
            "ParallelTransformer", "ParallelMLP", "ParallelAttention",
-           "decode_layers", "parameters_only"]
+           "Lanes", "decode_layers", "parameters_only"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -466,6 +466,70 @@ def _tp_pin(x, mesh, axis, dim):
             mesh, jax.sharding.PartitionSpec(*spec)))
 
 
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass(frozen=True)
+class Lanes:
+    """The real lanes of a mixed serving step as ONE compact list.
+
+    A step of ``rows`` slots at ``width`` lanes holds, a row, a prefix
+    of real lanes (a prompt's chunk, a decoding row's one token) and
+    pads.  Everything position-wise — the embedding, the norms, every
+    projection, the MLP, the experts, the head — runs on the list,
+    ``(1, size, ...)``, so a pad costs nothing; only the sequence
+    mixers (the paged write and attention with their rotary positions,
+    a state-space convolution and scan) see ``(rows, width, ...)``:
+    :meth:`to_rows` lays the list out as rows, pads zero, and
+    :meth:`take` gathers it back.
+
+    ``index`` ``(size,)``: entry ``i`` of the list is the flat lane
+    ``row * width + lane``, ``rows * width`` where unused; ``slot``
+    ``(rows * width,)``: the inverse, ``size`` for a pad; ``last``
+    ``(rows,)``: each row's last real lane in the list (a row with none
+    points at its predecessor's: its output is nobody's).
+    """
+
+    index: jax.Array
+    slot: jax.Array
+    last: jax.Array
+    rows: int = dataclasses.field(metadata=dict(static=True))
+    width: int = dataclasses.field(metadata=dict(static=True))
+
+    @classmethod
+    def of(cls, real, size: int) -> "Lanes":
+        """The list of ``real`` ``(rows, width)`` bool, a prefix of
+        each row, at most ``size`` lanes in all."""
+        rows, width = real.shape
+        flat = real.reshape(-1)
+        index = jnp.nonzero(flat, size=size,
+                            fill_value=rows * width)[0].astype(jnp.int32)
+        slot = jnp.where(flat, jnp.cumsum(flat) - 1, size)
+        last = jnp.maximum(jnp.cumsum(real.sum(axis=1)) - 1, 0)
+        return cls(index, slot.astype(jnp.int32), last.astype(jnp.int32),
+                   rows, width)
+
+    @property
+    def valid(self):
+        """``(1, size)``: the entries that hold a lane."""
+        return (self.index < self.rows * self.width)[None]
+
+    def take(self, x):
+        """``(rows, width, ...)`` -> ``(1, size, ...)``."""
+        flat = x.reshape((self.rows * self.width,) + x.shape[2:])
+        return jnp.take(flat, self.index, axis=0, mode="fill",
+                        fill_value=0)[None]
+
+    def to_rows(self, x):
+        """``(1, size, ...)`` -> ``(rows, width, ...)``, pads zero."""
+        return jnp.take(x[0], self.slot, axis=0, mode="fill",
+                        fill_value=0).reshape(
+            (self.rows, self.width) + x.shape[2:])
+
+    def last_lanes(self, x):
+        """``(1, size, ...)`` -> ``(rows, 1, ...)``: each row's last
+        real lane, the one it samples from."""
+        return x[0, self.last][:, None]
+
+
 class ParallelAttention(nn.Module):
     """TP attention block: ColumnParallel qkv → RoPE → flash → RowParallel.
 
@@ -487,7 +551,9 @@ class ParallelAttention(nn.Module):
     prefill): the dense cache runs a blocked online-softmax scan over
     the live prefix, the ring cache combines the banded flash kernel
     with a ring-correction einsum for the first ``min(window, s)``
-    queries.
+    queries.  ``lanes`` (paged serving's mixed step, :class:`Lanes`):
+    ``x`` is the step's compact lane list; the projections run on it
+    and only the write and attend see rows.
     """
 
     cfg: TransformerConfig
@@ -691,7 +757,7 @@ class ParallelAttention(nn.Module):
 
     @nn.compact
     def __call__(self, x, *, mask_bias=None, deterministic: bool = True,
-                 decode: bool = False):
+                 decode: bool = False, lanes: Optional[Lanes] = None):
         cfg = self.cfg
         b, s, _ = x.shape
         h, hk, d = cfg.num_heads, cfg.kv_heads, cfg.head_dim
@@ -701,6 +767,14 @@ class ParallelAttention(nn.Module):
             sequence_parallel=cfg.sequence_parallel,
             dtype=cfg.dtype, param_dtype=cfg.param_dtype,
             name="qkv_proj")(x)
+        if lanes is not None:
+            # a compact mixed step: the projections ran on its real
+            # lanes (``x`` is the list); the paged write and attention
+            # take rows, by absolute position
+            if not (decode and cfg.kv_cache == "paged"):
+                raise ValueError("lanes= is the paged serving step's")
+            qkv = lanes.to_rows(qkv)
+            b, s = qkv.shape[:2]
         if cfg.qkv_grouped:
             # Megatron qkv layout: features grouped per kv-head —
             # [q_g·rep … q_g·rep+rep-1, k_g, v_g] per group g — so the
@@ -747,14 +821,15 @@ class ParallelAttention(nn.Module):
             # validated here; dynamic_update_slice would silently clamp.
             # generate() enforces the bound statically.
             if cfg.kv_cache == "paged":
-                o = self._paged_decode(q, k, v, rot)
+                o = self._paged_decode(q, k, v, rot).reshape(b, s, h * d)
+                if lanes is not None:
+                    o = lanes.take(o)
                 return RowParallelLinear(
                     features=cfg.hidden_size,
                     use_bias=cfg.add_bias_linear,
                     sequence_parallel=cfg.sequence_parallel,
                     dtype=cfg.dtype, param_dtype=cfg.param_dtype,
-                    name="out_proj")(
-                        self._gated(o.reshape(b, s, h * d), gate))
+                    name="out_proj")(self._gated(o, gate))
             S = cfg.max_seq_len
             # rolling ring-buffer cache (Mistral design): with a
             # sliding window only the last `window` keys are ever
@@ -968,14 +1043,14 @@ class ParallelTransformerLayer(nn.Module):
 
     @nn.compact
     def __call__(self, x, *, mask_bias=None, deterministic: bool = True,
-                 decode: bool = False):
+                 decode: bool = False, lanes: Optional[Lanes] = None):
         cfg = self.cfg
         seq_spec = (TENSOR_AXIS if cfg.sequence_parallel else None)
         x = maybe_constrain(x, "data", seq_spec)
         a = _norm(cfg, "input_norm")(x)
         a = ParallelAttention(cfg, name="attention")(
             a, mask_bias=mask_bias, deterministic=deterministic,
-            decode=decode)
+            decode=decode, lanes=lanes)
         if cfg.hidden_dropout > 0.0 and not deterministic:
             a = nn.Dropout(rate=cfg.hidden_dropout)(a, deterministic=False)
         x = x + a.astype(x.dtype)
@@ -1037,7 +1112,8 @@ def parameters_only(scanned):
 
 def decode_layers(stack: nn.Module, layer, num_layers: int, x, *,
                   scope: str = "layers", first: int = 0,
-                  decode: bool = True, layer_args=None, **kwargs):
+                  decode: bool = True, layer_args=None,
+                  lanes: Optional[Lanes] = None, **kwargs):
     """The layers of a scanned stack under ``decode=True``, WITHOUT a
     scan over the cache.
 
@@ -1075,7 +1151,8 @@ def decode_layers(stack: nn.Module, layer, num_layers: int, x, *,
     layer, traced — what a layer reads that is NOT sliced from the
     stack (a bank of weights that a kernel takes whole: a slice that
     feeds a ``pallas_call`` is a copy, a slice that feeds a GEMM fuses
-    into it).
+    into it).  ``lanes``: a compact mixed serving step's lane list
+    (:class:`Lanes`), handed to every layer, traced.
     """
     stacked = stack.get_variable("params", scope)["layer"]
     layers = (list(layer) if isinstance(layer, (list, tuple))
@@ -1086,13 +1163,15 @@ def decode_layers(stack: nn.Module, layer, num_layers: int, x, *,
     # shapes are the same, and XLA inlines the calls
     def applier(module):
         @jax.jit
-        def apply_layer(variables, x, key, args):
+        def apply_layer(variables, x, key, args, lanes):
             rngs = None if key is None else {"dropout": key}
             if not decode:
                 return module.apply(variables, x, *args, rngs=rngs,
                                     **kwargs), None
+            extra = {} if lanes is None else {"lanes": lanes}
             return module.apply(variables, x, *args, decode=True,
-                                mutable=["cache"], rngs=rngs, **kwargs)
+                                mutable=["cache"], rngs=rngs, **kwargs,
+                                **extra)
         return apply_layer
 
     appliers = {}
@@ -1112,7 +1191,7 @@ def decode_layers(stack: nn.Module, layer, num_layers: int, x, *,
         x, updated = apply_layer(
             variables, x,
             None if key is None else jax.random.fold_in(key, i),
-            () if layer_args is None else layer_args[i])
+            () if layer_args is None else layer_args[i], lanes)
         if decode:
             stack.put_variable("cache", name, updated["cache"])
     return x
@@ -1138,7 +1217,7 @@ class ParallelTransformer(nn.Module):
 
     @nn.compact
     def __call__(self, x, *, mask_bias=None, deterministic: bool = True,
-                 decode: bool = False):
+                 decode: bool = False, lanes: Optional[Lanes] = None):
         cfg = self.cfg
         if cfg.scan_layers:
             if not decode or self.is_initializing():
@@ -1163,7 +1242,7 @@ class ParallelTransformer(nn.Module):
                 x = decode_layers(
                     self, ParallelTransformerLayer(cfg, parent=None),
                     cfg.num_layers, x, mask_bias=mask_bias,
-                    deterministic=deterministic)
+                    deterministic=deterministic, lanes=lanes)
             else:
                 x = y
         else:
@@ -1184,7 +1263,7 @@ class ParallelTransformer(nn.Module):
                         and i % cfg.remat_skip_every == 0)
                 layer_cls = (ParallelTransformerLayer if skip
                              else remat_cls)
-                kw = {"decode": True} if decode else {}
+                kw = {"decode": True, "lanes": lanes} if decode else {}
                 x = layer_cls(cfg, name=f"layer_{i}")(
                     x, mask_bias=mask_bias, deterministic=deterministic,
                     **kw)

@@ -797,6 +797,11 @@ class InferenceServer:
             "kv_write_pages": engine.kv_write_pages,
             # steps dispatched while their predecessor was unfetched
             "steps_ahead": engine.steps_ahead,
+            # a mixed step's compact lanes: real ones fed, ones
+            # computed, prefilling rows the lane budget held back
+            "mixed_lanes_real": engine.mixed_lanes_real,
+            "mixed_lanes_computed": engine.mixed_lanes_computed,
+            "prefill_rows_deferred": engine.prefill_rows_deferred,
             "kv_dtype": engine.kv_dtype,
             "kv_bits": engine.kv_bits,
         }

@@ -18,7 +18,10 @@ executables for the engine's whole lifetime (:class:`PagedEngine`):
   enables top-k/top-p.
 - ``prefill_step`` — the same function at width ``prefill_chunk``:
   the mixed step in which prompts ride as chunks beside decoding
-  tenants, so any prompt length replays one shape.
+  tenants, so any prompt length replays one shape.  It computes its
+  REAL lanes only: the model runs on a compact list of at most
+  ``lane_budget`` of them (:class:`~apex_tpu.models.transformer.
+  Lanes`), and only the sequence mixers see ``(slots, width)`` rows.
 - ``spec_step``    — only with ``spec_tokens > 0``: the width
   ``1 + spec_tokens`` draft/verify step.
 - ``admit``        — scatter every waiting tenant's token, budget,
@@ -64,6 +67,7 @@ import jax.numpy as jnp
 
 from apex_tpu.core.mesh import TENSOR_AXIS
 from apex_tpu.models.generate import apply_decode, cache_shapes
+from apex_tpu.models.transformer import Lanes
 from apex_tpu.ops.fused_sampling import fused_sample, \
     fused_sample_reference
 from apex_tpu.ops.paged_attention import tp_head_shards
@@ -515,7 +519,9 @@ class PagedEngine:
                 "whose dataclass .cfg carries "
                 f"{', '.join(_MODEL_CONTRACT)}; applied with "
                 "decode=True and a mutable 'cache' collection it "
-                "returns (batch, width, vocab) logits and keeps, a "
+                "returns (batch, width, vocab) logits — given lanes= "
+                "(a models.transformer.Lanes list), (batch, 1, vocab) "
+                "of each row's last real lane — and keeps, a "
                 "layer, a page pool plus 'block_tables' / 'cursors' "
                 "leaves (and any per-slot recurrent state, leading "
                 "axis = slots) that the engine overwrites before "
@@ -642,6 +648,14 @@ class PagedEngine:
         self._alloc = slot_cache.BlockAllocator(num_blocks,
                                                 self.block_size)
         self._trie = slot_cache.PrefixTrie()
+        #: the lanes a mixed step computes (T, static): half of its
+        #: ``max_slots × prefill_chunk``, rounded up to a multiple of
+        #: 128, and never fewer than the oldest prompt's chunk beside
+        #: every other row decoding; the planner feeds at most this
+        #: many real lanes (:meth:`_waiting_rows`)
+        rows = self.max_slots * self._chunk
+        self.lane_budget = min(rows, max(-(-rows // 256) * 128,
+                                         self.max_slots - 1 + self._chunk))
         #: lifetime counters (gauges ride health()/metrics)
         self.cow_forks = 0
         self.spec_proposed = 0
@@ -730,6 +744,12 @@ class PagedEngine:
         #: and not a layer (lifetime; 0 without recurrent state)
         self.ssm_state_resets = 0
         self.ssm_positions = 0
+        #: over mixed steps (lifetime): the real lanes fed, the lanes
+        #: computed (``lane_budget`` a step) and the prefilling rows the
+        #: budget left waiting a step
+        self.mixed_lanes_real = 0
+        self.mixed_lanes_computed = 0
+        self.prefill_rows_deferred = 0
         self.cache = jax.tree.map(
             lambda s: jnp.zeros(s.shape, s.dtype), shapes)
         self.state = slot_cache.init_slot_state(self.max_slots)
@@ -796,6 +816,7 @@ class PagedEngine:
                 [jnp.concatenate(columns, axis=1).reshape(-1)] + tail)
 
         slots, pages = self._tables.shape
+        budget = self.lane_budget
 
         def fed(packed, n_flags):
             # ``_packed``'s array taken apart: block tables, cursors,
@@ -823,9 +844,16 @@ class PagedEngine:
             # chunk, decoding rows their last sampled token (+ pad)
             tok_ids = jnp.zeros_like(feed).at[:, 0].set(state.tok)
             ids = jnp.where(is_prefill[:, None], feed, tok_ids)
-            logits, cache = apply_decode(model, variables, cache, ids)
-            last = jnp.take_along_axis(
-                logits, (n_tokens - 1)[:, None, None], axis=1)[:, 0]
+            lanes = None
+            if feed.shape[1] > 1:
+                # the mixed step computes its real lanes only — a live
+                # row's first n_tokens — and the head each row's last
+                real = (jnp.arange(feed.shape[1])[None]
+                        < n_tokens[:, None]) & (is_prefill | emit)[:, None]
+                lanes = Lanes.of(real, budget)
+            logits, cache = apply_decode(model, variables, cache, ids,
+                                         lanes)
+            last = logits[:, 0]
             split = jax.vmap(jax.random.split)(state.rng)
             # fused decode epilogue (see ops/fused_sampling): the
             # Pallas kernel reads the (slots, vocab) logits once on
@@ -1229,6 +1257,29 @@ class PagedEngine:
             [self._tables.reshape(-1), self._cursors, feed.reshape(-1),
              n_tokens] + [f.astype(np.int32) for f in flags])
 
+    def _waiting_rows(self, w: int) -> List[int]:
+        """The prefilling rows a mixed step of width ``w`` leaves
+        waiting: its real lanes — a decoding row's one, a prefilling
+        row's chunk — may not pass ``lane_budget``, so the YOUNGEST
+        prefilling rows wait a step (the oldest admitted go first, so
+        no row starves; the budget always holds the oldest)."""
+        lanes, prefilling = 0, []
+        for slot, rec in enumerate(self._tenants):
+            if rec is None:
+                continue
+            n = 1
+            if rec.fed < rec.prompt.size:
+                n = min(w, rec.prompt.size - rec.fed)
+                prefilling.append((rec.seq, slot, n))
+            lanes += n
+        waiting = []
+        for _, slot, n in sorted(prefilling, reverse=True):
+            if lanes <= self.lane_budget:
+                break
+            lanes -= n
+            waiting.append(slot)
+        return waiting
+
     def _pages_short(self, w: int) -> bool:
         """Would a step of width ``w`` have to preempt?  The pages the
         live rows' next tokens need (:meth:`_extend`'s two demands: a
@@ -1306,9 +1357,16 @@ class PagedEngine:
                 is_prefill = np.zeros((self.max_slots,), bool)
                 emit = np.zeros((self.max_slots,), bool)
                 preempted: List[int] = []
+                waiting = (self._waiting_rows(w)
+                           if any_prefill and w > 1 else [])
                 for slot in range(self.max_slots):
                     rec = self._tenants[slot]
                     if rec is None:
+                        continue
+                    if slot in waiting:
+                        # a waiting row feeds no lane and emits nothing:
+                        # its cursor, pages and recurrent state stay
+                        n_tokens[slot] = 0
                         continue
                     if rec.fed < rec.prompt.size:
                         n = min(w, rec.prompt.size - rec.fed)
@@ -1328,10 +1386,15 @@ class PagedEngine:
                     n_tokens[slot] = 1
                     is_prefill[slot] = False
                     emit[slot] = False
+                if any_prefill and w > 1:
+                    self.mixed_lanes_real += int(
+                        n_tokens[is_prefill | emit].sum())
+                    self.mixed_lanes_computed += self.lane_budget
+                    self.prefill_rows_deferred += len(waiting)
                 if self.ssm_state_bytes:
                     live = np.fromiter(
                         (rec is not None for rec in self._tenants),
-                        bool, self.max_slots)
+                        bool, self.max_slots) & (n_tokens > 0)
                     self.ssm_positions += int(n_tokens[live].sum())
                     self.ssm_state_resets += int(
                         (self._cursors[live] == 0).sum())
@@ -1347,7 +1410,7 @@ class PagedEngine:
                         (last_page - first_page + 1).sum())
                 if w > 1:
                     live = [slot for slot, rec in enumerate(self._tenants)
-                            if rec is not None]
+                            if rec is not None and n_tokens[slot]]
                     first = self._cursors[live]
                     last = first + n_tokens[live] - 1
                     self.kv_write_pages += int(

@@ -391,8 +391,9 @@ def test_health_counts_experts_and_window_pages_by_hand():
     moved = lambda k: after[k] - before[k]
     assert steps == 8
     real_lanes = 35 + 3                  # the 4th token is never fed
-    # slot 1 is empty: the engine gives an empty row one lane
-    lanes = real_lanes + steps
+    # slot 1 is empty: a width-1 step gives an empty row one lane, the
+    # five mixed steps' lane lists hold none of it
+    lanes = real_lanes + steps - 5
     assert moved("expert_layer_steps") == 4 * steps
     assert moved("expert_assignments") == 4 * 4 * lanes
     assert 4 * steps <= moved("experts_active") <= 4 * 32 * steps

@@ -402,6 +402,17 @@ def _moved(text, sizes):
     return dict(found)
 
 
+def _dot_rows(text):
+    """The rows of every dense product of the compiled text: its
+    result's leading dimension."""
+    import re
+
+    return {int(dims.split(",")[0])
+            for dims in re.findall(
+                r" = [a-z]+\d+\[([\d,]+)\]\S* (?:dot|convolution)\(",
+                text)}
+
+
 @pytest.mark.parametrize("family,config,layers,width", STEP_PROGRAMS)
 def test_serve_step_programs_copy_no_cache(topo, monkeypatch, family,
                                            config, layers, width):
@@ -428,6 +439,11 @@ def test_serve_step_programs_copy_no_cache(topo, monkeypatch, family,
             np.ones((slots,), np.int32), off, off)))).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
+    # the dense products multiply no pad: a mixed step's run on its
+    # compact lane list (lane_budget rows), the head on a row's last
+    # lane (slots rows); the width-1 step's on its slots, as before
+    assert _dot_rows(text) == ({engine.lane_budget, slots} if width > 1
+                               else {slots})
 
     big = [a for path, a in
            jax.tree_util.tree_flatten_with_path(engine.cache)[0]
