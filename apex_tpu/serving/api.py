@@ -46,11 +46,17 @@ _SENTINEL = object()
 
 #: the server's spans: ``step`` is one whole iteration of the worker
 #: loop with work (deadline expiry, the scheduler's step, delivery;
-#: id ``step``) — an idle server is the absence of it; ``deliver``
-#: hands the step's tokens to handles and taps (client callbacks run
-#: here)
+#: id ``step``; timed on the CPU too, so that a stalled step tells
+#: computing from waiting); ``deliver`` hands the step's tokens to
+#: handles and taps (client callbacks run here); ``idle`` is the
+#: worker's wait for work, entered only when there is none (one
+#: instance a stretch without work);
+#: ``follow_up`` the wait for a client's next turn after a step that
+#: freed a slot (``FOLLOW_UP_S``).  The two waits lie outside ``step``
 SERVE_STEP = "apex/serve/step"
 DELIVER = "apex/serve/deliver"
+SERVE_IDLE = "apex/serve/idle"
+FOLLOW_UP = "apex/serve/follow_up"
 
 #: after a step that finished a request, with an empty queue, the
 #: worker waits at most this long for a follow-up request before the
@@ -292,7 +298,8 @@ class InferenceServer:
         # their admission to it (prefill, as the request felt it)
         self._first_tokens = 0
         self._prefill_s = 0.0
-        self.spans = SpanTotals((SERVE_STEP, DELIVER))
+        self.spans = SpanTotals((SERVE_STEP, DELIVER, SERVE_IDLE, FOLLOW_UP),
+                                cpu=(SERVE_STEP,))
         #: the exception that killed the worker loop, if any — clients
         #: see ServerClosed; the root cause lives here for post-mortems.
         #: Published under _wakeup together with the _stop flip, so a
@@ -445,9 +452,11 @@ class InferenceServer:
         try:
             while True:
                 with self._wakeup:
-                    while (not self.scheduler.has_work()
-                           and not self._stop):
-                        self._wakeup.wait(0.1)
+                    if not self.scheduler.has_work() and not self._stop:
+                        with span(self.spans, SERVE_IDLE):
+                            while (not self.scheduler.has_work()
+                                   and not self._stop):
+                                self._wakeup.wait(0.1)
                     if self._stop and (not self._drain_on_stop
                                        or not self.scheduler.has_work()):
                         break
@@ -577,7 +586,8 @@ class InferenceServer:
         self._slot_freed = False
         with self._wakeup:
             if not self._stop and self.scheduler.queue_depth == 0:
-                self._wakeup.wait(FOLLOW_UP_S)
+                with span(self.spans, FOLLOW_UP):
+                    self._wakeup.wait(FOLLOW_UP_S)
 
     def _drain_out(self) -> None:
         """Evict everything for :meth:`begin_drain` (worker thread):
@@ -773,11 +783,14 @@ class InferenceServer:
             "drain_evicted": self._drain_evicted,
             "preempts": self.scheduler.preempts,
             # the program's own measurement (docs/serving.md): span
-            # totals of the worker's three layers, and where requests
-            # changed state
+            # totals of the worker's three layers, the same by
+            # duration, and where requests changed state
             "spans": {**self.spans.snapshot(),
                       **self.scheduler.spans.snapshot(),
                       **engine.spans.snapshot()},
+            "span_hist": {**self.spans.histogram(),
+                          **self.scheduler.spans.histogram(),
+                          **engine.spans.histogram()},
             "admitted": self.scheduler.admitted,
             "queue_wait_s": self.scheduler.queue_wait_s,
             "first_tokens": self._first_tokens,

@@ -261,7 +261,7 @@ class TestInferenceServer:
             "status", "ready", "draining", "uptime_s", "steps",
             "queue_depth", "occupancy", "tokens_emitted", "requeues",
             "failed_requests", "deadline_expired", "drain_evicted",
-            "preempts", "spans", "admitted", "queue_wait_s",
+            "preempts", "spans", "span_hist", "admitted", "queue_wait_s",
             "first_tokens", "prefill_s", "compiles", "error",
             "chips_per_replica", "blocks_in_use", "blocks_total",
             "live_tokens", "shared_blocks", "cow_forks",
@@ -269,13 +269,19 @@ class TestInferenceServer:
             "mixed_lanes_real", "mixed_lanes_computed",
             "prefill_rows_deferred", "kv_dtype", "kv_bits",
             "spec_accept_rate"}         # the last: spec_tokens > 0 only
-        assert set(health["spans"]) == {
-            "apex/serve/step", "apex/serve/deliver",
+        assert set(health["spans"]) == set(health["span_hist"]) == {
+            "apex/serve/step", "apex/serve/deliver", "apex/serve/idle",
+            "apex/serve/follow_up",
             "apex/sched/admit", "apex/sched/route",
             "apex/engine/step_prefill", "apex/engine/step_decode",
             "apex/engine/step_spec", "apex/engine/plan",
             "apex/engine/dispatch", "apex/engine/fetch",
             "apex/engine/commit"}
+        assert set(health["span_hist"]["apex/serve/step"]) == {
+            "0", "1", "2", "4", "8", "16", "32", "64", "128", "256", "512",
+            "1024"}
+        assert set(health["span_hist"]["apex/serve/step"]["64"]) == {
+            "n", "s", "cpu"}
         merged = {}
         for row in rows:
             merged.update(row)

@@ -9,6 +9,12 @@ Contracts under test:
 - ``InferenceServer.health()["spans"]`` holds every span of the three
   layers, their counts add up to the steps taken and a child's seconds
   never exceed its parent's;
+- ``health()["span_hist"]`` holds the same instances by duration: a
+  span lands in the bucket of its milliseconds' bit length, every
+  name's buckets add up to its total, and ``apex/serve/step`` alone
+  carries the worker's CPU seconds, which tell a wait from computing;
+  the worker's two waits, for work and for a client's next turn, are
+  spans of their own, and the benchmark's stall metrics read them;
 - ``admitted`` / ``queue_wait_s`` / ``first_tokens`` / ``prefill_s`` /
   ``compiles`` count what they say, and every request's stamps are in
   order;
@@ -24,6 +30,8 @@ Contracts under test:
 """
 
 import glob
+import importlib.util
+import json
 import pathlib
 import re
 import threading
@@ -46,6 +54,7 @@ from apex_tpu.serving import (
 )
 from apex_tpu.serving import api, engine as engine_mod, scheduler
 from apex_tpu.utils import tracecheck
+from apex_tpu.utils import profiler
 from apex_tpu.utils.profiler import SpanTotals, span
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
@@ -54,10 +63,17 @@ ENGINE_STEPS = (engine_mod.STEP_PREFILL, engine_mod.STEP_DECODE,
                 engine_mod.STEP_SPEC)
 ENGINE_PARTS = (engine_mod.PLAN, engine_mod.DISPATCH, engine_mod.FETCH,
                 engine_mod.COMMIT)
+SERVER_WAITS = (api.SERVE_IDLE, api.FOLLOW_UP)
 SPANS = ((api.SERVE_STEP, api.DELIVER, scheduler.ADMIT, scheduler.ROUTE)
-         + ENGINE_STEPS + ENGINE_PARTS)
-FIELDS = ("spans", "admitted", "queue_wait_s", "first_tokens",
-          "prefill_s", "compiles", "kv_write_pages", "steps_ahead")
+         + ENGINE_STEPS + ENGINE_PARTS + SERVER_WAITS)
+FIELDS = ("spans", "span_hist", "admitted", "queue_wait_s",
+          "first_tokens", "prefill_s", "compiles", "kv_write_pages",
+          "steps_ahead")
+#: the benchmark's per-layer metrics that read ``span_hist``
+STALL_METRICS = ("stall_ms_per_s.serve", "stalls_per_1k_steps.serve",
+                 "stall_fetch_ms_per_s.serve",
+                 "stall_dispatch_ms_per_s.serve",
+                 "stall_cpu_ms_per_s.serve", "follow_up_ms_per_step.serve")
 
 
 @pytest.fixture(scope="module")
@@ -77,6 +93,22 @@ def _prompts(model, sizes, seed=11):
 
 def _moved(after, before, name, field):
     return after["spans"][name][field] - before["spans"][name][field]
+
+
+def _health_ratio():
+    path = ROOT / "benchmarks" / "readers" / "health_ratio.py"
+    spec = importlib.util.spec_from_file_location("health_ratio", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def _stall_metric(name, before, after):
+    spec = json.loads((ROOT / "benchmarks" / "metrics"
+                       / f"{name}.json").read_text())
+    assert spec["reader"] == "health_ratio"
+    run = {"facts": {"health_before": before, "health_after": after}}
+    return _health_ratio().read(spec["args"], run)
 
 
 # ------------------------------------------------------------ primitive
@@ -113,6 +145,38 @@ class TestSpanPrimitive:
             pass
         assert totals.snapshot()["a"]["s"] >= 0.005
 
+    @pytest.mark.parametrize("seconds,bucket", [
+        (0.0002, "0"), (0.070, "64"), (2.0, "1024"), (30.0, "1024")])
+    def test_a_span_lands_in_the_bucket_of_its_milliseconds(
+            self, seconds, bucket):
+        totals = SpanTotals(("a",))
+        with span(totals, "a", since=time.perf_counter() - seconds):
+            pass
+        hist = totals.histogram()["a"]
+        assert list(hist) == [str(ms) for ms in profiler.BUCKETS_MS]
+        assert [k for k, cell in hist.items() if cell["n"]] == [bucket]
+        assert hist[bucket]["s"] == totals.snapshot()["a"]["s"] >= seconds
+        assert "cpu" not in hist[bucket]
+
+    @pytest.mark.parametrize("work", ["sleep", "spin"])
+    def test_cpu_seconds_tell_a_wait_from_computing(self, work):
+        totals = SpanTotals(("a", "b"), cpu=("a",))
+        with span(totals, "a"):
+            t0 = time.perf_counter()
+            if work == "sleep":
+                time.sleep(0.05)
+            else:
+                while time.perf_counter() - t0 < 0.05:
+                    pass
+        cells = [c for c in totals.histogram()["a"].values() if c["n"]]
+        assert len(cells) == 1
+        cell = cells[0]
+        if work == "sleep":
+            assert 0.0 <= cell["cpu"] < 0.2 * cell["s"]
+        else:
+            assert 0.3 * cell["s"] < cell["cpu"] <= 1.05 * cell["s"]
+        assert all("cpu" not in c for c in totals.histogram()["b"].values())
+
 
 # ------------------------------------------------- the serving path
 class TestServerSpans:
@@ -138,7 +202,26 @@ class TestServerSpans:
             again = server.health()
             summary = server.latency_summary()
             counts = dict(server.engine.trace_counts)
+        final = server.health()             # the worker has stopped
         assert set(after["spans"]) == set(SPANS)
+        assert set(final["span_hist"]) == set(SPANS)
+        for name in SPANS:
+            cells = final["span_hist"][name].values()
+            assert sum(c["n"] for c in cells) == final["spans"][name]["n"]
+            assert abs(sum(c["s"] for c in cells)
+                       - final["spans"][name]["s"]) < 1e-9
+            assert all(("cpu" in c) == (name == api.SERVE_STEP)
+                       for c in cells)
+        step_cells = final["span_hist"][api.SERVE_STEP].values()
+        assert 0 < sum(c["cpu"] for c in step_cells) \
+            <= 1.05 * final["spans"][api.SERVE_STEP]["s"]
+        # the worker waited for work (between the batches, and until
+        # the shutdown) and for follow-ups after the steps that freed
+        # a slot, outside the steps
+        for wait in SERVER_WAITS:
+            assert final["spans"][wait]["n"] > 0
+        for name in STALL_METRICS:
+            assert _stall_metric(name, before, after) >= 0.0
         steps = after["steps"] - before["steps"]
         assert steps > 0
         moved = {n: _moved(after, before, n, "n") for n in SPANS}
@@ -255,6 +338,10 @@ class TestServerSpans:
                     health = server.health()
                     for rec in health["spans"].values():
                         assert rec["n"] >= 0 and rec["s"] >= 0.0
+                    for hist in health["span_hist"].values():
+                        for cell in hist.values():
+                            assert cell["n"] >= 0 and cell["s"] >= 0.0
+                            assert cell.get("cpu", 0.0) >= 0.0
                     server.latency_summary()
                     reads[0] += 1
             except BaseException as exc:        # pragma: no cover
@@ -274,17 +361,28 @@ class TestServerSpans:
 
 # --------------------------------------------- on the profiler's clock
 def test_trace_holds_the_engine_spans_nested_on_one_line(gpt, tmp_path):
+    """A live server's spans, the worker's two waits included, lie on
+    the worker thread's line; the engine's nest in their steps."""
     from jax.profiler import ProfileData
 
     model, params = gpt
-    engine = PagedEngine(model, params, max_slots=2, block_size=8,
-                         prefill_chunk=4, pool_tokens=128)
-    sched = Scheduler(engine)
-    engine.warmup()
+    server = InferenceServer(model, params, max_slots=2, block_size=8,
+                             prefill_chunk=4, pool_tokens=128)
+    server.start()                  # warm-up compiles before the trace
     with utils.profiler.trace(str(tmp_path)):
-        req = sched.submit(Request(
-            prompt=np.arange(9, dtype=np.int32), max_new_tokens=4))
-        sched.drain()
+        handle = server.submit(np.arange(9, dtype=np.int32),
+                               max_new_tokens=4)
+        handle.result(timeout=300)
+        # the last step freed a slot with nobody queued: the worker
+        # waits for a follow-up, then for work until the shutdown
+        deadline = time.monotonic() + 60
+        while (server.health()["spans"][api.FOLLOW_UP]["n"] == 0
+               or server.scheduler.has_work()):
+            assert time.monotonic() < deadline
+            time.sleep(0.01)
+        time.sleep(0.2)
+        server.shutdown()
+    req = handle._request
     path = glob.glob(str(
         tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb"))[-1]
     lines = []
@@ -298,9 +396,14 @@ def test_trace_holds_the_engine_spans_nested_on_one_line(gpt, tmp_path):
     assert len(lines) == 1, "the spans of one thread lie on one line"
     evs = lines[0]
     names = {e[2] for e in evs}
-    assert names >= set(ENGINE_PARTS) | {
+    assert names >= set(ENGINE_PARTS) | set(SERVER_WAITS) | {
         engine_mod.STEP_PREFILL, engine_mod.STEP_DECODE,
-        scheduler.ADMIT, scheduler.ROUTE}
+        scheduler.ADMIT, scheduler.ROUTE, api.SERVE_STEP, api.DELIVER}
+    # the waits lie outside the steps
+    steps = [e for e in evs if e[2] == api.SERVE_STEP]
+    for s, t, name, _ in evs:
+        if name in SERVER_WAITS:
+            assert not any(a < t and s < b for a, b, _, _ in steps), name
     # a step is two events of its name, one a half: the dispatch half
     # (plan, dispatch) and, once the step before it is collected, the
     # collect half (fetch, commit)
@@ -421,6 +524,39 @@ def test_kernel_scopes_reach_the_lowered_text(scope):
     # ``jit(f)/jvp(attention.fwd)/pallas_call``,
     # ``.../transpose(jvp(attention.bwd_dq))/pallas_call``
     assert re.search(rf"[/(]{re.escape(scope)}\)*/pallas_call", text)
+
+
+# ------------------------------------------- the benchmark's readers
+SERVE_CELLS = ["mistral_7b_l8.chat_open_loop", "mistral_7b_l8.decode_heavy",
+               "falcon_h1_34b_l4.long_answers_closed_loop",
+               "trinity_large_l5_e32.short_long_mixed_closed_loop"]
+
+
+@pytest.mark.parametrize("name", STALL_METRICS)
+def test_stall_metric_lists_the_serve_cells_and_skips_an_older_program(
+        name):
+    """Each reads ``span_hist`` in the four serve cells; a program
+    without the field (the parent of the change that brought it) gives
+    no number, and a window without a stall reads 0."""
+    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
+    entry, = [m for m in bench["per_layer"] if m["name"] == name]
+    assert entry["workloads"] == SERVE_CELLS
+    assert entry["source"] == "program_counter"
+    old = {"steps": 10, "uptime_s": 1.0,
+           "spans": {api.SERVE_STEP: {"n": 10, "s": 0.1}}}
+    assert _stall_metric(name, old, dict(old, steps=30,
+                                         uptime_s=3.0)) is None
+    hist = SpanTotals(SPANS, cpu=(api.SERVE_STEP,))
+    for _ in range(20):             # 20 instances of 3 ms a name
+        for each in SPANS:
+            with span(hist, each, since=time.perf_counter() - 0.003):
+                pass
+    before = dict(old, span_hist=SpanTotals(
+        SPANS, cpu=(api.SERVE_STEP,)).histogram())
+    after = dict(old, steps=30, uptime_s=3.0, span_hist=hist.histogram())
+    # 60 ms of follow-ups over 20 steps; no instance of 64 ms or more
+    want = 3.0 if name == "follow_up_ms_per_step.serve" else 0.0
+    assert _stall_metric(name, before, after) == pytest.approx(want, rel=0.1)
 
 
 # --------------------------------------------------------------- docs
