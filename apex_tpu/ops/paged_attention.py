@@ -93,19 +93,28 @@ conventions:
   (``pltpu.PrefetchScalarGridSpec``), and the kernel fetches the
   row's live pages itself, in chunks of ``C = max(1, 128 //
   block_size)`` pages (about 128 key positions, the MXU's width):
-  one strided ``make_async_copy`` brings a page for all kv heads,
-  K and V double buffered in VMEM, the next chunk's copies started
-  before the current chunk's wait.  The trip count is the row's live
-  chunk count, ``ceil(((length + s - 1) // block_size + 1) / C)``,
-  so a call costs what its live tokens cost.  The sweep used to be a
-  third grid axis over ``pages_per_seq``, dead pages clamped and
-  ``pl.when``-skipped; that saved their bytes and not their time — a
-  grid step on this chip costs 0.2–0.35 µs whether or not it does
-  anything, and 16 × 8 × 256 of them made the decode kernel 10.8 ms
-  against 15 µs of live bytes (PERF.md §6, PR 30).  Online softmax
-  runs in the log2 domain with the transposed (keys-on-sublanes)
-  score tiles of ``ops/attention.py``, one ``(C·block_size,
-  rep·s)`` tile per chunk and kv head.
+  one strided ``make_async_copy`` brings a page for all kv heads.
+  The trip count is the row's live chunk count, ``ceil(((length + s
+  - 1) // block_size + 1) / C)``, so a call costs what its live
+  tokens cost.  The sweep used to be a third grid axis over
+  ``pages_per_seq``, dead pages clamped and ``pl.when``-skipped; that
+  saved their bytes and not their time — a grid step on this chip
+  costs 0.2–0.35 µs whether or not it does anything, and 16 × 8 × 256
+  of them made the decode kernel 10.8 ms against 15 µs of live bytes
+  (PERF.md §6).  The chunks of all rows are ONE stream of
+  fetches, run in the grid's order: while a chunk is attended the two
+  after it are in flight in VMEM's other two slots, whether they are
+  the row's own or the next rows' first (the scalar-prefetched
+  lengths and block tables say which pages those are), and the fused
+  kernel's page write-back is waited one row later — so neither a row
+  nor a chunk boundary drains the DMA queue (PERF.md §6).
+  Online softmax runs in the log2 domain, one score tile per chunk
+  and kv head: a wide step's (a prefill chunk's) is the transposed
+  ``(C·block_size, rep·s)`` tile of ``ops/attention.py``, keys on
+  sublanes; a narrow step's (decode, a speculative verify) is ``(rep·s,
+  C·block_size)``, keys on lanes, a register or two, and every head's
+  tile is issued before any head's statistics so that the heads'
+  matrix-unit round trips overlap.
 - **XLA gather reference** (``implementation="xla"``; golden semantics,
   CPU/GPU fallback): ``k_pages[:, block_tables]`` then a masked fp32
   einsum — bit-comparable to the dense cache's attention in
@@ -462,55 +471,140 @@ def _live_pages(length, s, bs, mb):
     """Pages holding a key some query of the row can see: the prefix
     up to the newest query's position, clamped to the table (a cursor
     at or past the table's end sweeps all of it)."""
-    return jnp.minimum(jnp.maximum(length + s - 1, 0) // bs + 1, mb)
+    return jnp.minimum(
+        jax.lax.div(jnp.maximum(length + s - 1, 0), bs) + 1, mb)
+
+
+def _pick(pred, a, b):
+    """``a`` where ``pred``, else ``b``: the sweep's scalar index
+    arithmetic is plain ``lax`` (a select, a division or remainder of
+    non-negative values), because ``jnp.where``, ``//`` and ``%`` each
+    lower a nested function, and every kernel body is lowered again at
+    each start of the program."""
+    return jax.lax.select(pred, jnp.asarray(a, jnp.int32),
+                          jnp.asarray(b, jnp.int32))
+
+
+#: chunk buffers of the sweep: the chunk attended and the two behind it
+#: in flight (PERF.md §6)
+_SLOTS = 3
+
+#: a sweep's pallas_call runs its rows in order: each hands the stream
+#: of chunk fetches on to the rows after it
+_ROWS_IN_ORDER = pltpu.CompilerParams(dimension_semantics=("arbitrary",))
+
+
+def _key_axis(lanes):
+    """The axis of a sweep's score tile that holds the chunk's keys.
+
+    A wide step (a prefill chunk: ``rep·s`` of 128 query lanes or
+    more) puts the keys on sublanes — ``(C·bs, rep·s)``, the
+    ops/attention.py layout, measured there — so the query lanes fill
+    the vector registers.  A narrow one (decode: ``rep`` of 4–6 query
+    rows; a speculative verify) puts them on lanes — ``(rep·s, C·bs)``
+    — where the transposed tile would be 16 registers of which a few
+    lanes are real, and every statistic, exponential and the value
+    contraction's transpose would pay for all of them (PERF.md
+    §6)."""
+    return 0 if lanes >= 128 else 1
 
 
 def _sweep_scratch(hk, bs, d, lanes, pool_dtype):
-    """Scratch of :func:`_sweep_row`: the double-buffered K and V
-    chunk tiles for all kv heads, their DMA semaphores (side × slot),
-    and the per-head online-softmax state."""
+    """Scratch of :func:`_sweep_row`: the K and V chunk tiles for all
+    kv heads in :data:`_SLOTS` slots, their DMA semaphores (side ×
+    slot), the slot of the row's first chunk (carried from row to
+    row), and the per-head online-softmax state, laid out along
+    :func:`_key_axis`'s other axis."""
     t = _chunk_pages(bs) * bs
+    keys_on_sublanes = _key_axis(lanes) == 0
+    stat = (1, lanes) if keys_on_sublanes else (lanes, 1)
     return [
-        pltpu.VMEM((2, hk, t, d), pool_dtype),           # K chunks
-        pltpu.VMEM((2, hk, t, d), pool_dtype),           # V chunks
-        pltpu.SemaphoreType.DMA((2, 2)),
-        pltpu.VMEM((hk, 1, lanes), jnp.float32),         # m (lane rows)
-        pltpu.VMEM((hk, 1, lanes), jnp.float32),         # l (lane rows)
-        pltpu.VMEM((hk, d, lanes), jnp.float32),         # transposed acc
+        pltpu.VMEM((_SLOTS, hk, t, d), pool_dtype),      # K chunks
+        pltpu.VMEM((_SLOTS, hk, t, d), pool_dtype),      # V chunks
+        pltpu.SemaphoreType.DMA((2, _SLOTS)),
+        pltpu.SMEM((1,), jnp.int32),                     # first slot
+        pltpu.VMEM((hk, *stat), jnp.float32),            # m
+        pltpu.VMEM((hk, *stat), jnp.float32),            # l
+        pltpu.VMEM((hk, *((d, lanes) if keys_on_sublanes
+                          else (lanes, d))), jnp.float32),   # acc
     ]
 
 
-def _sweep_row(tables_ref, row, length, k_hbm, v_hbm, scratch, o_ref, *,
+def _row_span(length, *, s, bs, mb, window):
+    """A row's sweep: its live pages, the first chunk a query of the
+    row can see into (a static 0 without a window: the window's start
+    of the row's first query lane otherwise) and its chunk count —
+    never 0, so every row fetches and waits at least one chunk."""
+    c_pages = _chunk_pages(bs)
+    live = _live_pages(length, s, bs, mb)
+    n_chunks = jax.lax.div(live + c_pages - 1, c_pages)
+    first = 0 if window is None else jnp.minimum(
+        jax.lax.div(jnp.maximum(length - window + 1, 0), c_pages * bs),
+        n_chunks - 1)
+    return live, first, n_chunks
+
+
+def _stream_ahead(k, c, n_chunks, first1, n1, first2):
+    """Where the grid's stream of chunks is ``k`` (at most 2) chunks
+    after chunk ``c`` of a row of ``n_chunks``, as ``(rows on,
+    chunk)``: in this row, in the next (its sweep runs from ``first1``
+    to ``n1``) or in the one after (its ``first2``; every row sweeps
+    at least one chunk, so two chunks on is never past its first)."""
+    past = k - (n_chunks - c)          # chunks into the rows after this
+    on = _pick(past < 0, 0, _pick(past < n1 - first1, 1, 2))
+    chunk = _pick(on == 0, c + k, _pick(on == 1, first1 + past, first2))
+    return on, chunk
+
+
+def _sweep_row(tables_ref, lens_ref, k_hbm, v_hbm, scratch, o_ref, *,
                s, bs, rep, mb, q_tile, qmax=None, page_scales=None,
                on_last_chunk=None, window=None):
     """One row's online-softmax sweep over its LIVE pages — the one
     body behind both kernels (the masking/softmax algebra must never
-    fork).
+    fork).  The row is the grid's (``pl.program_id(0)``), its length
+    ``lens_ref[row]``.
 
     The pool stays in HBM (``k_hbm``/``v_hbm``, unblocked); the row's
     live pages arrive in CHUNKS of :func:`_chunk_pages` pages by the
     kernel's own DMA — one strided copy brings a page for ALL kv heads
-    — into a double-buffered VMEM tile, the next chunk's copies
-    starting before the current chunk's wait.  The trip count is the
+    — into one of :data:`_SLOTS` VMEM tiles.  The trip count is the
     row's live chunk count, so a call costs what its live tokens cost,
     whatever the table's width.  Page slots of the last chunk past the
     row's last live page re-fetch that page (every buffer row holds
     real pool data); the position mask makes them unreachable.
 
-    Score tiles are TRANSPOSED — ``(C·bs, rep·s)``: key slots on
-    sublanes, (q-head, chunk-offset) lanes — so the softmax statistics
-    are native lane rows and the value accumulation contracts over the
-    chunk at full MXU shape (the ops/attention.py layout, measured
-    there).  Lane ``l`` holds q head ``l // s`` at chunk offset
-    ``l % s``.  ``q_tile(head)`` returns the head's scaled ``(rep·s,
-    d)`` queries (log2 domain).
+    The fetches are ONE STREAM over the grid: the chunks of row 0,
+    then row 1's, and so on, each in slot ``ordinal mod 3``.  While a
+    chunk is attended the two after it in the stream are in flight —
+    the row's next two, or the next rows' first ones, read from the
+    scalar-prefetched lengths and block tables (:func:`_stream_ahead`)
+    — so a row starts with its data on the way and a short row costs
+    no round trip of its own.  The grid's first step starts the
+    stream's first two chunks; nothing is started past the last row;
+    each chunk is waited by its own row, so the semaphores balance.
+    The rows must run in order (:data:`_ROWS_IN_ORDER`).  No row reads
+    a live page another row writes in the call: a row's write page is
+    its own last page (the engine forks a shared page before a write),
+    and a row that may not write starts no write.
+
+    A wide step's score tiles are TRANSPOSED — ``(C·bs, rep·s)``: key
+    slots on sublanes, (q-head, chunk-offset) lanes — so the softmax
+    statistics are native lane rows and the value accumulation
+    contracts over the chunk at full MXU shape (the ops/attention.py
+    layout, measured there).  Lane ``l`` holds q head ``l // s`` at
+    chunk offset ``l % s``.  A narrow step's are ``(rep·s, C·bs)``,
+    the same queries on sublanes and the keys on lanes
+    (:func:`_key_axis`); the algebra is the same along the other axis.
+    ``q_tile(head)`` returns the head's scaled ``(rep·s, d)`` queries
+    (log2 domain).
 
     With ``qmax`` set the buffers hold int8/fp8 codes and
     ``page_scales(head, page)`` returns the page's fp32 K and V amax
     scales as (1, 1) tiles.  The per-page dequant multiplier
     ``scale/qmax`` is constant over its page's ``bs`` rows of the
     chunk tile, so it factors out of both contractions as a
-    ``(C·bs, 1)`` column: codes are cast up (exact — |int8| ≤ 127 and
+    ``(C·bs, 1)`` column (a ``(1, C·bs)`` row of a narrow step's
+    scores): codes are cast up (exact — |int8| ≤ 127 and
     e4m3 fit any float) for the MXU dot, and the product is rescaled
     in-register before the log2-domain softmax statistics (scores) /
     the output accumulation (values).
@@ -529,111 +623,154 @@ def _sweep_row(tables_ref, row, length, k_hbm, v_hbm, scratch, o_ref, *,
     ``window=None`` is the sweep it always was, instruction for
     instruction.
     """
-    kbuf, vbuf, sems, m_ref, l_ref, acc_ref = scratch
+    kbuf, vbuf, sems, slot_ref, m_ref, l_ref, acc_ref = scratch
     _slots, hk, t, _d = kbuf.shape
     c_pages = t // bs
     lanes = rep * s
-    live = _live_pages(length, s, bs, mb)
-    n_chunks = (live + c_pages - 1) // c_pages
-    # the first chunk a query of the row can see into: a static 0
-    # without a window
-    first = 0 if window is None else jnp.minimum(
-        jnp.maximum(length - window + 1, 0) // t, n_chunks - 1)
+    row, rows = pl.program_id(0), pl.num_programs(0)
+    length = lens_ref[row]
+    # this row's sweep and the next two rows' (clamped to the grid: a
+    # row past it is never started)
+    (live, first, n_chunks), (live1, first1, n1), (live2, first2, _) = [
+        _row_span(lens_ref[jnp.minimum(row + on, rows - 1)], s=s, bs=bs,
+                  mb=mb, window=window) for on in range(3)]
+    # the slot of the row's first chunk: the stream's ordinal mod 3
+    base = _pick(row == 0, 0, slot_ref[0])
 
-    def copies(c, slot):
+    def copies(r, r_live, c, slot):
         out = []
         for i in range(c_pages):
-            phys = tables_ref[row, jnp.minimum(c * c_pages + i, live - 1)]
-            rows = pl.ds(i * bs, bs)
+            phys = tables_ref[r, jnp.minimum(c * c_pages + i, r_live - 1)]
+            span = pl.ds(i * bs, bs)
             out.append(pltpu.make_async_copy(
-                k_hbm.at[:, phys], kbuf.at[slot, :, rows],
+                k_hbm.at[:, phys], kbuf.at[slot, :, span],
                 sems.at[0, slot]))
             out.append(pltpu.make_async_copy(
-                v_hbm.at[:, phys], vbuf.at[slot, :, rows],
+                v_hbm.at[:, phys], vbuf.at[slot, :, span],
                 sems.at[1, slot]))
         return out
 
-    for cp in copies(first, first % 2):
-        cp.start()
+    def fetch(k, c, slot):
+        # start the stream's chunk k places after chunk c of this row
+        on, chunk_no = _stream_ahead(k, c, n_chunks, first1, n1, first2)
+        r_live = _pick(on == 0, live, _pick(on == 1, live1, live2))
+
+        @pl.when(row + on < rows)
+        def _start():
+            for cp in copies(row + on, r_live, chunk_no, slot):
+                cp.start()
+
+    @pl.when(row == 0)
+    def _open_stream():
+        def one(k, carry):
+            fetch(k, first, k)
+            return carry
+        jax.lax.fori_loop(0, 2, one, None)
+
     m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
     l_ref[...] = jnp.zeros_like(l_ref)
     acc_ref[...] = jnp.zeros_like(acc_ref)
+    ax = _key_axis(lanes)
+    tile = (t, lanes) if ax == 0 else (lanes, t)
 
     def chunk(c, carry):
-        slot = c % 2
-
-        @pl.when(c + 1 < n_chunks)
-        def _prefetch():
-            for cp in copies(c + 1, 1 - slot):
-                cp.start()
-
-        for cp in copies(c, slot):
+        slot = jax.lax.rem(base + c - first, _SLOTS)
+        fetch(2, c, jax.lax.rem(slot + 2, _SLOTS))
+        for cp in copies(row, live, c, slot):
             cp.wait()
         if on_last_chunk is not None:
             pl.when(c == n_chunks - 1)(lambda: on_last_chunk(slot))
 
-        k_pos = c * t + jax.lax.broadcasted_iota(
-            jnp.int32, (t, lanes), 0)
-        q_off = jax.lax.broadcasted_iota(
-            jnp.int32, (t, lanes), 1) % s
+        k_pos = c * t + jax.lax.broadcasted_iota(jnp.int32, tile, ax)
+        q_off = jax.lax.broadcasted_iota(jnp.int32, tile, 1 - ax) % s
         # visible up to the query's own position, and never past the
         # table (the last chunk's spare slots re-fetch the last live
         # page: a cursor at or past the table's end must not see them)
         dead = k_pos > jnp.minimum(length + q_off, mb * bs - 1)
         if window is not None:
             dead |= k_pos <= length + q_off - window
-        for head in range(hk):
-            qs = q_tile(head)
+
+        def scores(head):
+            qs = q_tiles[head] if q_tiles else q_tile(head)
             kt = kbuf[slot, head]
             kq = kt if qmax is None else kt.astype(qs.dtype)  # exact
             sc = jax.lax.dot_general(
-                kq, qs, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)      # (t, lanes)
+                *((kq, qs) if ax == 0 else (qs, kq)),
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)      # tile
+            vcol = None
             if qmax is not None:
                 per_page = [page_scales(head, c * c_pages + i)
                             for i in range(c_pages)]
-                kcol, vcol = (
-                    jnp.concatenate(
-                        [jnp.broadcast_to(p[side], (bs, 1))
-                         for p in per_page], axis=0)
-                    * jnp.float32(1.0 / qmax) for side in (0, 1))
+                unit = jnp.float32(1.0 / qmax)
+                # a page's scale over its bs keys: along the score
+                # tile's key axis, down the value tile's rows
+                kscale = jnp.concatenate(
+                    [jnp.broadcast_to(ks, (bs, 1) if ax == 0 else (1, bs))
+                     for ks, _ in per_page], axis=ax) * unit
+                vcol = jnp.concatenate(
+                    [jnp.broadcast_to(vs, (bs, 1)) for _, vs in per_page],
+                    axis=0) * unit
                 # in-register dequant: one f32 multiply per score tile
-                sc = sc * kcol
-            sc = jnp.where(dead, _NEG_INF, sc)
-            m_prev = m_ref[head]                         # (1, lanes)
+                sc = sc * kscale
+            return jnp.where(dead, _NEG_INF, sc), vcol
+
+        def stats(head, sc):
+            m_prev = m_ref[head]                         # per query
             m_new = jnp.maximum(m_prev,
-                                jnp.max(sc, axis=0, keepdims=True))
-            # every lane sees >= 1 live key in chunk 0 (position 0 is
+                                jnp.max(sc, axis=ax, keepdims=True))
+            # every query sees >= 1 live key in chunk 0 (position 0 is
             # always visible), so m is finite from the first chunk on
             # and exp2(-1e30 - m) underflows to exactly 0 at dead
             # positions — no explicit dead-row zeroing needed (see
             # ops/attention.py)
             p = jnp.exp2(sc - m_new)
             if window is not None:
-                # a lane whose window starts in a later chunk has seen
+                # a query whose window starts in a later chunk has seen
                 # no live key yet: m is still the floor and exp2(0) = 1
                 p = jnp.where(dead, 0.0, p)
             alpha = jnp.exp2(m_prev - m_new)
             l_ref[head] = l_ref[head] * alpha + jnp.sum(
-                p, axis=0, keepdims=True)
+                p, axis=ax, keepdims=True)
+            m_ref[head] = m_new
+            return p, alpha
+
+        def accumulate(head, p, alpha, vcol):
             vt = vbuf[slot, head]
             if qmax is None:
                 vq, pv = vt, p.astype(vt.dtype)
             else:
                 vq, pv = vt.astype(jnp.float32) * vcol, p
-            acc_ref[head] = acc_ref[head] * alpha + jax.lax.dot_general(
-                vq, pv, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)      # (d, lanes)
-            m_ref[head] = m_new
+            acc_ref[head] = acc_ref[head] * alpha + (
+                jax.lax.dot_general(vq, pv, (((0,), (0,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+                if ax == 0 else          # (d, lanes), else (lanes, d)
+                jax.lax.dot_general(pv, vq, (((1,), (0,)), ((), ())),
+                                    preferred_element_type=jnp.float32))
+
+        # narrow tiles are a register each: every head's scores are
+        # issued, then every head's statistics, then every head's value
+        # products, so the heads' matrix-unit round trips overlap; a
+        # wide tile is attended head by head
+        wave = hk if ax == 1 else 1
+        for h0 in range(0, hk, wave):
+            heads = range(h0, h0 + wave)
+            tiles = [scores(head) for head in heads]
+            probs = [stats(head, sc) for head, (sc, _) in zip(heads, tiles)]
+            for head, (_, vcol), (p, alpha) in zip(heads, tiles, probs):
+                accumulate(head, p, alpha, vcol)
         return carry
 
+    # a narrow step's queries are rotated and scaled once a row, not
+    # once a chunk (the same values: bitwise the same sweep)
+    q_tiles = [q_tile(head) for head in range(hk)] if ax == 1 else None
     jax.lax.fori_loop(first, n_chunks, chunk, None)
+    slot_ref[0] = jax.lax.rem(base + n_chunks - first, _SLOTS)
 
     for head in range(hk):
         l = l_ref[head]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, head] = jnp.transpose(acc_ref[head] / l_safe).astype(
-            o_ref.dtype)
+        out = acc_ref[head] / jnp.where(l == 0.0, 1.0, l)
+        o_ref[0, head] = (out.T if ax == 0 else out).astype(o_ref.dtype)
 
 
 def _paged_kernel(tables_ref, lens_ref, q_ref, k_hbm, v_hbm, *refs,
@@ -649,7 +786,6 @@ def _paged_kernel(tables_ref, lens_ref, q_ref, k_hbm, v_hbm, *refs,
         o_ref, *scratch = refs
     else:
         ks_ref, vs_ref, o_ref, *scratch = refs
-    row = pl.program_id(0)
 
     def q_tile(head):
         return q_ref[0, head] * jnp.asarray(scale * _LOG2E, q_ref.dtype)
@@ -658,9 +794,9 @@ def _paged_kernel(tables_ref, lens_ref, q_ref, k_hbm, v_hbm, *refs,
         return (_page_scale(ks_ref[0, head], j),
                 _page_scale(vs_ref[0, head], j))
 
-    _sweep_row(tables_ref, row, lens_ref[row], k_hbm, v_hbm, scratch,
-               o_ref, s=s, bs=bs, rep=rep, mb=mb, q_tile=q_tile,
-               qmax=qmax, page_scales=page_scales, window=window)
+    _sweep_row(tables_ref, lens_ref, k_hbm, v_hbm, scratch, o_ref, s=s,
+               bs=bs, rep=rep, mb=mb, q_tile=q_tile, qmax=qmax,
+               page_scales=page_scales, window=window)
 
 
 def _run_paged(q4, k_pages, v_pages, tables, lengths, scale, interpret,
@@ -699,6 +835,7 @@ def _run_paged(q4, k_pages, v_pages, tables, lengths, scale, interpret,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((b, hk, rep * s, d),
                                            q4.dtype),
+            compiler_params=_ROWS_IN_ORDER,
             interpret=interpret,
         )(*args)
     return (o3.reshape(b, hk, rep, s, d)
@@ -831,7 +968,9 @@ def _paged_fused_kernel(tables_ref, lens_ref, wphys_ref, woff_ref,
     buffer, so the new token is visible to its own query
     (write-then-attend) without a pool round-trip, and one DMA per
     side copies the patched page for all heads back into the pool
-    through the aliased output instead of a separate XLA scatter pass.
+    through the aliased output instead of a separate XLA scatter pass;
+    the next row waits for it as it begins (the copy's slot is not
+    refilled before), the last row before it ends.
 
     Extra scalar prefetch vs the plain kernel: ``wphys``/``woff`` (the
     write page and offset, null-routed on the host side of the trace)
@@ -859,13 +998,16 @@ def _paged_fused_kernel(tables_ref, lens_ref, wphys_ref, woff_ref,
          wsem, *scratch) = rest
     kbuf, vbuf = scratch[:2]
     _slots, hk, t, d = kbuf.shape
-    row = pl.program_id(0)
+    row, rows = pl.program_id(0), pl.num_programs(0)
+
+    def may_write(r):
+        return (lens_ref[r] < S) & (real_ref[r] != 0)
 
     length = lens_ref[row]
     wphys = wphys_ref[row]
     woff = woff_ref[row]
     real = real_ref[row] != 0
-    write_ok = (length < S) & real
+    write_ok = may_write(row)
     wpage = _live_pages(length, 1, bs, mb) - 1   # the sweep's last page
     wslot = wpage % (t // bs)                    # its place in the chunk
 
@@ -898,11 +1040,15 @@ def _paged_fused_kernel(tables_ref, lens_ref, wphys_ref, woff_ref,
         return y.astype(kbuf.dtype)
 
     def _writeback(slot, i):
-        rows = pl.ds(i * bs, bs)
-        return (pltpu.make_async_copy(kbuf.at[slot, :, rows],
+        span = pl.ds(i * bs, bs)
+        return (pltpu.make_async_copy(kbuf.at[slot, :, span],
                                       kp_out.at[:, wphys], wsem.at[0]),
-                pltpu.make_async_copy(vbuf.at[slot, :, rows],
+                pltpu.make_async_copy(vbuf.at[slot, :, span],
                                       vp_out.at[:, wphys], wsem.at[1]))
+
+    def _landed():
+        for cp in _writeback(0, 0):      # any same-sized source waits
+            cp.wait()
 
     def _prologue(slot):
         # the row lands at its sublane of the chunk tile by a masked
@@ -959,15 +1105,17 @@ def _paged_fused_kernel(tables_ref, lens_ref, wphys_ref, woff_ref,
                 jnp.where(use_new, new[:, 1:2],
                           _page_scale(vs_ref[0, head], j)))
 
-    _sweep_row(tables_ref, row, length, k_hbm, v_hbm, scratch, o_ref,
-               s=1, bs=bs, rep=rep, mb=mb, q_tile=q_tile, qmax=qmax,
+    # the previous row's page went home from the slot of its last chunk
+    # while that chunk was attended; the stream refills that slot no
+    # sooner than this row's first chunk's fetch two ahead, so its
+    # wait comes here, before the sweep — and the last row drains its
+    # own
+    pl.when((row > 0) & may_write(jnp.maximum(row - 1, 0)))(_landed)
+    _sweep_row(tables_ref, lens_ref, k_hbm, v_hbm, scratch, o_ref, s=1,
+               bs=bs, rep=rep, mb=mb, q_tile=q_tile, qmax=qmax,
                page_scales=page_scales, on_last_chunk=_prologue,
                window=window)
-
-    @pl.when(write_ok)
-    def _landed():
-        for cp in _writeback(0, 0):      # any same-sized source waits
-            cp.wait()
+    pl.when(write_ok & (row == rows - 1))(_landed)
 
 
 def _run_decode_fused(q4, k_new, v_new, k_pages, v_pages, tables,
@@ -1063,6 +1211,7 @@ def _run_decode_fused(q4, k_new, v_new, k_pages, v_pages, tables,
             # (5), k_pages (6), v_pages (7) — aliased to the pool
             # outputs, so the one page a row writes moves in place
             input_output_aliases={6: 1, 7: 2},
+            compiler_params=_ROWS_IN_ORDER,
             interpret=interpret,
         )(*args)
     o3 = outs[0].reshape(b, hk, rep, 1, d) \

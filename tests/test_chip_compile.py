@@ -122,7 +122,9 @@ def _pool(bs, dtype, pool_tokens):
             ((B, MAX_SEQ // bs), jnp.int32), ((B,), jnp.int32)), nb
 
 
-@pytest.mark.parametrize("s", [1, 32])        # decode, prefill chunk
+# decode and a speculative verify sweep keys on lanes, a prefill chunk
+# on sublanes (paged_attention._key_axis)
+@pytest.mark.parametrize("s", [1, 32, 4])     # decode, prefill, verify
 @pytest.mark.parametrize("bs", [16, 128])     # engine default, MXU-wide
 @pytest.mark.parametrize("kv", ["bf16", "int8"])
 @pytest.mark.parametrize("pool_tokens", POOLS)
@@ -200,7 +202,7 @@ def _falcon_pool(bs=16):
             ((FB, F_SEQ // bs), jnp.int32), ((FB,), jnp.int32))
 
 
-@pytest.mark.parametrize("s", [1, 32])
+@pytest.mark.parametrize("s", [1, 32, 4])
 def test_paged_attention_five_query_heads_a_kv_head(compile_for_chip, s):
     def fn(q, kp, vp, bt, ln):
         return paged_attention(q, kp, vp, bt, ln, implementation="pallas")

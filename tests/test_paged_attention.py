@@ -73,6 +73,38 @@ def _pool_setup(rng, *, b, hk, d, NB, BS, MB, lengths, s, dtype,
     return kp, vp, tables
 
 
+#: batches whose neighbouring rows hand the kernels' stream of chunk
+#: fetches on across every kind of row boundary (pages of 8 tokens,
+#: sweep chunks of 128 keys, ``max_seq_len`` 1280): rows of 1, 2 and 9
+#: chunks side by side, a row at ``max_seq_len``, two rows on one
+#: copy-on-write prefix (each writing a page of its own), one row alone
+_HANDOFF_S = 1280
+_HANDOFFS = {
+    "chunks-1-2-9": dict(lengths=[100, 200, 1100, 5, 1100, 130]),
+    "row-at-S": dict(lengths=[300, _HANDOFF_S, 40]),
+    "cow-prefix": dict(lengths=[300, 500, 60], share=37),
+    "one-row": dict(lengths=[700]),
+}
+#: a pad lane of a coded pool (``chunk_lens`` 0) between real rows
+_PAD_ROW = dict(lengths=[300, 5, 1100, 40], pads=(1,))
+
+
+def _kept_ids(argvalues, *handoffs):
+    """An existing parametrisation's cases, on their own rows (batch
+    ``None``) under the ids they always had, then the hand-off batches
+    ``(name, args...)`` appended."""
+    kept = []
+    for v in argvalues:
+        vals = v.values if hasattr(v, "values") else (
+            v if isinstance(v, tuple) else (v,))
+        kept.append(pytest.param(
+            *vals, None, marks=getattr(v, "marks", ()),
+            id="-".join(getattr(x, "__name__", str(x)) for x in vals)))
+    return kept + [pytest.param(*args, name, id="-".join(
+        [*(getattr(x, "__name__", str(x)) for x in args), name]))
+        for name, *args in handoffs]
+
+
 class TestGoldenKernel:
     @pytest.mark.parametrize("s,h,hk,dtype", [
         (1, 4, 4, jnp.float32),        # pure decode, MHA
@@ -217,17 +249,24 @@ class TestQuantizedKernel:
     golden suite uses — the two paths share the online-softmax
     algebra, only the dequant site differs."""
 
-    @pytest.mark.parametrize("kv_dtype", _KV_DTYPES)
-    @pytest.mark.parametrize("s,h,hk", [
-        (1, 4, 4),        # pure decode, MHA
-        (1, 8, 2),        # decode, GQA 4:1
-        (4, 4, 2),        # chunk queries (spec-verify shape), GQA
-    ])
+    @pytest.mark.parametrize("s,h,hk,kv_dtype,batch", _kept_ids(
+        [pytest.param(*shape, kv, marks=marks) for shape in [
+            (1, 4, 4),        # pure decode, MHA
+            (1, 8, 2),        # decode, GQA 4:1
+            (4, 4, 2),        # chunk queries (spec-verify shape), GQA
+        ] for kv, marks in (("int8", ()), ("fp8", _KV_DTYPES[1].marks))],
+        ("chunks-1-2-9", 1, 10, 2, "int8"), ("chunks-1-2-9", 4, 8, 2, "int8"),
+        ("one-row", 1, 8, 2, "fp8")))
     def test_kernel_matches_quant_dequant_reference(self, s, h, hk,
-                                                    kv_dtype):
+                                                    kv_dtype, batch):
         rng = np.random.default_rng(10)
         b, d, NB, BS, MB = 3, 32, 24, 8, 6
         lengths = [9, 0, 27]
+        if batch is not None:
+            lengths = [min(n, _HANDOFF_S - s)
+                       for n in _HANDOFFS[batch]["lengths"]]
+            b, MB = len(lengths), _HANDOFF_S // BS
+            NB = sum(-(-(n + s) // BS) for n in lengths) + 1
         kp, vp, tables = _pool_setup(
             rng, b=b, hk=hk, d=d, NB=NB, BS=BS, MB=MB,
             lengths=lengths, s=s, dtype=jnp.float32)
@@ -627,10 +666,18 @@ class TestFusedDecodePrologue:
     to the kernel's blocked accumulation order."""
 
     def _setup(self, rng, *, b=3, h=8, hk=4, d=32, BS=8, S=64,
-               kv_dtype=None, lengths=None):
+               kv_dtype=None, lengths=None, share=0, pads=()):
+        """``share``: row 1 reads row 0's first ``share`` pages (a
+        copy-on-write prefix, both rows writing pages of their own);
+        ``pads``: rows that are pad lanes of a coded pool."""
         from apex_tpu.ops.paged_attention import quantize_kv_pages
         from apex_tpu.ops.rope import rope_cos_sin
 
+        if lengths is None:
+            # fresh-page, mid-page and page-boundary-append rows
+            lengths = np.array([5, BS, 3 * BS - 1], np.int32)[:b]
+        lengths = np.asarray(lengths, np.int32)
+        b = len(lengths)
         MB = S // BS
         NB = b * MB + 3
         kp = jnp.asarray(rng.normal(size=(hk, NB, BS, d)), jnp.float32)
@@ -638,17 +685,20 @@ class TestFusedDecodePrologue:
         scales = {}
         if kv_dtype is not None:
             kp, vp, ks, vs = quantize_kv_pages(kp, vp, kv_dtype)
+            cl = np.ones((b,), np.int32)
+            cl[list(pads)] = 0
             scales = dict(k_scales=ks, v_scales=vs,
-                          chunk_lens=jnp.ones((b,), jnp.int32))
-        if lengths is None:
-            # fresh-page, mid-page and page-boundary-append rows
-            lengths = np.array([5, BS, 3 * BS - 1], np.int32)[:b]
+                          chunk_lens=jnp.asarray(cl))
         tables = np.zeros((b, MB), np.int32)
         used = rng.permutation(np.arange(1, NB))[: b * MB] \
             .reshape(b, MB)
         for r in range(b):
             npages = min(MB, -(-int(min(lengths[r], S - 1) + 1) // BS))
             tables[r, :npages] = used[r, :npages]
+        if share:
+            # full pages before both rows' write pages
+            assert share <= min(lengths[:2]) // BS
+            tables[1, :share] = tables[0, :share]
         q = jnp.asarray(rng.normal(size=(b, 1, h, d)), jnp.float32)
         nk = jnp.asarray(rng.normal(size=(b, 1, hk, d)), jnp.float32)
         nv = jnp.asarray(rng.normal(size=(b, 1, hk, d)), jnp.float32)
@@ -701,11 +751,14 @@ class TestFusedDecodePrologue:
             np.testing.assert_array_equal(np.asarray(a),
                                           np.asarray(b))
 
-    @pytest.mark.parametrize("h,hk", [(8, 4), (10, 2)])   # 2:1, 5:1
-    def test_kernel_matches_reference_unquantized(self, h, hk):
+    @pytest.mark.parametrize("h,hk,batch", _kept_ids(
+        [(8, 4), (10, 2)],                                   # 2:1, 5:1
+        *[(name, 10, 2) for name in _HANDOFFS]))
+    def test_kernel_matches_reference_unquantized(self, h, hk, batch):
         rng = np.random.default_rng(4)
+        kw = {} if batch is None else dict(S=_HANDOFF_S, **_HANDOFFS[batch])
         (q, nk, nv, kp, vp, tables, lengths, rope, sc, S,
-         live) = self._setup(rng, h=h, hk=hk)
+         live) = self._setup(rng, h=h, hk=hk, **kw)
         args = (q, nk, nv, kp, vp, tables, lengths)
         ref = self._run("xla", args, S, rope, sc)
         got = self._run("pallas_interpret", args, S, rope, sc)
@@ -718,13 +771,18 @@ class TestFusedDecodePrologue:
             np.testing.assert_array_equal(
                 np.asarray(got[i][:, live]), np.asarray(ref[i][:, live]))
 
-    @pytest.mark.parametrize("kv_dtype", _KV_DTYPES)
-    def test_kernel_matches_reference_quantized(self, kv_dtype):
+    @pytest.mark.parametrize("kv_dtype,batch", _kept_ids(
+        _KV_DTYPES, ("chunks-1-2-9", "int8"), ("cow-prefix", "int8"),
+        ("pad-row", "int8"), ("row-at-S", "int8")))
+    def test_kernel_matches_reference_quantized(self, kv_dtype, batch):
         """Codes AND monotone running-amax scales bitwise on live
-        pages — the PR-8 scale discipline survives the fusion."""
+        pages — the PR-8 scale discipline survives the fusion; a pad
+        lane between real rows writes nothing."""
         rng = np.random.default_rng(5)
+        kw = {} if batch is None else dict(
+            S=_HANDOFF_S, **_HANDOFFS.get(batch, _PAD_ROW))
         (q, nk, nv, kp, vp, tables, lengths, rope, sc, S,
-         live) = self._setup(rng, kv_dtype=kv_dtype)
+         live) = self._setup(rng, kv_dtype=kv_dtype, **kw)
         args = (q, nk, nv, kp, vp, tables, lengths)
         ref = self._run("xla", args, S, rope, sc)
         got = self._run("pallas_interpret", args, S, rope, sc)
@@ -1126,6 +1184,9 @@ class TestSweepFollowsLiveTokensNotTheTable:
                  if e.primitive.name == "pallas_call"]
         assert len(calls) == 1
         assert tuple(calls[0].params["grid_mapping"].grid) == (3,)
+        # the rows hand the stream of chunk fetches on: run in order
+        assert calls[0].params["compiler_params"]["mosaic_tpu"] \
+            .dimension_semantics == ("arbitrary",)
 
 
 # --------------------------------------------------------------------- #
@@ -1192,21 +1253,31 @@ class TestWindow:
     _FP8 = pytest.mark.skipif(not hasattr(jnp, "float8_e4m3fn"),
                               reason="no float8_e4m3fn in this jax build")
 
-    @pytest.mark.parametrize("s,window,dtype,kv_dtype", [
+    #: rows whose window starts in a later chunk beside rows that the
+    #: window does not cut: the stream hands on between sweeps that
+    #: start at chunk 0 and sweeps that start at chunk 1 or 2
+    _AFTER_SHORT = [5, 280, 40, 270, 131]
+
+    @pytest.mark.parametrize("s,window,dtype,kv_dtype,batch", _kept_ids([
         (1, 5, jnp.float32, None), (1, 9, jnp.float32, "int8"),
         (1, 128, jnp.float32, None), (1, 130, jnp.bfloat16, None),
         (4, 5, jnp.float32, None), (4, 9, jnp.bfloat16, None),
         (4, 128, jnp.float32, "int8"),
         pytest.param(4, 130, jnp.float32, "fp8", marks=_FP8),
         (32, 5, jnp.float32, None), (32, 9, jnp.float32, "int8"),
-        (32, 128, jnp.bfloat16, None), (32, 130, jnp.float32, None)])
-    def test_chunk_kernel(self, s, window, dtype, kv_dtype):
+        (32, 128, jnp.bfloat16, None), (32, 130, jnp.float32, None)],
+        ("window-after-short", 1, 9, jnp.float32, None),
+        ("window-after-short", 4, 130, jnp.float32, "int8"),
+        ("window-after-short", 32, 9, jnp.float32, None)))
+    def test_chunk_kernel(self, s, window, dtype, kv_dtype, batch):
         rng = np.random.default_rng(41)
         S = self.BS * self.MB
         # every edge of the sweep, and cursors that put the first
         # lane's window start and the last lane's in different chunks
         lengths = _edge_lengths(self.BS, S, s) + [
             window + 120, window + 127, window + 128, 2 * 128 + window - 3]
+        if batch is not None:
+            lengths = self._AFTER_SHORT
         # (a lane farther past the table's end than the window is
         # wide sees no key at all: out of the engine's contract, and
         # garbage of another kind in kernel and reference)
@@ -1222,11 +1293,13 @@ class TestWindow:
             np.asarray(out, np.float32), np.asarray(ref, np.float32),
             atol=tol, rtol=tol)
 
-    @pytest.mark.parametrize("window,dtype,kv_dtype", [
+    @pytest.mark.parametrize("window,dtype,kv_dtype,batch", _kept_ids([
         (5, jnp.float32, None), (9, jnp.bfloat16, None),
         (128, jnp.float32, None), (5, jnp.float32, "int8"),
-        pytest.param(128, jnp.float32, "fp8", marks=_FP8)])
-    def test_fused_kernel(self, window, dtype, kv_dtype):
+        pytest.param(128, jnp.float32, "fp8", marks=_FP8)],
+        ("window-after-short", 9, jnp.float32, None),
+        ("window-after-short", 130, jnp.float32, "int8")))
+    def test_fused_kernel(self, window, dtype, kv_dtype, batch):
         """The windowed attend within tolerance; the write is the
         window's business not at all: pages, codes and scales bitwise
         the reference's on live pages."""
@@ -1236,6 +1309,7 @@ class TestWindow:
         rng = np.random.default_rng(42)
         S = self.BS * self.MB
         lengths = np.asarray(
+            self._AFTER_SHORT if batch is not None else
             [n for n in _edge_lengths(self.BS, S) if n <= S]
             + [window + 127, window + 128, 300], np.int32)
         b = len(lengths)
@@ -1302,3 +1376,144 @@ class TestWindow:
                                                    jnp.float32)
         with pytest.raises(ValueError, match="window"):
             paged_attention(q, kp, vp, tables, lens, window=0)
+
+
+# --------------------------------------------------------------------- #
+# the stream of chunk fetches across rows
+# --------------------------------------------------------------------- #
+class TestChunkStream:
+    """Both kernels fetch ONE stream of chunks over the grid, two
+    ahead of the chunk attended, across row boundaries: a row's result
+    is what it is alone, and the schedule never refills a slot that is
+    still to be read."""
+
+    @pytest.mark.parametrize("fused,kv_dtype,s,batch", [
+        (True, None, 1, "chunks-1-2-9"), (True, "int8", 1, "chunks-1-2-9"),
+        (True, None, 1, "cow-prefix"), (False, None, 4, "chunks-1-2-9"),
+        (False, "int8", 32, "cow-prefix")])
+    def test_rows_together_are_the_rows_alone_bitwise(self, fused,
+                                                      kv_dtype, s, batch):
+        """The batch's outputs, and for the fused kernel its written
+        pages and scales, bit for bit those of its rows run one call a
+        row (no hand-off), the rows' writes applied in turn."""
+        from apex_tpu.ops.paged_attention import paged_decode_fused
+
+        rng = np.random.default_rng(44)
+        b_kw = _HANDOFFS[batch]
+        lengths = np.minimum(b_kw["lengths"], _HANDOFF_S - s)
+        b, hk, h, d, BS = len(lengths), 2, 10, 32, 8
+        MB = _HANDOFF_S // BS
+        kp, vp, tables = _pool_setup(
+            rng, b=b, hk=hk, d=d, NB=b * MB + 1, BS=BS, MB=MB,
+            lengths=list(lengths), s=s, dtype=jnp.float32)
+        if b_kw.get("share"):
+            tables[1, :b_kw["share"]] = tables[0, :b_kw["share"]]
+        pool = [kp, vp]
+        if kv_dtype is not None:
+            pool = list(quantize_kv_pages(kp, vp, kv_dtype))
+        q = jnp.asarray(rng.normal(size=(b, s, h, d)), jnp.float32)
+        nk = jnp.asarray(rng.normal(size=(b, s, hk, d)), jnp.float32)
+        lens = jnp.asarray(lengths, jnp.int32)
+
+        def call(rows, pool):
+            sc = {} if kv_dtype is None else dict(
+                k_scales=pool[2], v_scales=pool[3])
+            if not fused:
+                return [paged_attention(
+                    q[rows], pool[0], pool[1], jnp.asarray(tables[rows]),
+                    lens[rows], implementation="pallas_interpret", **sc)]
+            if kv_dtype is not None:
+                sc["chunk_lens"] = jnp.ones(lens[rows].shape, jnp.int32)
+            return list(paged_decode_fused(
+                q[rows], nk[rows], nk[rows], pool[0], pool[1],
+                jnp.asarray(tables[rows]), lens[rows],
+                max_seq_len=_HANDOFF_S, implementation="pallas_interpret",
+                **sc))
+
+        together = call(slice(None), pool)
+        outs = []
+        for r in range(b):
+            alone = call(slice(r, r + 1), pool)
+            outs.append(alone[0])
+            pool = alone[1:] or pool
+        np.testing.assert_array_equal(np.asarray(together[0]),
+                                      np.concatenate(outs))
+        for got, want in zip(together[1:], pool):
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+    @pytest.mark.parametrize("window", [None, 130])
+    def test_every_chunk_is_fetched_once_two_ahead(self, window):
+        """The kernels' fetch schedule replayed on the host with the
+        kernels' own index functions: every row's chunks are started
+        once, in the stream's order, into a slot that holds no chunk
+        still to be read; each is read from the slot it went to; the
+        fused kernel's write-back slot is not refilled before the next
+        row (which waits for it) begins."""
+        from apex_tpu.ops.paged_attention import (_SLOTS, _row_span,
+                                                  _stream_ahead)
+
+        lengths = [100, 200, 1100, 5, 1100, 130, 2048, 0, 7, 900]
+        spans = [tuple(int(v) for v in _row_span(
+            jnp.int32(n), s=1, bs=8, mb=256, window=window)[1:])
+            for n in lengths]
+        rows = len(spans)
+        held, started, writeback = {}, [], None
+
+        def fetch(row, k, c, slot):
+            (f1, n1), (f2, _) = (spans[min(row + on, rows - 1)]
+                                 for on in (1, 2))
+            on, chunk_no = (int(v) for v in _stream_ahead(
+                k, c, spans[row][1], f1, n1, f2))
+            if row + on < rows:
+                assert slot not in held and slot != writeback
+                held[slot] = (row + on, chunk_no)
+                started.append((row + on, chunk_no))
+
+        base = 0
+        for row, (first, n_chunks) in enumerate(spans):
+            writeback = None                 # waited as the row begins
+            if row == 0:
+                fetch(0, 0, first, 0)
+                fetch(0, 1, first, 1)
+            for c in range(first, n_chunks):
+                slot = (base + c - first) % _SLOTS
+                fetch(row, 2, c, (slot + 2) % _SLOTS)
+                assert held.pop(slot) == (row, c)
+                if c == n_chunks - 1:
+                    writeback = slot
+            base = (base + n_chunks - first) % _SLOTS
+        assert started == [(r, c) for r, (f, n) in enumerate(spans)
+                           for c in range(f, n)]
+        assert not held
+        assert any(f > 0 for f, _ in spans) == (window is not None)
+
+    def test_tensor_parallel_shards_run_the_same_stream(self):
+        """A coded pool sharded on its kv heads over two devices: each
+        shard runs the kernel over the whole batch on its heads, so its
+        stream hands on at the same row boundaries — outputs, pages
+        and scales bit for bit the one-device call's."""
+        from apex_tpu.ops.paged_attention import paged_decode_fused
+
+        rng = np.random.default_rng(45)
+        lengths = _HANDOFFS["chunks-1-2-9"]["lengths"]
+        b, hk, h, d, BS = len(lengths), 2, 10, 32, 8
+        MB = _HANDOFF_S // BS
+        kp, vp, tables = _pool_setup(
+            rng, b=b, hk=hk, d=d, NB=b * MB + 1, BS=BS, MB=MB,
+            lengths=lengths, s=1, dtype=jnp.float32)
+        kp, vp, ks, vs = quantize_kv_pages(kp, vp, "int8")
+        kw = dict(k_scales=ks, v_scales=vs,
+                  chunk_lens=jnp.ones((b,), jnp.int32))
+        q = jnp.asarray(rng.normal(size=(b, 1, h, d)), jnp.float32)
+        nk = jnp.asarray(rng.normal(size=(b, 1, hk, d)), jnp.float32)
+        args = (q, nk, nk, kp, vp, jnp.asarray(tables),
+                jnp.asarray(lengths, jnp.int32))
+        mesh = jax.sharding.Mesh(np.array(jax.devices()[:2]), ("tensor",))
+        one = paged_decode_fused(*args, max_seq_len=_HANDOFF_S,
+                                 implementation="pallas_interpret", **kw)
+        two = paged_decode_fused(*args, max_seq_len=_HANDOFF_S,
+                                 implementation="pallas_interpret",
+                                 mesh=mesh, shard_axis="tensor", **kw)
+        assert len(one) == len(two) == 5
+        for a, b_ in zip(one, two):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b_))
